@@ -18,7 +18,7 @@ them. Designed for two call shapes:
 just produced. Exit codes: 0 = within tolerance, 1 = regression(s),
 2 = not enough history to fit a single baseline.
 
-Untracked metrics (tunnel RTT, phase walls, registry snapshots) and
+Untracked metrics (fetch latency, phase walls, registry snapshots) and
 metrics new to the current record are tolerated by construction — the
 sentinel gates performance, not growth.
 """

@@ -3,7 +3,7 @@
 # reference's off-heap feature index (util/PalDBIndexMap.scala). Features
 # ingest straight to padded-ELL (--sparse) and the power-law head of the
 # column distribution is densified onto the MXU (--hot-columns -1, the
-# measured-cost-model auto split — see docs/PERF.md).
+# measured-cost-model auto split — ops/sparse.py::to_hybrid).
 set -euo pipefail
 cd "$(dirname "$0")"
 export PYTHONPATH="..${PYTHONPATH:+:$PYTHONPATH}"

@@ -115,8 +115,9 @@ class GLMDriverParams:
     # additionally run the EXPENSIVE training diagnostics: learning-curve
     # refits + bootstrap CIs (``Params.trainingDiagnosticsEnabled``)
     training_diagnostics: bool = False
-    # float64 matches the reference's double-precision solves; silently
-    # degrades to float32 when x64 is disabled (default on TPU backends)
+    # float64 matches the reference's double-precision solves; degrades
+    # to float32 when x64 is disabled (the default) — the driver logs the
+    # dtype actually used at start
     precision: str = "float64"
     # device mesh for the solve: {"data": N} row-shards the batch (GSPMD
     # psum aggregation), {"data": N, "feature": M} additionally shards the

@@ -588,7 +588,7 @@ def _current_heartbeat():
 def _run_game_training(
     params: GameDriverParams, logger: PhotonLogger, shutdown
 ) -> GameTrainingRun:
-    from photon_ml_tpu.cli.train import driver_dtype
+    from photon_ml_tpu.cli.train import driver_dtype, log_driver_runtime
 
     task = TaskType[params.task]
     dtype = driver_dtype(params.precision)
@@ -604,6 +604,7 @@ def _run_game_training(
     from photon_ml_tpu.parallel import initialize_multihost
 
     initialize_multihost()  # no-op when unconfigured / already joined
+    log_driver_runtime(logger, params.precision)
     # gate on process_count alone: a launcher may have initialized the
     # distributed runtime itself, and a False here while process_count>1
     # would make every process silently ingest the FULL input
@@ -1451,8 +1452,7 @@ def main(argv=None) -> None:
     # after parse_args: --help / bad flags must not initialize
     # the accelerator backend or touch the cache directory.
     # JOIN FIRST: jax.distributed.initialize must run before anything
-    # touches the backend, and enable_compilation_cache reads
-    # jax.default_backend()
+    # touches the backend
     from photon_ml_tpu.parallel import initialize_multihost
     from photon_ml_tpu.utils import enable_compilation_cache
 

@@ -24,6 +24,7 @@ import numpy as np
 
 from photon_ml_tpu.cli.config import ScoringParams, load_params
 from photon_ml_tpu.cli.train import (
+    log_driver_runtime,
     prepare_output_dir,
     resolve_date_range,
 )
@@ -122,6 +123,7 @@ def run_scoring(params) -> ScoringRun:
     )
     logger.info(f"scoring records with {params.model_kind} "
                 f"model from {params.model_dir}")
+    log_driver_runtime(logger)
 
     with timed(logger, "score"):
         if params.model_kind == "glm":

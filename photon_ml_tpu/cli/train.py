@@ -38,13 +38,39 @@ from photon_ml_tpu.utils.logging import PhotonLogger, timed
 
 
 def driver_dtype(precision: str):
-    """float64 when requested AND enabled; float32 otherwise (no warnings)."""
+    """float64 when requested AND enabled; float32 otherwise —
+    :func:`log_driver_runtime` says which at driver start."""
     import jax
     import jax.numpy as jnp
 
     if precision == "float64" and jax.config.jax_enable_x64:
         return jnp.float64
     return jnp.float32
+
+
+def log_driver_runtime(logger, precision: Optional[str] = None) -> None:
+    """Log once, at driver start, where and how this run executes: the
+    device, the dtype the solves actually use, and the Avro codec ingest
+    takes and why."""
+    import jax
+
+    from photon_ml_tpu.io import native
+
+    dev = jax.devices()[0]
+    logger.info(
+        f"device: platform={dev.platform} device_kind={dev.device_kind} "
+        f"count={jax.device_count()}"
+    )
+    if precision is not None:
+        used = np.dtype(driver_dtype(precision)).name
+        note = (
+            ""
+            if used == precision
+            else f" (precision={precision!r} requested; jax_enable_x64 "
+            "is off)"
+        )
+        logger.info(f"solve dtype: {used}{note}")
+    logger.info(native.codec_report())
 
 
 def read_records(paths: List[str]) -> List[dict]:
@@ -64,8 +90,8 @@ def read_records(paths: List[str]) -> List[dict]:
 def _hybridize(batch, params, logger):
     """Split an ELL batch into the dense-hot + bucketed sparse-cold
     representation (``ops.sparse.HybridFeatures``): the power-law head
-    rides the MXU, the tail keeps the scatter path at near-zero padding
-    (docs/PERF.md sparse section). The batch's row-aligned fields are
+    rides the MXU, the tail keeps the scatter path at near-zero
+    padding. The batch's row-aligned fields are
     permuted to the hybrid's stored order (training is row-order
     invariant)."""
     import dataclasses as _dc
@@ -252,6 +278,7 @@ def _run_glm_training(params: GLMDriverParams) -> GLMTrainingRun:
     logger.info(f"GLM training driver: task={params.task} "
                 f"optimizer={params.optimizer} reg={params.reg_type} "
                 f"lambdas={params.reg_weights}")
+    log_driver_runtime(logger, params.precision)
 
     # ---- PREPROCESS ------------------------------------------------------
     with timed(logger, "preprocess"):

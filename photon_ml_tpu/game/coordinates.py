@@ -355,9 +355,9 @@ class FixedEffectCoordinate:
     def update_and_score(
         self, w: jax.Array, partial_scores: jax.Array, key=None
     ) -> Tuple[jax.Array, object, jax.Array]:
-        """update + full-batch rescore, fused into one dispatch (on
-        remote/tunneled devices each dispatch is a round trip; the
-        coordinate-descent loop uses this form)."""
+        """update + full-batch rescore, fused into one dispatch (every
+        dispatch costs a host->device launch and, when its result is
+        read, a fetch; the coordinate-descent loop uses this form)."""
         return self.update_step(w, partial_scores, key)
 
     def wrap_tracker(self, tracker):
@@ -369,10 +369,10 @@ class FixedEffectCoordinate:
         """Device-resident arrays update_step reads, as an explicit
         pytree. The fused whole-pass jit threads these as ARGUMENTS:
         closed-over concrete arrays are not hoisted by tracing (they are
-        not tracers) and lower to HLO literals — the serialized program
-        would carry the whole dataset (observed: remote-compile requests
-        rejected with HTTP 413). The reg weight rides along so grid
-        sweeps can vmap a combo axis over it."""
+        not tracers) and lower to HLO literals — the compiled program
+        would carry the whole dataset (observed: multi-hundred-MB
+        modules). The reg weight rides along so grid sweeps can vmap a
+        combo axis over it."""
         return self.fused_state_for_reg(self._reg_weight)
 
     def fused_state_for_reg(self, reg_weight):
@@ -538,9 +538,9 @@ def _make_multi_bucket_update(config: CoordinateConfig):
     the bucket's entities in one vmapped call, scatter solutions back.
     Sentinel indices (== num_entities) clip on gather and drop on scatter.
 
-    Fusing the whole multi-bucket pass into a single dispatch matters on
-    remote/tunneled devices where every dispatch pays a round trip (a
-    4-bucket update would otherwise cost 4+ latencies per CD pass). Cache
+    Fusing the whole multi-bucket pass into a single dispatch saves
+    per-dispatch launch latency (a 4-bucket update would otherwise cost
+    4+ launches per CD pass). Cache
     key zeroes reg_weight (traced per entity) so a lambda grid reuses one
     compile."""
     return _make_multi_bucket_update_cached(
@@ -824,7 +824,7 @@ class EntityShardedRandomEffectCoordinate:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from photon_ml_tpu.game.data import BucketedRandomEffectDesign
-        from photon_ml_tpu.parallel.mesh import ENTITY_AXIS, shard_map
+        from photon_ml_tpu.parallel.mesh import ENTITY_AXIS
 
         if config.random_effect is None:
             raise ValueError("config lacks random_effect; wrong coordinate")
@@ -1013,14 +1013,14 @@ class EntityShardedRandomEffectCoordinate:
                 lambda *a: update_shard(*a), *args
             )
             out_specs = jax.tree_util.tree_map(spec_of, out_shape)
-            return shard_map(
+            return jax.shard_map(
                 update_shard,
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=out_specs,
                 # per-shard solver while_loops have no replication rule;
                 # every output is genuinely shard-varying anyway
-                check_rep=False,
+                check_vma=False,
             )(*args)
 
         self._update_all = jax.jit(update_all)
@@ -1028,12 +1028,12 @@ class EntityShardedRandomEffectCoordinate:
         def score_fn(table, feats, ents):
             args = (table, feats, ents)
             in_specs = jax.tree_util.tree_map(spec_of, args)
-            return shard_map(
+            return jax.shard_map(
                 _score_rows_by_entity,
                 mesh=mesh,
                 in_specs=in_specs,
                 out_specs=P(ENTITY_AXIS),
-                check_rep=False,
+                check_vma=False,
             )(*args)
 
         self._score = jax.jit(score_fn)

@@ -494,12 +494,22 @@ class IngestSource:
         )
 
     def _native(self):
-        try:
-            from photon_ml_tpu.io import native
+        """The native codec module, or None when its library could not
+        be built/loaded (``native.codec_report()`` says why — the
+        drivers log it at start)."""
+        from photon_ml_tpu.io import native
 
-            return native if native.native_available() else None
-        except Exception:  # noqa: BLE001 — any failure means fallback
-            return None
+        return native if native.native_available() else None
+
+    def _warn_python_codec(self, why) -> None:
+        """The per-dataset fallback must be as visible as the
+        per-process one: the Python codec is ~28x slower."""
+        from photon_ml_tpu.utils.logging import PhotonLogger
+
+        PhotonLogger(None).warn(
+            f"native reader does not support {self.files!r} ({why}); "
+            "decoding with the Python codec"
+        )
 
     def _check_nonempty(self, n: int):
         """Valid-but-empty inputs fail loudly here rather than training a
@@ -537,7 +547,8 @@ class IngestSource:
                 label=f"native read {self.files}",
                 paths=self.files,
             )
-        except native.UnsupportedSchema:
+        except native.UnsupportedSchema as e:
+            self._warn_python_codec(e)
             return None
 
     def _native_nonempty(self, out):
@@ -568,8 +579,8 @@ class IngestSource:
                 return FeatureVocabulary(
                     sorted(keys), add_intercept=add_intercept
                 )
-            except native.UnsupportedSchema:
-                pass
+            except native.UnsupportedSchema as e:
+                self._warn_python_codec(e)
         return FeatureVocabulary.from_records(
             self.records(),
             add_intercept=add_intercept,

@@ -11,13 +11,18 @@ performs the vocabulary join ((name, term) -> column id, the
 columnar outputs natively. Python only sees numpy arrays.
 
 The shared library builds on first use with ``g++`` (no pybind11 in the
-image — plain C ABI + ctypes); if the toolchain or zlib is missing every
-entry point reports unavailable and callers fall back to the Python codec.
+image — plain C ABI + ctypes) into a directory named by the source's
+content hash, so a binary built from any other ``avro_reader.cpp`` can
+never be loaded. If the toolchain or zlib is missing every entry point
+reports unavailable and callers fall back to the ~28x slower Python
+codec — the drivers log which codec is in use and why
+(:func:`codec_report`).
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import io
 import json
 import os
@@ -68,7 +73,17 @@ COL_LABEL, COL_OFFSET, COL_WEIGHT = 0, 1, 2
 
 _SRC = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native",
                     "avro_reader.cpp")
-_SO = os.path.join(os.path.dirname(_SRC), "_build", "libpml_avro.so")
+BUILD_DIR = os.path.join(os.path.dirname(_SRC), "_build")
+
+
+def library_path() -> str:
+    """Where the shared library for the CURRENT source lives:
+    ``_build/<sha256 of avro_reader.cpp>/libpml_avro.so``. Freshness is
+    the path itself — no mtime comparison a copied tree could fool."""
+    with open(_SRC, "rb") as f:
+        digest = hashlib.sha256(f.read()).hexdigest()[:16]
+    return os.path.join(BUILD_DIR, digest, "libpml_avro.so")
+
 
 _lib_lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
@@ -98,12 +113,15 @@ def live_native_handles() -> int:
 
 def _build_and_load() -> Tuple[Optional[ctypes.CDLL], Optional[str]]:
     try:
-        if (not os.path.exists(_SO)
-                or os.path.getmtime(_SO) < os.path.getmtime(_SRC)):
-            os.makedirs(os.path.dirname(_SO), exist_ok=True)
+        so = library_path()
+        if not os.path.exists(so):
+            os.makedirs(os.path.dirname(so), exist_ok=True)
+            # build beside the target and rename: a concurrent process
+            # never loads a half-written library
+            tmp = f"{so}.{os.getpid()}.tmp"
             base = [
                 "g++", "-O3", "-std=c++17", "-shared", "-fPIC", "-pthread",
-                _SRC, "-o", _SO,
+                _SRC, "-o", tmp,
             ]
             # libdeflate inflates ~2-3x faster than zlib; fall back to
             # zlib-only when the dev package is absent
@@ -118,7 +136,8 @@ def _build_and_load() -> Tuple[Optional[ctypes.CDLL], Optional[str]]:
                 )
             if proc.returncode != 0:
                 return None, f"native build failed: {proc.stderr[-2000:]}"
-        lib = ctypes.CDLL(_SO)
+            os.replace(tmp, so)
+        lib = ctypes.CDLL(so)
         lib.pml_vocabset_new.restype = ctypes.c_void_p
         lib.pml_vocabset_new.argtypes = [
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
@@ -205,6 +224,17 @@ def native_available() -> bool:
 def native_error() -> Optional[str]:
     get_lib()
     return _lib_error
+
+
+def codec_report() -> str:
+    """One line for a driver's log: which Avro codec ingest takes in this
+    process, and why."""
+    if native_available():
+        return f"avro codec: native ({library_path()})"
+    return (
+        "avro codec: python (~28x slower ingest) — native reader "
+        f"unavailable: {native_error()}"
+    )
 
 
 # ---------------------------------------------------------------------------

@@ -1,13 +1,13 @@
 """Hand-written Pallas kernel suite for the wide-feature sparse path.
 
-``ops/sparse.py`` routes its three ELL contractions here (per
-``PHOTON_SPARSE_KERNEL`` — see :mod:`photon_ml_tpu.kernels.dispatch`),
-and ``GLMObjective`` additionally swaps whole objective passes for the
-fused single-read sweeps in :mod:`photon_ml_tpu.kernels.fused`. Every
-consumer of the sparse containers — ``GLMObjective`` solves, GAME
-random-effect batches, serving, ``HybridFeatures``' cold segments —
-benefits with zero call-site changes. docs/KERNELS.md is the field
-guide.
+Under ``PHOTON_SPARSE_KERNEL=pallas`` (see
+:mod:`photon_ml_tpu.kernels.dispatch`) ``ops/sparse.py`` routes its
+three ELL contractions here and ``GLMObjective`` swaps whole objective
+passes for the fused single-read sweeps in
+:mod:`photon_ml_tpu.kernels.fused`. The default (``auto``) is the XLA
+lowering on every platform: the suite runs in the Pallas interpreter
+off-TPU and does not lower for TPU on the installed toolchain.
+docs/KERNELS.md is the field guide.
 """
 
 from photon_ml_tpu.kernels.dispatch import (
@@ -16,9 +16,7 @@ from photon_ml_tpu.kernels.dispatch import (
     design_reads,
     interpret_mode,
     kernel_mode,
-    pallas_available,
     record_kernel_cost,
-    reset_probe_cache,
     use_pallas,
 )
 from photon_ml_tpu.kernels.ell import (
@@ -38,11 +36,9 @@ __all__ = [
     "KERNEL_MODES",
     "kernel_mode",
     "use_pallas",
-    "pallas_available",
     "interpret_mode",
     "design_reads",
     "record_kernel_cost",
-    "reset_probe_cache",
     "ell_matvec",
     "ell_rmatvec",
     "ell_colsum",

@@ -1,34 +1,32 @@
 """Sparse-kernel dispatch: PHOTON_SPARSE_KERNEL={auto,pallas,xla}.
 
-BENCH_r05 pinned the wide-feature GLM loss on XLA's gather/scatter
-lowering of the ELL contractions (``sparse_uniform_vs_sklearn`` 0.39x at
-92% ceiling fit — the pass cost IS the wall clock), so ``ops/sparse.py``
-now routes its ELL kernels through the hand-written Pallas suite in this
-package when that is the better backend. This module is the ONE place
-that decides:
+``ops/sparse.py`` routes its ELL contractions either through XLA's
+gather/scatter lowering or through the hand-written Pallas suite in this
+package. This module is the ONE place that decides, by a rule — never by
+trying a kernel and catching what happens (docs/KERNELS.md "Dispatch"):
 
-- ``kernel_mode()``: the env knob. ``auto`` (default) selects Pallas on
-  TPU and the existing XLA lowering everywhere else; ``pallas`` forces
-  the Pallas suite (interpret mode off-TPU — how tier-1 proves kernel
-  correctness on CPU); ``xla`` pins today's gather/scatter path.
-- ``use_pallas(...)``: mode x environment x shape eligibility. Pallas is
-  skipped when the coefficient table or accumulator would not fit the
-  VMEM budget (``PHOTON_PALLAS_VMEM_CAP``, default 4 MiB per buffer —
-  row blocks stream, but w and the scatter accumulator are resident),
-  when the batch is degenerate (0 rows/slots), or when a >1-device mesh
-  is active — sharded ELL solves stay on XLA, whose partitioner knows
-  how to split a gather; a Pallas custom call would be replicated.
-- ``pallas_available()``: a cached one-shot probe that builds and runs a
-  tiny kernel on the current backend. ``auto`` consults it, so a Mosaic
-  toolchain that cannot lower the suite (the round-3 lab saw exactly
-  that) degrades to the XLA path instead of failing every solve.
-  ``pallas`` skips the probe — forced means forced, and tests want the
-  real error.
+- ``kernel_mode()``: the env knob. ``auto`` (default) and ``xla`` select
+  the XLA lowering on EVERY platform. None of the six Pallas kernels
+  lowers for TPU on the installed toolchain (jax 0.9.0 / libtpu 0.0.34):
+  the in-kernel table gather ``w_ref[0, :][ix]`` stops at "Only 2D
+  gather is supported" and the scatter's per-row ``ix[r, :]`` at an
+  unimplemented ``dynamic_slice`` — both recorded as strict xfails in
+  ``tests/test_kernels.py::TestTpuLowering``, so a repair flips those
+  tests and forces this rule to be revisited. ``pallas`` forces the
+  suite: interpret mode off-TPU (how tier-1 proves kernel semantics on
+  CPU), the real lowering on TPU — where today it raises the compiler's
+  own error at the first sparse pass.
+- ``use_pallas(...)``: mode x shape eligibility. Even when forced,
+  Pallas is skipped when the coefficient table or accumulator would not
+  fit the VMEM budget (``PHOTON_PALLAS_VMEM_CAP``, default 4 MiB per
+  buffer — row blocks stream, but w and the scatter accumulator are
+  resident), when the batch is degenerate (0 rows/slots), or when a
+  >1-device mesh is active outside ``shard_local()`` — GSPMD would
+  replicate a Pallas custom call; that case is logged and counted.
 - ``record_kernel_cost(...)``: every kernel wrapper books its analytic
   cost profile (FLOPs, bytes, ONE-design-read roofline traffic) into the
   shared :mod:`photon_ml_tpu.obs.xla_cost` cost book, once per
-  (kernel, shape bucket), so bench MFU/achieved-bytes attribution covers
-  the new executables exactly like the XLA ones.
+  (kernel, shape bucket).
 """
 
 from __future__ import annotations
@@ -36,7 +34,7 @@ from __future__ import annotations
 import contextlib
 import os
 import threading
-from typing import Dict, Optional, Tuple
+from typing import Optional
 
 import jax
 
@@ -45,14 +43,12 @@ __all__ = [
     "VMEM_CAP_ENV",
     "KERNEL_MODES",
     "kernel_mode",
-    "pallas_available",
     "use_pallas",
     "interpret_mode",
     "accumulator_fits",
     "active_mesh_devices",
     "record_kernel_cost",
     "design_reads",
-    "reset_probe_cache",
     "shard_local",
     "in_shard_local",
 ]
@@ -80,15 +76,10 @@ _DESIGN_READS = {
     "fused_hdiag": 1,
 }
 
-_probe_lock = threading.Lock()
-_probe_result: Dict[str, bool] = {}
-
 _record_lock = threading.Lock()
 _recorded = set()
 
-# one-shot multidevice-fallback signal (the eligibility rule below used
-# to fire SILENTLY: every >1-device run quietly lost the Pallas kernels
-# with nothing in any artifact saying so)
+# one-shot multidevice-fallback signal
 _fallback_lock = threading.Lock()
 _fallback_logged = False
 
@@ -120,36 +111,31 @@ def in_shard_local() -> bool:
 
 def _note_multidevice_fallback(devices: int) -> None:
     """One-shot log + always-counted metric when the >1-device-mesh rule
-    routes an eligible contraction to XLA (ISSUE 14 bugfix: the silent
-    loss of the Pallas kernels on every multi-device run)."""
+    routes a forced-Pallas contraction to XLA."""
     global _fallback_logged
-    try:
-        from photon_ml_tpu import obs
+    from photon_ml_tpu import obs
+    from photon_ml_tpu.utils.logging import PhotonLogger
 
-        obs.registry().inc("kernels.dispatch.multidevice_fallback")
-        with _fallback_lock:
-            if _fallback_logged:
-                return
-            _fallback_logged = True
-        obs.emit_event(
-            "kernels.dispatch.multidevice_fallback",
-            cat="kernels",
-            devices=devices,
-            hint=(
-                "GSPMD meshes route ELL contractions to XLA; shard_map "
-                "paths keep Pallas via kernels.dispatch.shard_local()"
-            ),
-        )
-        from photon_ml_tpu.utils.logging import PhotonLogger
-
-        PhotonLogger(None).warn(
-            f"sparse ELL contractions falling back to the XLA lowering "
-            f"under a {devices}-device mesh (Pallas custom calls are "
-            "not GSPMD-partitionable); explicit shard_map paths can "
-            "keep the Pallas suite via kernels.dispatch.shard_local()"
-        )
-    except Exception:
-        pass  # dispatch must never fail on observability
+    obs.registry().inc("kernels.dispatch.multidevice_fallback")
+    with _fallback_lock:
+        if _fallback_logged:
+            return
+        _fallback_logged = True
+    obs.emit_event(
+        "kernels.dispatch.multidevice_fallback",
+        cat="kernels",
+        devices=devices,
+        hint=(
+            "GSPMD meshes route ELL contractions to XLA; shard_map "
+            "paths keep Pallas via kernels.dispatch.shard_local()"
+        ),
+    )
+    PhotonLogger(None).warn(
+        f"sparse ELL contractions falling back to the XLA lowering "
+        f"under a {devices}-device mesh (Pallas custom calls are "
+        "not GSPMD-partitionable); explicit shard_map paths can "
+        "keep the Pallas suite via kernels.dispatch.shard_local()"
+    )
 
 
 def kernel_mode() -> str:
@@ -184,69 +170,9 @@ def accumulator_fits(d: int, itemsize: int) -> bool:
 
 
 def active_mesh_devices() -> int:
-    """Device count of the active mesh context (1 when none). Sharded
-    solves enter through ``parallel.mesh.set_mesh``; both the 0.4.x
-    ``with mesh:`` form and newer ``jax.set_mesh`` land in thread-local
-    state this reads back, best-effort."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        env = mesh_lib.thread_resources.env
-        size = getattr(env.physical_mesh, "size", 0)
-        if size and size > 1:
-            return int(size)
-    except Exception:
-        pass
-    try:  # newer jax: abstract mesh context
-        from jax._src import mesh as mesh_lib
-
-        am = mesh_lib.get_abstract_mesh()
-        if am is not None and getattr(am, "size", 0) > 1:
-            return int(am.size)
-    except Exception:
-        pass
-    return 1
-
-
-def _probe() -> bool:
-    """Build + run a tiny representative kernel once per backend; any
-    failure marks Pallas unavailable for ``auto`` until process exit
-    (``reset_probe_cache`` for tests)."""
-    backend = jax.default_backend()
-    with _probe_lock:
-        if backend in _probe_result:
-            return _probe_result[backend]
-    ok = True
-    try:
-        import numpy as np
-
-        from photon_ml_tpu.kernels import ell
-
-        idx = np.array([[0, 2], [1, 3]], np.int32)
-        val = np.array([[1.0, 2.0], [3.0, 4.0]], np.float32)
-        w = np.arange(3, dtype=np.float32)
-        out = jax.jit(
-            lambda i, v, ww: ell.ell_matvec(i, v, ww, 3)
-        )(idx, val, w)
-        # row0 = 1*w[0] + 2*w[2] = 4; row1 = 3*w[1] + 4*w[3->pad] = 3
-        np.testing.assert_allclose(
-            np.asarray(out), [4.0, 3.0], rtol=1e-5
-        )
-    except Exception:
-        ok = False
-    with _probe_lock:
-        _probe_result[backend] = ok
-    return ok
-
-
-def pallas_available() -> bool:
-    return _probe()
-
-
-def reset_probe_cache() -> None:
-    """Forget probe results (tests that flip backends/envs)."""
-    with _probe_lock:
-        _probe_result.clear()
+    """Device count of the mesh ``jax.set_mesh`` installed (1 when
+    none). Readable from inside a jit trace."""
+    return max(1, jax.sharding.get_abstract_mesh().size)
 
 
 def use_pallas(
@@ -256,9 +182,10 @@ def use_pallas(
     nnz_per_row: Optional[int] = None,
 ) -> bool:
     """Should the current op take the Pallas path? Trace-time static:
-    mode, backend, probe, mesh context, and shape eligibility."""
-    mode = kernel_mode()
-    if mode == "xla":
+    mode, mesh context, and shape eligibility. Only ``pallas`` mode ever
+    answers True (module docstring); a selected kernel that then fails
+    to lower raises the compiler's error — nothing here catches it."""
+    if kernel_mode() != "pallas":
         return False
     if n is not None and n == 0:
         return False  # nothing to tile; XLA returns the empty/zero result
@@ -269,14 +196,12 @@ def use_pallas(
     devices = active_mesh_devices()
     if devices > 1 and not in_shard_local():
         # GSPMD would replicate a Pallas custom call (wrong results at
-        # whole-array shapes), so sharded solves stay on XLA — but no
-        # longer silently (one-shot log + counter), and shard_map'd
-        # paths that declared themselves shard-local keep the kernels.
+        # whole-array shapes), so sharded solves stay on XLA — logged
+        # once and counted; shard_map'd paths that declared themselves
+        # shard-local keep the kernels.
         _note_multidevice_fallback(devices)
         return False
-    if mode == "pallas":
-        return True
-    return jax.default_backend() == "tpu" and pallas_available()
+    return True
 
 
 def design_reads(kernel: str) -> int:

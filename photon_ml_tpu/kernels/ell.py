@@ -1,8 +1,9 @@
-"""Pallas TPU kernels for the padded-ELL contractions.
+"""Pallas kernels for the padded-ELL contractions.
 
-These replace XLA's gather/scatter lowering of the three hot ops in
+Written to replace XLA's gather/scatter lowering of the three hot ops in
 ``ops/sparse.py`` — the ~90 ms/pass frontier BENCH_r05 measured at 92%
-of the sparse solve's wall clock:
+of the sparse solve's wall clock. They do NOT lower for TPU on jax 0.9.0
+(docs/KERNELS.md "Status"), so today they run only in the interpreter:
 
     matvec:   z_i = sum_k v_ik * w[c_ik]      (gather + row reduce)
     rmatvec:  g_j = sum_{ik: c_ik=j} v_ik a_i (scatter-add)
@@ -45,14 +46,7 @@ import os
 
 import jax
 import jax.numpy as jnp
-
-try:  # pragma: no cover - exercised via dispatch.pallas_available
-    from jax.experimental import pallas as pl
-
-    HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    pl = None
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 from photon_ml_tpu.kernels import dispatch
 

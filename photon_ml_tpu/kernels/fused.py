@@ -34,14 +34,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-
-try:  # pragma: no cover - exercised via dispatch.pallas_available
-    from jax.experimental import pallas as pl
-
-    HAVE_PALLAS = True
-except ImportError:  # pragma: no cover
-    pl = None
-    HAVE_PALLAS = False
+from jax.experimental import pallas as pl
 
 from photon_ml_tpu.kernels import dispatch
 from photon_ml_tpu.kernels.ell import (

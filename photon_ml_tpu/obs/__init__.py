@@ -67,6 +67,7 @@ from photon_ml_tpu.obs.collectives import (
 )
 from photon_ml_tpu.obs.compile_events import (
     install_compile_listener,
+    xla_cache_hits,
     xla_compile_events,
 )
 from photon_ml_tpu.obs.dispatch_count import (
@@ -153,6 +154,7 @@ __all__ = [
     "trace",
     "install_compile_listener",
     "xla_compile_events",
+    "xla_cache_hits",
     "CostBook",
     "CostRecord",
     "annotate_span",

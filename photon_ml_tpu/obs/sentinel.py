@@ -1,6 +1,6 @@
 """Bench regression sentinel: machine-checked perf baselines.
 
-Five BENCH records exist (``BENCH_r01..r05``) with order-of-magnitude
+The committed BENCH records (``BENCH_r03..r05``) show order-of-magnitude
 swings between rounds (GAME CD 1.19 -> 10.1 iters/s), yet nothing
 machine-checks that the NEXT change doesn't silently give those wins
 back — the records were write-only history. This module turns them into
@@ -12,7 +12,7 @@ a gate:
 - :func:`metric_direction` classifies each metric as higher-is-better
   (throughput, speedup ratios, MFU, AUC), lower-is-better (wall clocks,
   per-device footprints, collective counts), or untracked (environment
-  noise: tunnel RTT, phase walls, registry dumps — regressions there are
+  noise: fetch latency, phase walls, registry dumps — regressions there are
   not code regressions).
 - :func:`fit_baselines` fits a noise-tolerant baseline per metric over
   the history: median plus a tolerance band widened by the metric's own
@@ -52,8 +52,8 @@ __all__ = [
     "run_sentinel",
 ]
 
-# Defaults tuned on the real BENCH_r01..r05 history: every metric of r05
-# passes against the r01..r04 baseline, while a uniform 30% degradation
+# Defaults tuned on the real r01..r05 bench history: every metric of r05
+# passed against the r01..r04 baseline, while a uniform 30% degradation
 # of r05's tracked throughput/wall metrics is flagged.
 DEFAULT_TOLERANCE = 0.25
 DEFAULT_MAD_K = 4.0
@@ -167,7 +167,7 @@ _DIRECTION_RULES = (
     # dispatch economy (ROADMAP item 1, device-resident loops): host
     # round trips per training unit — a creeping dispatch count is the
     # latency regression wall clocks on a timeshared bench host cannot
-    # see, so it gates directly and tunnel-invariantly
+    # see, so it gates directly and platform-invariantly
     (
         re.compile(r"(^|\.)dispatches_per_(path|run|solve)$"),
         LOWER_IS_BETTER,
@@ -196,7 +196,7 @@ def metric_direction(name: str) -> int:
 # MAD band needs >= min_samples history records first). The multi-device
 # scaling efficiency wall_1dev/(N*wall_Ndev) has an honest ceiling of
 # ~1/N on the timeshared-CPU bench host (virtual devices share one
-# core, wall cannot drop). Through BENCH_r06 the floor was the
+# core, wall cannot drop). Through the r06 run the floor was the
 # bind-with-zero-history 0.25/N rule — a quarter of the ceiling, i.e.
 # "the 2-device regression is back" alarm. With the overlap-scaled path
 # landed (PHOTON_COLLECTIVE_MODE=overlap: row-balanced blocking +

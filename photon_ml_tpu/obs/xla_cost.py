@@ -43,8 +43,10 @@ from photon_ml_tpu.obs.metrics import registry as _registry
 from photon_ml_tpu.obs.trace import emit_event as _emit_event
 
 __all__ = [
-    "PEAK_FLOPS",
-    "PEAK_HBM_BPS",
+    "DevicePeaks",
+    "DEVICE_PEAKS",
+    "device_peaks",
+    "require_device_peaks",
     "COLLECTIVE_RE",
     "count_collectives",
     "CostRecord",
@@ -54,13 +56,52 @@ __all__ = [
     "annotate_span",
 ]
 
-# TPU v5e roofline constants (bench.py's former module constants, now the
-# ONE copy every consumer shares): peak dense bf16 matmul FLOP/s and HBM
-# bandwidth. GLM objective passes stream the design matrix at ~2
-# FLOP/byte — far below the ~240 FLOP/byte compute-bound knee — so the
-# HBM line is the relevant ceiling for the solvers in this repo.
-PEAK_FLOPS = 197e12
-PEAK_HBM_BPS = 819e9
+
+@dataclasses.dataclass(frozen=True)
+class DevicePeaks:
+    """Published per-chip roofline peaks: dense bf16 matmul FLOP/s and
+    HBM bytes/s."""
+
+    flops: float
+    hbm_bps: float
+
+
+# The ONE peaks table every consumer shares, keyed by jax's
+# ``device_kind``. A device that is not here gets NO utilisation figure
+# (mfu / hbm_util) from the program — never another chip's peaks.
+# Source: Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16,
+# 819 GB/s HBM per chip). GLM objective passes stream the design matrix
+# at ~2 FLOP/byte — far below the ~240 FLOP/byte compute-bound knee — so
+# the HBM line is the relevant ceiling for the solvers in this repo.
+DEVICE_PEAKS: Dict[str, DevicePeaks] = {
+    "TPU v5 lite": DevicePeaks(flops=197e12, hbm_bps=819e9),
+}
+
+
+def device_peaks(device_kind: Optional[str] = None) -> Optional[DevicePeaks]:
+    """Peaks of ``device_kind`` (default: the first jax device's), or
+    None when the table does not list it."""
+    if device_kind is None:
+        import jax
+
+        device_kind = jax.devices()[0].device_kind
+    return DEVICE_PEAKS.get(device_kind)
+
+
+def require_device_peaks() -> DevicePeaks:
+    """``device_peaks()`` for callers whose output is a utilisation
+    figure (bench.py, chip_smoke.py): an unlisted device is an error."""
+    peaks = device_peaks()
+    if peaks is None:
+        import jax
+
+        raise RuntimeError(
+            "no roofline peaks for device_kind "
+            f"{jax.devices()[0].device_kind!r}; known: "
+            f"{sorted(DEVICE_PEAKS)} (obs/xla_cost.py DEVICE_PEAKS)"
+        )
+    return peaks
+
 
 # The collective ops that matter for the scaling story (each -start
 # variant collapses onto its base op — async collectives lower as
@@ -99,18 +140,6 @@ def _sig(x: float, digits: int = 4) -> float:
     return float(f"{x:.{digits}g}")
 
 
-def _first_dict(obj) -> Optional[dict]:
-    """cost_analysis() returns a dict on some jax versions and a
-    one-element list of dicts on others; normalize."""
-    if obj is None:
-        return None
-    if isinstance(obj, dict):
-        return obj
-    if isinstance(obj, (list, tuple)) and obj and isinstance(obj[0], dict):
-        return obj[0]
-    return None
-
-
 @dataclasses.dataclass(frozen=True)
 class CostRecord:
     """One executable's static cost profile.
@@ -146,20 +175,24 @@ class CostRecord:
         self,
         seconds: float,
         passes: float = 1.0,
-        peak_flops: float = PEAK_FLOPS,
-        peak_hbm_bps: float = PEAK_HBM_BPS,
+        peaks: Optional[DevicePeaks] = None,
     ) -> Dict[str, float]:
         """Hardware attribution for ``passes`` executions of this record
         over a measured ``seconds`` window — the span-annotation payload
-        (flops / achieved_tflops / mfu / bytes_per_s / hbm_util)."""
+        (flops / achieved_tflops / bytes_per_s, plus mfu / hbm_util
+        against ``peaks``, default the running device's — omitted when
+        :data:`DEVICE_PEAKS` does not list it)."""
         out: Dict[str, float] = {}
         if seconds <= 0:
             return out
+        if peaks is None:
+            peaks = device_peaks()
         if self.flops is not None:
             fl = self.flops * passes
             out["flops"] = fl
             out["achieved_tflops"] = _sig(fl / seconds / 1e12)
-            out["mfu"] = _sig(fl / seconds / peak_flops)
+            if peaks is not None:
+                out["mfu"] = _sig(fl / seconds / peaks.flops)
         hbm_bytes = (
             self.roofline_bytes
             if self.roofline_bytes is not None
@@ -168,7 +201,8 @@ class CostRecord:
         if hbm_bytes is not None:
             bps = hbm_bytes * passes / seconds
             out["bytes_per_s"] = _sig(bps)
-            out["hbm_util"] = _sig(bps / peak_hbm_bps)
+            if peaks is not None:
+                out["hbm_util"] = _sig(bps / peaks.hbm_bps)
         return out
 
 
@@ -213,9 +247,8 @@ class CostBook:
         colls: Dict[str, int] = {}
         source = "analytic"
         if executable is not None:
-            ca = None
             try:
-                ca = _first_dict(executable.cost_analysis())
+                ca = executable.cost_analysis()
             except Exception:
                 ca = None
             if ca is not None:
@@ -347,8 +380,7 @@ def annotate_span(
     record: Optional[CostRecord],
     seconds: float,
     passes: float = 1.0,
-    peak_flops: float = PEAK_FLOPS,
-    peak_hbm_bps: float = PEAK_HBM_BPS,
+    peaks: Optional[DevicePeaks] = None,
 ) -> None:
     """Attach hardware attribution (``flops``/``achieved_tflops``/
     ``mfu``/``bytes_per_s``) to a live span from a cost-book record and a
@@ -356,9 +388,6 @@ def annotate_span(
     or the disabled-mode null span — callers never need to guard."""
     if record is None or seconds is None or seconds <= 0:
         return
-    attrs = record.achieved(
-        seconds, passes=passes,
-        peak_flops=peak_flops, peak_hbm_bps=peak_hbm_bps,
-    )
+    attrs = record.achieved(seconds, passes=passes, peaks=peaks)
     if attrs:
         sp.set(**attrs)

@@ -235,7 +235,7 @@ class GLMObjective:
         """Take the single-read fused Pallas pass? Plain ELL designs
         only (the hybrid/blocked containers keep their per-segment
         dispatch through matvec/rmatvec/colsum), under the same
-        mode/backend/VMEM eligibility as the per-op kernels."""
+        mode/VMEM eligibility as the per-op kernels."""
         from photon_ml_tpu.ops.sparse import _use_pallas_for
 
         return is_sparse(feats) and _use_pallas_for(feats, w_dtype)

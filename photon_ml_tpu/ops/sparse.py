@@ -19,16 +19,16 @@ All are single XLA ops (gather / scatter-add) that shard cleanly over the
 'data' mesh axis: indices/values are row-leading, so batch sharding and the
 psum-reduced partials work exactly as for dense features.
 
-Since PR 5 the three ELL contractions DISPATCH between that XLA lowering
-and the hand-written Pallas suite in ``photon_ml_tpu/kernels/`` (VMEM-
-resident table/accumulator, streamed row blocks — docs/KERNELS.md) per
-``PHOTON_SPARSE_KERNEL={auto,pallas,xla}``: ``auto`` takes Pallas on TPU
-(where XLA's ~90 ms/pass gather/scatter rate was the measured solve
-ceiling, BENCH_r05) and stays bit-for-bit on the XLA path off-TPU;
-``pallas`` forces the suite (interpret mode on CPU — the tier-1 proof);
-``xla`` pins today's lowering. Dispatch happens here so every consumer
-— ``GLMObjective``, GAME random-effect batches, serving scorers, the
-hybrid container's cold segments — switches with zero call-site changes.
+The three ELL contractions DISPATCH between that XLA lowering and the
+hand-written Pallas suite in ``photon_ml_tpu/kernels/`` (VMEM-resident
+table/accumulator, streamed row blocks — docs/KERNELS.md) per
+``PHOTON_SPARSE_KERNEL={auto,pallas,xla}``: ``auto`` and ``xla`` take the
+XLA lowering on every platform (the suite does not lower for TPU on the
+installed toolchain — kernels/dispatch.py); ``pallas`` forces the suite
+(interpret mode on CPU — the tier-1 proof; the compiler's own error on
+TPU). Dispatch happens here so every consumer — ``GLMObjective``, GAME
+random-effect batches, serving scorers, the hybrid container's cold
+segments — switches with zero call-site changes.
 """
 
 from __future__ import annotations
@@ -98,7 +98,7 @@ class HybridFeatures:
     one MXU/HBM pass (~n * 4 bytes at full bandwidth) regardless of
     sparsity — so any column with enough entries is cheaper densified
     (the "feature-hashing into dense-ish blocks" direction of SURVEY §7
-    hard-part 3; docs/PERF.md has the measured rates). CTR-style feature
+    hard-part 3; rates measured on chip in r5, BENCH_r05). CTR-style feature
     data is Zipf-distributed, so a small slab absorbs most entries.
 
     Because the irregular cost scales with padded SLOTS, the cold tail is
@@ -206,8 +206,7 @@ class FeatureShardedSparse:
     every (row, block) lane to the DATASET max per-block entry count, so
     at width F the stored slots — the irregular-access cost driver —
     inflate toward max/mean over rows (measured 3.7x at F=8 on the
-    bench workload, THE inverse-scaling term of BENCH_r06's
-    ``sparse_fs_scaling``). Balanced blocks instead pack each block's
+    bench workload — a count, platform-independent). Balanced blocks instead pack each block's
     entries into width-``k`` VIRTUAL rows: a row with c entries in
     block f occupies ceil(c/k) of them, and ``row_map[v, f]`` records
     the original row virtual row v of block f contributes to (sentinel
@@ -294,7 +293,7 @@ jax.tree_util.register_pytree_node(
 def _use_pallas_for(sf: "SparseFeatures", other_dtype) -> bool:
     """Route this ELL contraction to the Pallas suite? Centralizes the
     eligibility call so matvec/rmatvec/colsum cannot drift: mode knob,
-    backend/probe, VMEM budget at the contraction's COMPUTE dtype, and
+    VMEM budget at the contraction's COMPUTE dtype, and
     degenerate/sharded-batch exclusions (kernels.dispatch)."""
     n, k = sf.indices.shape[-2], sf.indices.shape[-1]
     cd = jnp.result_type(sf.values.dtype, other_dtype)
@@ -359,11 +358,11 @@ def _low_precision_dot(x: jax.Array, w: jax.Array):
     stored bf16/f16. Without this, jnp's type promotion upcasts the
     matrix to the vector's f32 — and XLA MATERIALIZES the converted
     design as a temp, so every pass pays ~3 extra design-sized HBM
-    round trips (measured r5: the dense TRON solve ran 6.0 ms/pass
-    where the roofline pass is ~1.2 ms; benchmarks/dense_roofline_lab).
-    The vector rounds to bf16 — the same precision class the stored
-    design already imposes (docs/PERF.md: coefficients agree with the
-    all-f32 solve to ~2e-4). Full-precision designs are untouched."""
+    round trips (measured on chip in r5: the dense TRON solve ran
+    6.0 ms/pass where the roofline pass is ~1.2 ms). The vector rounds
+    to bf16 — the same precision class the stored design already
+    imposes (coefficients agree with the all-f32 solve to ~2e-4).
+    Full-precision designs are untouched."""
     low_x = x.dtype in (jnp.bfloat16, jnp.float16)
     low_w = w.dtype in (jnp.bfloat16, jnp.float16)
     if low_x != low_w:  # mixed precision: round the f32 side DOWN
@@ -886,8 +885,8 @@ def to_hybrid(
 
     ``hot_columns`` = H picks the H highest-count columns; -1 sizes the
     slab automatically: columns whose stored-entry count exceeds
-    ``min_count`` (the measured v5e break-even — ~64 irregular accesses
-    cost about one dense n-row column pass, docs/PERF.md), hottest
+    ``min_count`` (the v5e break-even measured in r5 — ~64 irregular
+    accesses cost about one dense n-row column pass), hottest
     first, until the slab reaches ``max_slab_bytes`` at the target dtype.
     A slab with zero qualifying columns degrades to H=1 so shapes stay
     static.

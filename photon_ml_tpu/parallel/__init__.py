@@ -23,11 +23,9 @@ from photon_ml_tpu.parallel.mesh import (
     make_host_device_mesh,
     make_mesh,
     replicated,
-    set_mesh,
     shard_batch,
     shard_bucketed_design,
     shard_design,
-    shard_map,
 )
 from photon_ml_tpu.parallel.overlap import (
     collective_mode,

@@ -43,9 +43,7 @@ from photon_ml_tpu.parallel.mesh import (
     DATA_AXIS,
     FEATURE_AXIS,
     replicated,
-    set_mesh,
     shard_batch,
-    shard_map,
 )
 
 
@@ -63,7 +61,7 @@ def distributed_train_glm(
     deterministic for a fixed mesh shape.
     """
     sharded = shard_batch(batch, mesh)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return train_glm(sharded, config, **kwargs)
 
 
@@ -136,7 +134,7 @@ def feature_sharded_train_glm(
     if sparse_ops.is_sparse(batch.features):
         # PHOTON_COLLECTIVE_MODE=overlap row-balances the blocked
         # container (stored slots track entries, not the max lane —
-        # the BENCH_r06 inverse-scaling term); the balanced virtual-row
+        # the slot-inflation term); the balanced virtual-row
         # scatter routes within a block, so it requires the row axis
         # unsharded. fused keeps the PR-5 flat layout as the
         # equivalence oracle (docs/PARALLEL.md).
@@ -224,7 +222,7 @@ def feature_sharded_train_glm(
                 NamedSharding(mesh, P(FEATURE_AXIS)),
             )
         )
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         models = train_glm(
             padded, blocked_config, initial_coefficients=init, **kwargs
         )
@@ -275,7 +273,7 @@ def hierarchical_value_and_grad(objective: GLMObjective, mesh: Mesh):
     obj0 = dataclasses.replace(objective, axis_name=None, l2_weight=0.0)
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P((HOST_AXIS, DEVICE_AXIS))),
         out_specs=(P(), P()),
@@ -283,7 +281,7 @@ def hierarchical_value_and_grad(objective: GLMObjective, mesh: Mesh):
         # psum_scatter -> psum -> all_gather chain (it infers 'host'
         # replication from the psum but not the gathered 'device' axis);
         # the outputs ARE replicated by construction
-        check_rep=False,
+        check_vma=False,
     )
     def vg(w, batch: LabeledBatch):
         from photon_ml_tpu.kernels import dispatch as _kdispatch
@@ -304,19 +302,19 @@ def hierarchical_value_and_grad(objective: GLMObjective, mesh: Mesh):
     return vg
 
 
-def _eager_and_traced() -> bool:
-    """True when we are on the HOST side of a dispatch (not inside a jit
-    trace) AND a tracer is active — the only situation where wrapping a
-    collective dispatch in a blocking profile window is both meaningful
-    and paid for by someone who asked for it."""
+def _eager_and_traced(*args) -> bool:
+    """True when we are on the HOST side of a dispatch (no argument is a
+    jit tracer) AND an obs tracer is active — the only situation where
+    wrapping a collective dispatch in a blocking profile window is both
+    meaningful and paid for by someone who asked for it."""
     from photon_ml_tpu import obs
 
     if obs.get_tracer() is None:
         return False
-    try:
-        return jax.core.trace_state_clean()
-    except Exception:
-        return False
+    return not any(
+        isinstance(leaf, jax.core.Tracer)
+        for leaf in jax.tree_util.tree_leaves(args)
+    )
 
 
 def shard_map_value_and_grad(
@@ -339,7 +337,7 @@ def shard_map_value_and_grad(
     width = mesh.shape[DATA_AXIS]
 
     @partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P(), P(DATA_AXIS)),
         out_specs=(P(), P()),
@@ -355,7 +353,7 @@ def shard_map_value_and_grad(
             return obj.value_and_grad(w, batch)
 
     def vg(w, batch: LabeledBatch):
-        if not _eager_and_traced():
+        if not _eager_and_traced(w, batch):
             return vg_raw(w, batch)
         from photon_ml_tpu.obs import collectives as obs_coll
 

@@ -31,31 +31,6 @@ HOST_AXIS = "host"
 DEVICE_AXIS = "device"
 
 
-def set_mesh(mesh: Mesh):
-    """``jax.set_mesh(mesh)`` across jax versions. Newer jax exposes it
-    at the top level; 0.5.x spells it ``jax.sharding.use_mesh``; 0.4.x
-    uses the Mesh object itself as the context manager. Always returns a
-    context manager — call as ``with set_mesh(mesh): ...``."""
-    impl = getattr(jax, "set_mesh", None)
-    if impl is not None:
-        return impl(mesh)
-    impl = getattr(jax.sharding, "use_mesh", None)
-    if impl is not None:
-        return impl(mesh)
-    return mesh
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, **kwargs):
-    """``jax.shard_map`` across jax versions (0.4.x keeps it under
-    ``jax.experimental.shard_map``). Keyword-only like the new API."""
-    impl = getattr(jax, "shard_map", None)
-    if impl is None:
-        from jax.experimental.shard_map import shard_map as impl
-    return impl(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs
-    )
-
-
 def make_mesh(
     n_data: Optional[int] = None, devices: Optional[Sequence] = None
 ) -> Mesh:

@@ -13,8 +13,8 @@ Joining is triggered ONLY by explicit configuration — the
 JAX_COORDINATOR_ADDRESS / JAX_NUM_PROCESSES / JAX_PROCESS_ID environment
 variables or the matching arguments. (Cloud TPU metadata can fill the
 process topology once initialize() runs, but metadata presence alone is
-not treated as a signal: dev images and single-chip tunnels carry pod-ish
-variables, and a misfired join hangs waiting for peers.)
+not treated as a signal: dev images and single-chip machines carry
+pod-ish variables, and a misfired join hangs waiting for peers.)
 
 Typical driver usage::
 
@@ -306,7 +306,7 @@ def initialize_multihost(
 
     # Join only on an EXPLICIT signal (argument or env var). TPU-metadata
     # auto-detection is deliberately not used as the trigger: single-chip
-    # tunnels and dev images carry pod-ish variables, and a misfired
+    # machines and dev images carry pod-ish variables, and a misfired
     # initialize() hangs waiting for peers.
     if not (coordinator_address or (num_processes or 0) > 1):
         return False  # single-process run: nothing to join

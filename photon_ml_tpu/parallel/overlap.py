@@ -1,10 +1,9 @@
 """Collective strategy knob + the chunked overlap reduction schedule.
 
-BENCH_r06 ``sparse_fs_scaling`` still showed INVERSE multi-device
-scaling (3.78 s on 1 device, 10.43 s on 8; ``collective_wall_ms``
-128 -> 438 ms) even after PR 5 coalesced the per-pass collective COUNT
-to one. Two distinct costs remained, and this module owns the strategy
-that removes both:
+On 8 virtual CPU devices ``sparse_fs_scaling`` still scaled INVERSELY
+after PR 5 coalesced the per-pass collective COUNT to one (a CPU
+timing; real chips not measured). Two distinct costs remained, and this
+module owns the strategy that removes both:
 
 1. **The reduction schedule.** The coalesced formulation issues ONE
    bucketed all-reduce of the whole (n + P,) payload at the END of the
@@ -22,7 +21,7 @@ that removes both:
 2. **The blocked-ELL padding inflation.** ``ops.sparse.shard_columns``
    pads every (row, block) lane to the DATASET max entry count; at
    width 8 a mean-4 lane pads to the max ~15 and the stored slot count
-   (the irregular-access cost driver, docs/PERF.md) inflates ~3.7x —
+   (the irregular-access cost driver) inflates ~3.7x —
    the dominant inverse-scaling term measured on the bench box. The
    ``overlap`` strategy row-balances the blocked container
    (``shard_columns(..., balance_rows=True)``): each block packs its
@@ -42,7 +41,7 @@ that removes both:
   the win is gated, not asserted.
 
 The chunked schedule only activates under an ACTIVE mesh that carries
-the 'feature' axis (``parallel.mesh.set_mesh``); everywhere else both
+the 'feature' axis (``jax.set_mesh``); everywhere else both
 modes lower to the identical local sum, so single-device numerics are
 bit-for-bit unchanged.
 """
@@ -50,7 +49,6 @@ bit-for-bit unchanged.
 from __future__ import annotations
 
 import os
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -61,8 +59,6 @@ __all__ = [
     "COLLECTIVE_MODES",
     "collective_mode",
     "overlap_chunks",
-    "active_mesh",
-    "active_axis_size",
     "feature_block_sum",
 ]
 
@@ -101,62 +97,16 @@ def overlap_chunks() -> int:
     return max(1, c)
 
 
-def active_mesh():
-    """The mesh installed by ``parallel.mesh.set_mesh`` (None when no
-    mesh context is active), readable from INSIDE a jit trace — the
-    0.4.x ``with mesh:`` form and newer ``jax.set_mesh`` both land in
-    thread-local state. Best-effort: an unreadable context reports None
-    and callers fall back to the fused schedule."""
-    try:
-        from jax._src import mesh as mesh_lib
-
-        env = mesh_lib.thread_resources.env
-        physical = env.physical_mesh
-        if getattr(physical, "size", 0) >= 1 and physical.axis_names:
-            return physical
-    except Exception:
-        pass
-    try:  # newer jax: abstract mesh context
-        from jax._src import mesh as mesh_lib
-
-        am = mesh_lib.get_abstract_mesh()
-        if am is not None and getattr(am, "size", 0) >= 1 and am.axis_names:
-            return am
-    except Exception:
-        pass
-    return None
-
-
-def active_axis_size(axis_name: str) -> int:
-    """Extent of ``axis_name`` on the active mesh (1 when absent)."""
-    mesh = active_mesh()
-    if mesh is None:
-        return 1
-    try:
-        return int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name])
-    except Exception:
-        return 1
-
-
 def _feature_axis_sharding(axis_name: str):
-    """(per-chunk sharded, replicated) NamedShardings over the active
-    mesh's ``axis_name``, or None when no such mesh axis is active."""
+    """(per-chunk sharded, replicated) NamedShardings over the mesh that
+    ``jax.set_mesh`` installed, or None when it has no ``axis_name`` axis
+    of extent >= 2. Readable from inside a jit trace."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    mesh = active_mesh()
-    if mesh is None or axis_name not in mesh.axis_names:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.shape.get(axis_name, 1) < 2:
         return None
-    if int(dict(zip(mesh.axis_names, mesh.devices.shape))[axis_name]) < 2:
-        return None
-    try:
-        # the constraint needs a CONCRETE mesh; abstract contexts fall
-        # back to the fused schedule
-        return (
-            NamedSharding(mesh, P(axis_name)),
-            NamedSharding(mesh, P()),
-        )
-    except Exception:
-        return None
+    return NamedSharding(mesh, P(axis_name)), NamedSharding(mesh, P())
 
 
 def feature_block_sum(

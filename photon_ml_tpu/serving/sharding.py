@@ -188,8 +188,7 @@ def route_batch(
     ``serving.shard_route`` once per involved shard; a raise/corrupt
     fault marks the shard down (its RE gathers degrade to -1).
 
-    The host-side routing cost BENCH_r08 exposed (sharded 2.4k qps vs
-    unsharded 4.7k) is decomposed into ``serving.route.{group,pad}``
+    The host-side routing cost is decomposed into ``serving.route.{group,pad}``
     spans + ``_ms`` histograms here (``serving.route.merge`` lives on
     :meth:`RoutedBatch.merge`) so ROADMAP item 2's dispatch-free attack
     has a measured per-stage baseline."""
@@ -477,7 +476,7 @@ class ShardedScoringEngine(ScoringEngine):
     def _make_scorers(self):
         from jax.sharding import PartitionSpec as P
 
-        from photon_ml_tpu.parallel.mesh import ENTITY_AXIS, shard_map
+        from photon_ml_tpu.parallel.mesh import ENTITY_AXIS
 
         def shard_body(params, feats, ents, fixed_mask):
             # per shard: (1, bucket, ...) routed blocks + this shard's
@@ -513,12 +512,12 @@ class ShardedScoringEngine(ScoringEngine):
                 {rk: P(ENTITY_AXIS, None) for rk in self._re_keys},
                 P(ENTITY_AXIS, None),
             )
-            return shard_map(
+            return jax.shard_map(
                 shard_body,
                 mesh=self.mesh,
                 in_specs=in_specs,
                 out_specs=P(ENTITY_AXIS, None),
-                check_rep=False,
+                check_vma=False,
             )(params, feats, ents, fixed_mask)
 
         self._scorer = jax.jit(sharded_scorer)

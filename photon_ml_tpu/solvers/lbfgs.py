@@ -126,9 +126,7 @@ def _two_loop(h: _History, grad: jax.Array) -> jax.Array:
     The sequential form issues ~4m small sharded-vector ops per
     direction, and under a 'feature' mesh every ``vdot`` over the
     sharded coefficient axis is its OWN scalar all-reduce — ~2m
-    collective latencies per L-BFGS iteration, which BENCH_r06's
-    inverse-scaling chase measured as a dominant per-width overhead
-    (docs/PARALLEL.md). Here the cross-terms come from one (m, m) Gram
+    collective latencies per L-BFGS iteration (docs/PARALLEL.md). Here the cross-terms come from one (m, m) Gram
     ``G = S Y^T`` plus two stacked history-vector products, so a
     direction costs O(1) collectives regardless of m; the recurrences
     themselves run on (m,)-replicated scalars. Expanding the recursion:

@@ -1,7 +1,6 @@
 from photon_ml_tpu.utils.logging import PhotonLogger, timed
 from photon_ml_tpu.utils.dates import DateRange, expand_date_paths
 from photon_ml_tpu.utils.compile_cache import enable_compilation_cache
-from photon_ml_tpu.utils.compat import force_cpu_devices
 
 __all__ = [
     "PhotonLogger",
@@ -9,5 +8,4 @@ __all__ = [
     "DateRange",
     "expand_date_paths",
     "enable_compilation_cache",
-    "force_cpu_devices",
 ]

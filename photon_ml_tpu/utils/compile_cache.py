@@ -1,56 +1,50 @@
 """Persistent XLA compilation cache for the drivers and benchmarks.
 
-Compile time dwarfs steady-state solve time on every benchmark config
-(first dense solve ~26s vs 0.09s steady-state; GAME warmups 16-70s), and
-the reference has no analog — Spark ships jars, XLA re-JITs per process.
-Wiring jax's persistent compilation cache into every CLI entry point
-makes the SECOND process's warmup a disk load instead of a re-compile
-(driver re-runs, lambda-grid re-submissions, scoring after training).
+Compile time dwarfs steady-state solve time on every benchmark config,
+and the reference has no analog — Spark ships jars, XLA re-JITs per
+process. Wiring jax's persistent compilation cache into every CLI entry
+point makes the SECOND process's warmup a disk load instead of a
+re-compile (driver re-runs, lambda-grid re-submissions, scoring after
+training).
 
-The cache key includes the jaxlib version, backend, and HLO, so stale
-entries are never reused; the directory is safe to share between
-concurrent processes (entries are content-addressed files).
+Where the cache lives is decided from OUTSIDE the program: when
+``JAX_COMPILATION_CACHE_DIR`` is set, jax already points there and this
+module sets no directory. Otherwise it is ONE fixed path inside the
+checkout: a cache that moves (per host, per backend, per run) is a
+cache that never hits. Programs compiled before a directory is known
+are not cached, so the drivers call this first. The cache key includes
+the jaxlib version, backend, and HLO, so stale entries are never
+reused; the directory is safe to share between concurrent processes
+(entries are content-addressed files).
 """
 
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-_DEFAULT_DIR = os.environ.get(
-    "PHOTON_ML_COMPILE_CACHE",
-    os.path.join(
-        os.path.expanduser("~"), ".cache", "photon_ml_tpu", "xla_cache"
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+
+# <checkout>/.jax_cache (git-ignored), used only when the variable is unset
+_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     ),
+    ".jax_cache",
 )
 
 
-def enable_compilation_cache(cache_dir: Optional[str] = None) -> str:
+def enable_compilation_cache() -> str:
     """Enable jax's persistent compilation cache (safe to call more than
-    once — the config updates are themselves idempotent).
-
-    Returns the cache directory in use. Callable any time (before or
-    after first jax use); entries persist across processes. Set
-    ``PHOTON_ML_COMPILE_CACHE=off`` to disable (e.g. hermetic tests).
+    once — the config updates are themselves idempotent). Returns the
+    directory in use.
     """
-    path = cache_dir or _DEFAULT_DIR
-    if path.lower() == "off":
-        return path
     import jax
 
-    if cache_dir is None:
-        # namespace by backend + host: entries are keyed by backend but
-        # NOT by the compiling machine's CPU features, and this stack can
-        # compile CPU programs on a remote helper — a shared dir then
-        # serves AOT results with unsupported ISA features ("could lead
-        # to SIGILL" warnings, observed with +prefer-no-gather entries)
-        import platform
-
-        path = os.path.join(
-            path, f"{jax.default_backend()}-{platform.node()}"
-        )
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+    path = os.environ.get(CACHE_DIR_ENV)
+    if not path:
+        path = _CHECKOUT_DIR
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
     # cache everything: the default min-compile-time threshold skips the
     # small per-coordinate programs whose dispatch-sized compiles still
     # add up across a grid sweep

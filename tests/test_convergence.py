@@ -652,7 +652,7 @@ class TestSentinelDirections:
 
     def test_history_not_flagged(self):
         """The new convergence.* metrics must not flag the committed
-        r01-r05 history (they are new; growth is not a regression)."""
+        r03-r05 history (they are new; growth is not a regression)."""
         import glob
 
         from photon_ml_tpu.obs.sentinel import run_sentinel

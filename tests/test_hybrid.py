@@ -2,7 +2,7 @@
 the power-law split must be algebraically invisible — every kernel,
 statistic, validator, and full solve agrees with the plain-ELL (and hence
 dense) semantics on the same matrix. The representation exists purely for
-the measured TPU cost model (docs/PERF.md: every ELL SLOT pays ~8 ns of
+the TPU cost model measured in r5 (every ELL SLOT pays ~8 ns of
 irregular access; a dense slab column rides the MXU at full bandwidth),
 so rows live in a permuted, cold-count-bucketed order — ``row_perm``
 maps stored back to original."""

@@ -266,7 +266,7 @@ class TestModelPersistence:
         assert evocabs == {"per-user": entity_vocab}
 
         # Without a caller-supplied entity vocab the row<->entity mapping is
-        # returned (ADVICE r1: it must never be lost) and indexing the table
+        # returned (it must never be lost) and indexing the table
         # through it recovers the same per-entity coefficients.
         params2, _, _, evocabs2 = load_game_model(
             root, vocabs={"global": g_vocab, "per-user": u_vocab}

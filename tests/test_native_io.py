@@ -116,7 +116,7 @@ class TestLabeledBatch:
 
     def test_streamed_matches_whole_read(self, tmp_path):
         """labeled_batch_streamed (per-file decode + async device
-        transfers, VERDICT r4 #6) must assemble the identical batch the
+        transfers) must assemble the identical batch the
         whole-dataset path builds, across multiple part files with
         different row counts."""
         paths = []
@@ -474,7 +474,7 @@ class TestEmptyVocabScan:
     def test_empty_file_build_vocab_raises(self, tmp_path):
         """A valid-but-empty input must fail build_vocab loudly on BOTH
         toolchains — the native scan must not silently yield an
-        intercept-only vocabulary (advisor r3)."""
+        intercept-only vocabulary."""
         path = str(tmp_path / "empty.avro")
         write_avro_file(path, TRAINING_EXAMPLE_SCHEMA, [])
         with pytest.raises(ValueError, match="no records found"):
@@ -738,7 +738,7 @@ class TestNativeWriter:
 
     def test_float_fields_roundtrip(self, tmp_path):
         """float / [null, float] fields take the 4-byte wire op — a
-        double-width encode silently corrupted these (advisor r3: 1.5
+        double-width encode silently corrupted these (1.5
         read back as 0.0)."""
         from photon_ml_tpu.io.avro import read_avro_file
         from photon_ml_tpu.io.native import write_columnar_avro

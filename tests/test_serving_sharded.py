@@ -160,7 +160,16 @@ class TestRouting:
 
 
 class TestShardedEquivalence:
-    @pytest.mark.parametrize("num_shards", [2, 4, 8])
+    @pytest.mark.parametrize(
+        "num_shards",
+        # tier-1 keeps the four-chip host's width; 2 and 8 are the same
+        # assertions at other widths (slow tier, ROADMAP D10)
+        [
+            pytest.param(2, marks=pytest.mark.slow),
+            4,
+            pytest.param(8, marks=pytest.mark.slow),
+        ],
+    )
     def test_matches_unsharded_and_offline(self, rng, devices, num_shards):
         params, shards, res = _model(rng)
         feats, ents = _batch(rng, 37)
@@ -330,7 +339,9 @@ class TestShardedCheckpointLoad:
         )
         return step_dir, fixed, table, keys
 
-    @pytest.mark.parametrize("serve_shards", [2, 4])
+    @pytest.mark.parametrize(
+        "serve_shards", [pytest.param(2, marks=pytest.mark.slow), 4]
+    )
     def test_resume_at_different_shard_count(
         self, rng, devices, tmp_path, serve_shards
     ):

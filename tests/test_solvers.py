@@ -304,13 +304,14 @@ class TestNewton:
 
         from photon_ml_tpu.solvers.newton import _small_cho_solve
 
+        # jitted, as every solver call site runs it: the eager form
+        # dispatches ~d^3/6 tiny programs one by one (55 s at d=32)
+        solve = jax.jit(_small_cho_solve)
         for d in (1, 2, 4, 16, 32):
             a = rng.normal(size=(d, d))
             h = a @ a.T + 5.0 * np.eye(d)
             b = rng.normal(size=d)
-            got = np.asarray(
-                _small_cho_solve(jnp.asarray(h), jnp.asarray(b))
-            )
+            got = np.asarray(solve(jnp.asarray(h), jnp.asarray(b)))
             ref = scipy.linalg.cho_solve(scipy.linalg.cho_factor(h), b)
             np.testing.assert_allclose(got, ref, rtol=1e-9, atol=1e-10)
         # batched under vmap

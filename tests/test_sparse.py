@@ -188,7 +188,7 @@ class TestSparseObjective:
         assert np.abs(w_sparse[off]).max() < 1e-10
 
     def test_sparse_batch_shards_over_mesh(self, rng, devices):
-        from photon_ml_tpu.parallel import make_mesh, set_mesh, shard_batch
+        from photon_ml_tpu.parallel import make_mesh, shard_batch
 
         dense, sparse, _ = self._batches(rng, n=253, d=20, nnz=4)
         obj = GLMObjective(loss=LOGISTIC_LOSS, l2_weight=0.2)
@@ -197,7 +197,7 @@ class TestSparseObjective:
         mesh = make_mesh()
         sharded = shard_batch(sparse, mesh)
         assert sharded.batch_size == 256  # padded to 8 devices
-        with set_mesh(mesh):
+        with jax.set_mesh(mesh):
             v_dist, g_dist = jax.jit(obj.value_and_grad)(w, sharded)
         np.testing.assert_allclose(float(v_dist), float(v_local), rtol=1e-12)
         np.testing.assert_allclose(

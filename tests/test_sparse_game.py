@@ -277,7 +277,7 @@ class TestBuildIndexJob:
 
 
 class TestWideSparseRandomEffect:
-    """VERDICT r3 #5: a SPARSE shard trains a random effect through
+    """A SPARSE shard trains a random effect through
     INDEX_MAP projection (per-entity active-column unions,
     ``RandomEffectCoordinateInProjectedSpace.scala:26-120``,
     ``IndexMapProjectorRDD.scala:113-120``)."""

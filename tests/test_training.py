@@ -288,7 +288,7 @@ class TestValidationMatrix:
 class TestHashableBounds:
     """Box bounds are stored as content-hashed HashableBounds so configs
     key the lru_cache'd solver builder in O(1) instead of hashing a
-    d_block-length float tuple per solve (advisor r4)."""
+    d_block-length float tuple per solve."""
 
     def test_wrap_equality_and_hash(self):
         from photon_ml_tpu.models.training import HashableBounds
