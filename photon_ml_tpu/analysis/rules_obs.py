@@ -11,8 +11,9 @@ name outside it a build-time error.
 
 Checked call shapes:
 
-- ``obs.span("...")`` / ``span("...")`` and ``obs.emit_event`` — the
-  tracer surface;
+- ``obs.span("...")`` / ``span("...")``, the retro-stamped
+  ``obs.add_span("...")`` and ``obs.emit_event`` — the span and event
+  surface;
 - registry instrument calls — ``.inc`` / ``.set_gauge`` / ``.observe``
   / ``.counter`` / ``.gauge`` / ``.histogram`` on a receiver that is
   recognizably a metrics registry (``reg``, ``registry()``,
@@ -39,7 +40,7 @@ from photon_ml_tpu.analysis.core import (
 
 __all__ = ["ObsTaxonomyRule"]
 
-_TRACER_FNS = frozenset({"span", "emit_event"})
+_TRACER_FNS = frozenset({"span", "add_span", "emit_event"})
 _REGISTRY_METHODS = frozenset(
     {"inc", "set_gauge", "observe", "counter", "gauge", "histogram"}
 )
@@ -91,7 +92,7 @@ class ObsTaxonomyRule(Rule):
     def _name_arg(self, call: ast.Call) -> Optional[ast.AST]:
         last, full = call_name(call)
         if last in _TRACER_FNS:
-            # plain span()/emit_event() or obs.span()/tracer-qualified
+            # plain span()/add_span()/emit_event(), or obs./tracer-qualified
             if call.args:
                 return call.args[0]
             return None

@@ -90,9 +90,14 @@ import argparse
 import json
 import sys
 import threading
+import time
 from typing import Optional
 
-from photon_ml_tpu.serving.batcher import Backpressure, MicroBatcher
+from photon_ml_tpu.serving.batcher import (
+    Backpressure,
+    MicroBatcher,
+    record_request,
+)
 from photon_ml_tpu.serving.engine import ScoreRequest
 from photon_ml_tpu.serving.registry import ModelRegistry
 from photon_ml_tpu.serving.stats import ServingStats, SloTracker
@@ -249,7 +254,14 @@ def serve_lines(
     to ``window`` requests outstanding (which is what fills micro-batches
     from a single stream). Commands execute at read time; their replies
     take their place in the output order. Returns the number of scored
-    requests."""
+    requests.
+
+    Every scored request leaves ONE ``serving.request`` record in the
+    flight ring (``obs.recent_spans()``), written here by the writer
+    thread once the reply is flushed: it runs from ``received`` (the line
+    in hand, before the JSON parse) to ``replied`` and carries the
+    batcher's stamps ``enqueued`` / ``flush`` / ``scored`` in between
+    (``serving.batcher.record_request``)."""
     import queue as queue_mod
 
     outbox: "queue_mod.Queue" = queue_mod.Queue(maxsize=window)
@@ -261,7 +273,7 @@ def serve_lines(
             item = outbox.get()
             if item is None:
                 return
-            kind, payload = item
+            kind, payload, received = item
             if kind == "score":
                 try:
                     reply = json.dumps({"score": payload.result()})
@@ -270,19 +282,24 @@ def serve_lines(
                     reply = json.dumps({"error": str(e)})
             else:
                 reply = payload
-            if broken:
-                continue  # output gone: keep draining so readers don't block
-            try:
-                out.write(reply + "\n")
-                out.flush()
-            except Exception:  # noqa: BLE001 — e.g. client hung up
-                broken = True
+            if not broken:
+                try:
+                    out.write(reply + "\n")
+                    out.flush()
+                except Exception:  # noqa: BLE001 — e.g. client hung up
+                    # keep draining so readers don't block
+                    broken = True
+            if kind == "score":
+                record_request(
+                    getattr(payload, "request_stamps", None),
+                    received, time.perf_counter(),
+                )
 
     wt = threading.Thread(target=writer, name="serve-writer", daemon=True)
     wt.start()
 
     def reply_now(obj: dict) -> None:
-        outbox.put(("line", json.dumps(obj)))
+        outbox.put(("line", json.dumps(obj), None))
 
     handle_cmd = make_admin_handler(
         batcher, registry, stats, quality=quality, tenants=tenants,
@@ -293,6 +310,7 @@ def serve_lines(
         for line in lines:
             if shutdown is not None and shutdown.requested:
                 break
+            received = time.perf_counter()
             line = line.strip()
             if not line:
                 continue
@@ -318,7 +336,9 @@ def serve_lines(
                                 else None
                             ),
                             priority=int(obj.get("priority", 0)),
+                            received=received,
                         ),
+                        received,
                     )
                 )
             except (Backpressure, ValueError, TypeError) as e:
@@ -341,10 +361,11 @@ class _CompatBatcher:
         self.stats = tm.stats
         self.slo = tm.batcher.slo
 
-    def submit(self, request, *, deadline_ms=None, priority=None):
+    def submit(self, request, *, deadline_ms=None, priority=None,
+               received=None):
         return self._tm.submit(
             self.tenant, request,
-            deadline_ms=deadline_ms, priority=priority,
+            deadline_ms=deadline_ms, priority=priority, received=received,
         )
 
     def health(self):

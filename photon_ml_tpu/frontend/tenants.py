@@ -218,6 +218,7 @@ class TenantManager:
         priority: Optional[int] = None,
         trace: Optional[str] = None,
         wire_read_ms: Optional[float] = None,
+        received: Optional[float] = None,
     ) -> Future:
         """Admit one request under the tenant's policy; the Future
         resolves to its float score. ``deadline_ms``/``priority``
@@ -225,7 +226,8 @@ class TenantManager:
         channel's per-line fields keep working through the shared
         queue); ``trace``/``wire_read_ms`` thread the frontend's
         request-causality fields through the envelope unchanged
-        (docs/OBSERVABILITY.md). Raises :class:`UnknownTenant`,
+        (docs/OBSERVABILITY.md), ``received`` likewise
+        (``MicroBatcher.submit``). Raises :class:`UnknownTenant`,
         :class:`Backpressure`
         (queue full past the shed policy, or the quota seam failing
         closed), or surfaces :class:`DeadlineExceeded` through the
@@ -263,6 +265,7 @@ class TenantManager:
                 over_quota=over,
                 trace=trace,
                 wire_read_ms=wire_read_ms,
+                received=received,
             )
         except Backpressure:
             with st._lock:

@@ -177,15 +177,16 @@ def _make_gathered_solve_cached(config: CoordinateConfig, budget: int):
         idx = order[:budget]
         valid = kept[idx]
         sub_mask = jnp.where(valid, mask[idx], 0.0)
-        result = solve(
-            w,
-            reg_weight,
-            features[idx],
-            labels[idx],
-            offsets[idx],
-            jnp.where(valid, weights[idx], 0.0),
-            sub_mask,
-        )
+        with jax.named_scope("fe_solve"):
+            result = solve(
+                w,
+                reg_weight,
+                features[idx],
+                labels[idx],
+                offsets[idx],
+                jnp.where(valid, weights[idx], 0.0),
+                sub_mask,
+            )
         # rescore the FULL batch in the same dispatch
         return result, features @ result.w
 
@@ -206,7 +207,10 @@ def _make_fixed_update_and_score_cached(config: CoordinateConfig):
 
     @jax.jit
     def run(w, reg_weight, features, labels, offsets, weights, mask):
-        result = solve(w, reg_weight, features, labels, offsets, weights, mask)
+        with jax.named_scope("fe_solve"):
+            result = solve(
+                w, reg_weight, features, labels, offsets, weights, mask
+            )
         return result, features @ result.w
 
     return run
@@ -230,7 +234,10 @@ def _make_fixed_update_and_score_permuted_cached(config: CoordinateConfig):
     def run(w, reg_weight, features, labels, offsets_base, partial_scores,
             weights, mask, perm, inv):
         offsets = offsets_base + partial_scores[perm]
-        result = solve(w, reg_weight, features, labels, offsets, weights, mask)
+        with jax.named_scope("fe_solve"):
+            result = solve(
+                w, reg_weight, features, labels, offsets, weights, mask
+            )
         return result, (features @ result.w)[inv]
 
     return run
@@ -563,10 +570,11 @@ def _make_multi_bucket_update_cached(config: CoordinateConfig):
             offsets = bucket.gather_offsets(full_offsets)
             w0 = jnp.take(table, eidx, axis=0, mode="clip")
             lam = jnp.take(reg_weights, eidx, mode="clip")
-            result = solve(
-                w0, lam, bucket.features, bucket.labels, offsets,
-                bucket.weights, bucket.mask,
-            )
+            with jax.named_scope("re_newton_solve"):
+                result = solve(
+                    w0, lam, bucket.features, bucket.labels, offsets,
+                    bucket.weights, bucket.mask,
+                )
             table = table.at[eidx].set(result.w, mode="drop")
             # final per-entity gradient norm rides the tracker tuple
             # (valid with tracking on or off), feeding the fleet-level
@@ -990,10 +998,11 @@ class EntityShardedRandomEffectCoordinate:
                     offs = bucket.gather_offsets(off_blk)
                     w0 = jnp.take(table_blk, li, axis=0, mode="clip")
                     lam = jnp.take(reg_blk, li, mode="clip")
-                    result = solve(
-                        w0, lam, bucket.features, bucket.labels, offs,
-                        bucket.weights, bucket.mask,
-                    )
+                    with jax.named_scope("re_newton_solve"):
+                        result = solve(
+                            w0, lam, bucket.features, bucket.labels, offs,
+                            bucket.weights, bucket.mask,
+                        )
                     table_blk = table_blk.at[li].set(
                         result.w, mode="drop"
                     )

@@ -12,6 +12,8 @@ reference's per-coordinate score RDDs with fullOuterJoin accumulation
 from __future__ import annotations
 
 import dataclasses
+import functools
+import itertools
 import time
 from typing import Dict, List, Mapping, Optional, Sequence
 
@@ -208,6 +210,37 @@ def _loss_fn_for_task(task: TaskType):
     if task == TaskType.POISSON_REGRESSION:
         return metrics_mod.total_poisson_loss
     raise ValueError(f"no GAME training evaluator for {task}")
+
+
+def _tree_bytes(tree) -> int:
+    """Bytes of a tree's array leaves (the ``bytes`` of a ``game.fetch``)."""
+    return sum(
+        int(getattr(leaf, "nbytes", 0))
+        for leaf in jax.tree_util.tree_leaves(tree)
+    )
+
+
+# identifies one CoordinateDescent.run in its spans (the `job` attribute)
+_RUN_IDS = itertools.count(1)
+
+
+def _spanned_run(run):
+    """``run`` under its root span ``game.cd.run``. Inside it every call
+    into a compiled pass is a ``game.dispatch`` span (until the call
+    returns: enqueue, not completion), every blocking device-to-host read
+    a ``game.fetch`` (until the value is on the host) and the host-side
+    tape decode a ``game.decode``; what none of them covers is the root's
+    self time. No span synchronises."""
+
+    @functools.wraps(run)
+    def spanned(self, num_iterations, *args, **kwargs):
+        with obs.span(
+            "game.cd.run", cat="game", job=next(_RUN_IDS),
+            iterations=int(num_iterations),
+        ):
+            return run(self, num_iterations, *args, **kwargs)
+
+    return spanned
 
 
 class _AsyncCheckpointWriter:
@@ -591,27 +624,7 @@ class CoordinateDescent:
         return self._chunk_fns, states
 
 
-    def _pass_cost(self, label: str, lower_thunk):
-        """Cost-book record for one dispatch program (a chunked
-        coordinate step or the fused whole-pass), lazily lowered via
-        ``lower_thunk`` and cached on the instance — the lowering is a
-        re-trace, so it runs once per (CD, program) and only when a
-        tracer asked for attribution. Analysis uses the LOWERED stage:
-        no backend compile, so the run's zero-recompile invariants
-        (``xla.compiles``) are untouched. Returns None when the program
-        cannot be analyzed; attribution is best-effort."""
-        cache = getattr(self, "_pass_cost_records", None)
-        if cache is None:
-            cache = self._pass_cost_records = {}
-        if label not in cache:
-            try:
-                cache[label] = obs.cost_book().record(
-                    "game.update", lower_thunk(), bucket=label
-                )
-            except Exception:
-                cache[label] = None
-        return cache[label]
-
+    @_spanned_run
     def run(
         self,
         num_iterations: int,
@@ -810,9 +823,12 @@ class CoordinateDescent:
                 ]
                 frozen = (set(ckpt.frozen) & set(names)) | seed_frozen
 
-        scores = {
-            n: self.coordinates[n].score(model.params[n]) for n in names
-        }
+        with obs.span("game.dispatch", cat="game", kind="score",
+                      coordinates=len(names)):
+            scores = {
+                n: self.coordinates[n].score(model.params[n])
+                for n in names
+            }
 
         # Per-update device stats stay ON DEVICE during the loop (objective
         # scalar, per-entity solver trackers) so consecutive updates
@@ -879,7 +895,16 @@ class CoordinateDescent:
                 )
 
                 fetch = jax.tree_util.tree_map(reshard_replicated, fetch)
-            host = jax.device_get(fetch)
+            with obs.span("game.fetch", cat="game", what="history",
+                          bytes=_tree_bytes(fetch)):
+                host = jax.device_get(fetch)
+            with obs.span("game.decode", cat="game", updates=len(pending)):
+                _decode(host, conv_enabled, _conv)
+            pending.clear()
+
+        def _decode(host, conv_enabled, _conv):
+            """Tape decode and history records on the host, for the
+            backlog ``materialize`` has just fetched."""
             for p, (obj, tr) in zip(pending, host):
                 result = p.pop("result")
                 raw = getattr(result, "pending", None)
@@ -944,7 +969,6 @@ class CoordinateDescent:
                         grad_norms=grad_norms,
                         entity_ids=entity_ids,
                     )
-            pending.clear()
 
         # the fused path needs the FULL trace-safe surface, not just
         # update_step — a custom coordinate providing only update/score
@@ -1001,15 +1025,18 @@ class CoordinateDescent:
             # host snapshot: params / key / history copied now (the
             # write must capture THIS boundary, not whatever the next
             # pass mutates)
-            params_host = {
-                n: jax.tree_util.tree_map(
-                    lambda a: np.asarray(a), model.params[n]
-                )
-                for n in names
-            }
+            with obs.span("game.fetch", cat="game", what="checkpoint",
+                          bytes=_tree_bytes(model.params)):
+                params_host = {
+                    n: jax.tree_util.tree_map(
+                        lambda a: np.asarray(a), model.params[n]
+                    )
+                    for n in names
+                }
+                key_host = np.asarray(key)
             return (
                 params_host,
-                np.asarray(key),
+                key_host,
                 [dataclasses.asdict(h) for h in history],
                 sorted(frozen),
             )
@@ -1114,18 +1141,21 @@ class CoordinateDescent:
                 save_checkpoint_sharded_final,
             )
 
-            params_host = {
-                n: jax.tree_util.tree_map(
-                    lambda a: np.asarray(a), model.params[n]
-                )
-                for n in names
-            }
+            with obs.span("game.fetch", cat="game", what="checkpoint",
+                          bytes=_tree_bytes(model.params)):
+                params_host = {
+                    n: jax.tree_util.tree_map(
+                        lambda a: np.asarray(a), model.params[n]
+                    )
+                    for n in names
+                }
+                key_host = np.asarray(key)
             ckpt_writer.join()
             save_checkpoint_sharded_final(
                 checkpoint_dir,
                 step,
                 params_host,
-                np.asarray(key),
+                key_host,
                 history=[dataclasses.asdict(h) for h in history],
                 frozen=sorted(frozen),
                 entity_keys=(
@@ -1213,10 +1243,7 @@ class CoordinateDescent:
         # the rollback/damp/freeze policy), then resume superpassing
         force_plain = False
         while it < num_iterations:
-            tracer = obs.get_tracer()
             pass_t0 = time.perf_counter()
-            pass_ts = tracer.now_us() if tracer is not None else 0.0
-            pass_recs = []  # cost records of this pass's dispatches
             if use_super and not frozen and not force_plain:
                 # dispatch chunk: K passes, shrunk to land exactly on
                 # the checkpoint cadence and the run end — checkpoint /
@@ -1232,22 +1259,15 @@ class CoordinateDescent:
                 params_in = {n: model.params[n] for n in names}
                 tol_arr = jnp.asarray(float(convergence_tolerance))
                 guard_arr = jnp.asarray(bool(divergence_guard))
-                rec = None
-                if tracer is not None:
-                    rec = self._pass_cost(
-                        f"superpass-{chunk}",
-                        lambda: self._superpass_progs[chunk].lower(
-                            sp_states, self.labels, self.base_offsets,
-                            self.weights, params_in, scores, key,
-                            tol_arr, guard_arr,
-                        ),
-                    )
-                    pass_recs.append(rec)
                 t0 = time.perf_counter()
-                (params_out, scores, key, passes_dev, guard_dev,
-                 conv_dev, objs_tape, tr_tapes) = sp_call(
-                    params_in, scores, key, tol_arr, guard_arr
-                )
+                with obs.span(
+                    "game.dispatch", cat="game", kind="superpass",
+                    iteration=it, passes=chunk, coordinates=len(names),
+                ):
+                    (params_out, scores, key, passes_dev, guard_dev,
+                     conv_dev, objs_tape, tr_tapes) = sp_call(
+                        params_in, scores, key, tol_arr, guard_arr
+                    )
                 model.params.update(params_out)
                 seconds = time.perf_counter() - t0
                 if divergence_guard or convergence_tolerance > 0:
@@ -1255,9 +1275,11 @@ class CoordinateDescent:
                     # them synchronizes — ONE sync per K passes. With
                     # neither feature on they are statically known and
                     # the dispatch chain stays fully pipelined.
-                    passes_done = int(passes_dev)
-                    guard = bool(guard_dev)
-                    converged = bool(conv_dev)
+                    with obs.span("game.fetch", cat="game",
+                                  what="superpass_flags"):
+                        passes_done = int(passes_dev)
+                        guard = bool(guard_dev)
+                        converged = bool(conv_dev)
                 else:
                     passes_done, guard, converged = chunk, False, False
                 for p in range(passes_done):
@@ -1285,59 +1307,7 @@ class CoordinateDescent:
                                 ),
                             }
                         )
-                if tracer is not None:
-                    sp_args = {
-                        "iteration": it,
-                        "chunk": chunk,
-                        "passes": passes_done,
-                        "coordinates": len(names),
-                        "guard": guard,
-                        "converged": converged,
-                        "timing": "wall",
-                    }
-                    try:
-                        from photon_ml_tpu.kernels import kernel_mode
-
-                        sp_args["sparse_kernel"] = kernel_mode()
-                    except Exception:
-                        pass
-                    if rec is not None and passes_done:
-                        # XLA's cost analysis counts the while body
-                        # ONCE; scale by the passes that actually ran
-                        sp_args.update(
-                            rec.achieved(seconds, passes=passes_done)
-                        )
-                    tracer.add_span(
-                        "game.superpass", pass_ts, seconds * 1e6,
-                        cat="game", args=sp_args,
-                    )
-                    # per-pass / per-coordinate spans share even splits
-                    # of the indivisible window (same contract as the
-                    # fused pass's shared-window coordinate spans)
-                    share = seconds * 1e6 / max(passes_done, 1)
-                    for p in range(passes_done):
-                        tracer.add_span(
-                            "game.pass", pass_ts + p * share, share,
-                            cat="game",
-                            args={
-                                "iteration": it + p,
-                                "coordinates": len(names),
-                                "fused": True,
-                                "superpass": True,
-                                "timing": "wall",
-                            },
-                        )
-                        for name in names:
-                            tracer.add_span(
-                                "game.update", pass_ts + p * share,
-                                share, cat="game",
-                                args={
-                                    "coordinate": name,
-                                    "iteration": it + p,
-                                    "fused": True,
-                                    "superpass": True,
-                                },
-                            )
+                if obs.get_tracer() is not None:
                     obs.sample_hbm()
                 it += passes_done
                 _reg = obs.registry()
@@ -1401,43 +1371,18 @@ class CoordinateDescent:
             if use_fused:
                 params_in = {n: model.params[n] for n in names}
                 fused = self._fused_pass_fn()
-                if tracer is not None:
-                    fstates = {
-                        n: self.coordinates[n].fused_state() for n in names
-                    }
-                    pass_recs.append(
-                        self._pass_cost(
-                            "fused",
-                            lambda: self._fused_pass.lower(
-                                fstates, self.labels, self.base_offsets,
-                                self.weights, params_in, scores, key,
-                            ),
-                        )
-                    )
                 t0 = time.perf_counter()
-                params_out, scores, key, objs, trackers = fused(
-                    params_in, scores, key
-                )
+                # the fused pass is ONE indivisible dispatch: one span
+                # says so, and no per-coordinate window is invented
+                with obs.span(
+                    "game.dispatch", cat="game", kind="fused",
+                    iteration=it, passes=1, coordinates=len(names),
+                ):
+                    params_out, scores, key, objs, trackers = fused(
+                        params_in, scores, key
+                    )
                 model.params.update(params_out)
                 seconds = time.perf_counter() - t0
-                if tracer is not None:
-                    # the fused pass is ONE indivisible dispatch, so the
-                    # per-coordinate spans share the pass window; args
-                    # mark them fused so nobody reads the duration as a
-                    # per-coordinate cost (same contract as the history
-                    # records' first-record-only `seconds`)
-                    for name in names:
-                        tracer.add_span(
-                            "game.update",
-                            pass_ts,
-                            seconds * 1e6,
-                            cat="game",
-                            args={
-                                "coordinate": name,
-                                "iteration": it,
-                                "fused": True,
-                            },
-                        )
                 for i, (name, obj, tr) in enumerate(
                     zip(names, objs, trackers)
                 ):
@@ -1463,42 +1408,30 @@ class CoordinateDescent:
                     with obs.span(
                         "game.update", cat="game",
                         coordinate=name, iteration=it,
-                    ) as upd_span:
+                    ):
                         key, sub = jax.random.split(key)
                         params_in = {n: model.params[n] for n in names}
-                        rec = None
-                        if tracer is not None:
-                            rec = self._pass_cost(
-                                name,
-                                lambda: fns[name].lower(
-                                    states, self.labels,
-                                    self.base_offsets, self.weights,
-                                    params_in, scores, sub,
-                                ),
-                            )
-                            pass_recs.append(rec)
                         t0 = time.perf_counter()
-                        p, tr, s, obj = fns[name](
-                            states,
-                            self.labels,
-                            self.base_offsets,
-                            self.weights,
-                            params_in,
-                            scores,
-                            sub,
-                        )
+                        with obs.span(
+                            "game.dispatch", cat="game",
+                            kind="coordinate", iteration=it, passes=1,
+                            coordinates=1,
+                        ):
+                            p, tr, s, obj = fns[name](
+                                states,
+                                self.labels,
+                                self.base_offsets,
+                                self.weights,
+                                params_in,
+                                scores,
+                                sub,
+                            )
                         model.params[name] = p
                         scores = {**scores, name: s}
+                        # wall of the (async) dispatch window (the
+                        # deferred-stats pipelining must not gain a
+                        # block_until_ready here)
                         seconds = time.perf_counter() - t0
-                        # wall of the (async) dispatch window — flagged
-                        # so nobody reads chunked-mode MFU as synced
-                        # device time (the deferred-stats pipelining
-                        # must not gain a block_until_ready here)
-                        if rec is not None:
-                            obs.annotate_span(
-                                upd_span, rec, seconds=seconds
-                            )
-                            upd_span.set(timing="wall")
                         vmetric = (
                             float(validation_fn(model))
                             if validation_fn is not None
@@ -1517,6 +1450,21 @@ class CoordinateDescent:
                             }
                         )
             else:
+                def _dispatch(kind):
+                    return obs.span(
+                        "game.dispatch", cat="game", kind=kind,
+                        iteration=it, passes=1, coordinates=1,
+                    )
+
+                def _objective_on_host(cand_scores, cand_params):
+                    with _dispatch("objective"):
+                        obj_dev = self._full_objective(
+                            cand_scores, cand_params
+                        )
+                    with obs.span("game.fetch", cat="game",
+                                  what="objective"):
+                        return float(obj_dev)
+
                 for name in names:
                     if name in frozen:
                         continue
@@ -1552,17 +1500,16 @@ class CoordinateDescent:
                             return p, r, s
 
                         key, sub = jax.random.split(key)
-                        params, result, new_scores = _attempt(
-                            model.params[name], partial, sub
-                        )
+                        with _dispatch("update"):
+                            params, result, new_scores = _attempt(
+                                model.params[name], partial, sub
+                            )
                         event = None
                         if divergence_guard:
                             cand_scores = {**scores, name: new_scores}
                             cand_params = {**model.params, name: params}
-                            obj_host = float(
-                                self._full_objective(
-                                    cand_scores, cand_params
-                                )
+                            obj_host = _objective_on_host(
+                                cand_scores, cand_params
                             )
                             if not np.isfinite(obj_host):
                                 # rollback to the pre-update state and retry
@@ -1582,17 +1529,17 @@ class CoordinateDescent:
                                 # damped retry perturbs the state
                                 obs.flight_dump("divergence")
                                 key, sub = jax.random.split(key)
-                                params, result, new_scores = _attempt(
-                                    model.params[name], partial * 0.5, sub
-                                )
+                                with _dispatch("update"):
+                                    params, result, new_scores = _attempt(
+                                        model.params[name], partial * 0.5,
+                                        sub,
+                                    )
                                 cand_scores = {**scores, name: new_scores}
                                 cand_params = {
                                     **model.params, name: params
                                 }
-                                obj_host = float(
-                                    self._full_objective(
-                                        cand_scores, cand_params
-                                    )
+                                obj_host = _objective_on_host(
+                                    cand_scores, cand_params
                                 )
                                 if np.isfinite(obj_host):
                                     event = "recovered"
@@ -1617,7 +1564,10 @@ class CoordinateDescent:
                         model.params[name] = params
                         scores[name] = new_scores
 
-                        obj = self._full_objective(scores, model.params)
+                        with _dispatch("objective"):
+                            obj = self._full_objective(
+                                scores, model.params
+                            )
                         # seconds measures host dispatch+update wall time;
                         # with deferred stats the device may still be
                         # draining
@@ -1645,51 +1595,9 @@ class CoordinateDescent:
                         )
             force_plain = False
             pass_seconds = time.perf_counter() - pass_t0
-            if tracer is not None:
-                pass_args = {"iteration": it, "coordinates": len(names)}
-                # ELL backend the pass's programs traced with — GAME
-                # random-effect batches ride ops.sparse's
-                # PHOTON_SPARSE_KERNEL dispatch with zero call-site
-                # changes, so traces must say which backend they measure
-                try:
-                    from photon_ml_tpu.kernels import kernel_mode
-
-                    pass_args["sparse_kernel"] = kernel_mode()
-                except Exception:
-                    pass
-                # hardware attribution of the WHOLE pass: the sum of
-                # this pass's dispatch cost records (one fused program,
-                # or one per chunked coordinate update) over the pass
-                # wall — live MFU for coordinate passes in the trace
-                flops = sum(
-                    r.flops for r in pass_recs
-                    if r is not None and r.flops
-                )
-                bytes_acc = sum(
-                    r.bytes_accessed for r in pass_recs
-                    if r is not None and r.bytes_accessed
-                )
-                if flops or bytes_acc:
-                    from photon_ml_tpu.obs.xla_cost import CostRecord
-
-                    pass_args["timing"] = "wall"
-                    pass_args.update(
-                        CostRecord(
-                            name="game.pass",
-                            bucket="",
-                            flops=flops or None,
-                            bytes_accessed=bytes_acc or None,
-                        ).achieved(pass_seconds)
-                    )
-                tracer.add_span(
-                    "game.pass",
-                    pass_ts,
-                    pass_seconds * 1e6,
-                    cat="game",
-                    args=pass_args,
-                )
+            if obs.get_tracer() is not None:
                 # live HBM counter-track sample at the pass boundary
-                # (graceful no-op where memory_stats is unsupported)
+                # (a no-op where memory_stats is unsupported)
                 obs.sample_hbm()
             _reg = obs.registry()
             _reg.inc("game.passes")
@@ -1940,7 +1848,10 @@ def run_grid(
         records[-len(names)][4] = time.perf_counter() - t0
 
     # ONE batched host drain for every combo's stats
-    host = jax.device_get([(r[2], r[3]) for r in records])
+    fetch = [(r[2], r[3]) for r in records]
+    with obs.span("game.fetch", cat="game", what="grid_history",
+                  bytes=_tree_bytes(fetch)):
+        host = jax.device_get(fetch)
     models = [
         GameModel(
             {
@@ -2113,7 +2024,10 @@ def run_lambda_path(
             raw.append((objs, trackers, time.perf_counter() - t0))
         models.append(GameModel(dict(params)))
     # ONE batched host drain for the whole path
-    host = jax.device_get([(o, t) for o, t, _ in raw])
+    fetch = [(o, t) for o, t, _ in raw]
+    with obs.span("game.fetch", cat="game", what="path_history",
+                  bytes=_tree_bytes(fetch)):
+        host = jax.device_get(fetch)
     history: List[List[CoordinateUpdateRecord]] = []
     for (objs, trackers), (_, _, seconds) in zip(host, raw):
         records: List[CoordinateUpdateRecord] = []

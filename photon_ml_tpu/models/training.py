@@ -22,10 +22,10 @@ from __future__ import annotations
 
 import dataclasses
 import enum
-import threading
+import itertools
 import time
 from functools import lru_cache
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -416,62 +416,8 @@ def _record_solve_metrics(config: GLMTrainingConfig, result) -> None:
         record_solver_metrics(config.optimizer.name.lower(), result)
 
 
-# One objective-pass cost-book record per (solver-config kind, batch
-# geometry): the per-span MFU numerator unit, scaled by the solve's
-# counted design passes (``solvers.common.design_passes``). The lowering
-# re-traces the objective — cheap next to a solve, but not free — so it
-# runs ONLY under an active tracer and exactly once per key; analysis
-# happens on the LOWERED stage (no backend compile, so the xla.compiles
-# zero-recompile invariants are untouched).
-_pass_cost_lock = threading.Lock()
-_pass_cost_cache: Dict[tuple, object] = {}
-
-
-def _leaf_key(tree) -> tuple:
-    return tuple(
-        (tuple(getattr(l, "shape", ())), str(getattr(l, "dtype", "")))
-        for l in jax.tree_util.tree_leaves(tree)
-    )
-
-
-def _objective_pass_cost(config: GLMTrainingConfig, batch, norm):
-    """Cost record of ONE fused value/grad pass over ``batch`` (the
-    2-matmul unit of ``design_passes``), from the shared cost book.
-    Returns None when the objective cannot be analyzed — attribution is
-    best-effort and must never fail a solve."""
-    key = (
-        dataclasses.replace(config, reg_weights=(0.0,)),
-        _leaf_key(batch),
-        _leaf_key(norm),
-    )
-    with _pass_cost_lock:
-        if key in _pass_cost_cache:
-            return _pass_cost_cache[key]
-    rec = None
-    try:
-        import numpy as np
-
-        loss = loss_for_task(config.task)
-        obj = GLMObjective(
-            loss=loss, normalization=norm, l2_weight=1.0
-        )
-        d = batch.num_features
-        n = int(np.shape(batch.labels)[0])
-        w0 = jax.ShapeDtypeStruct((d,), solve_dtype(batch))
-        lowered = jax.jit(
-            lambda w, b: obj.value_and_grad(w, b)
-        ).lower(w0, batch)
-        rec = obs.cost_book().record(
-            "glm.objective_pass",
-            lowered,
-            bucket=f"{n}x{d}",
-            analytic_flops=4.0 * n * d,
-        )
-    except Exception:
-        rec = None
-    with _pass_cost_lock:
-        _pass_cost_cache[key] = rec
-    return rec
+# identifies one path solve in its spans (the `job` attribute)
+_JOB_IDS = itertools.count(1)
 
 
 _summarize_jit = jax.jit(summarize_features)
@@ -557,109 +503,106 @@ def _train_glm_scan(
     stacked ys, so consecutive train_glm calls still pipeline (bench.py
     depends on that); the traced/convergence-enabled path synchronizes
     once and retro-emits per-lambda ``glm.solve`` spans + tape counters
-    inside the one ``glm.solve_path`` span window."""
+    inside the one ``glm.solve_path`` span window. Spans: the root
+    ``glm.solve_path`` holds ``glm.dispatch`` (the call into the compiled
+    program, until it returns) and ``glm.decode`` (the host-side indexing
+    of the stacked result: a handful of tiny eager programs)."""
     dtype = solve_dtype(batch)
     lams = sorted(config.reg_weights, reverse=True)
     solve_path = _build_path_solver(config)
     with obs.span(
         "glm.solve_path",
         cat="solver",
+        job=next(_JOB_IDS),
         optimizer=config.optimizer.name,
         path_len=len(lams),
         dispatches=1,
     ) as sp:
         tracer = obs.get_tracer()
-        ts0 = tracer.now_us() if tracer is not None else 0.0
         t0 = time.perf_counter()
-        ys = solve_path(w, jnp.asarray(lams, dtype), batch, norm)
+        with obs.span("glm.dispatch", cat="solver", program="solve_path"):
+            ys = solve_path(w, jnp.asarray(lams, dtype), batch, norm)
         conv_enabled = (
             tracer is not None or obs.convergence.tracking_enabled()
         )
-        results = None
+        from photon_ml_tpu.solvers.common import index_result
+
         if conv_enabled:
             # one sync for the whole path, then the per-element decode:
             # solver metrics, convergence reports/events, and — under a
             # tracer — retro-stamped per-lambda glm.solve spans whose
             # windows split the path wall proportionally to each solve's
             # counted design passes (the honest attribution available
-            # for an indivisible dispatch), each carrying its own cost
-            # annotation and (value, |grad|) counter replay
+            # for an indivisible dispatch), each carrying its (value,
+            # |grad|) counter replay
             sp.sync(ys["means"])
             seconds = time.perf_counter() - t0
-            from photon_ml_tpu.solvers.common import (
-                design_passes,
-                index_result,
-            )
-
+        with obs.span("glm.decode", cat="solver"):
+            # lazy per-lambda slices of the stacked ys (each slice is an
+            # async device op, not a sync — the pipelined-solve contract)
             results = [
                 index_result(ys["result"], i) for i in range(len(lams))
             ]
-            passes = [design_passes(r) for r in results]
-            total_passes = sum(passes) or 1.0
-            rec = _objective_pass_cost(config, batch, norm)
-            obs.annotate_span(
-                sp, rec, seconds=seconds, passes=total_passes
-            )
-            offset_us = ts0
-            for i, (lam, result) in enumerate(zip(lams, results)):
-                _record_solve_metrics(config, result)
-                report = obs.decode_result(
-                    result, optimizer=config.optimizer.name.lower()
+            if conv_enabled:
+                _note_path_convergence(
+                    config, lams, results, tracer, t0, seconds
                 )
-                obs.convergence.note_solve(
-                    report, label=f"lambda={float(lam):g}"
+            by_lambda = {}
+            for i, lam in enumerate(lams):
+                result = results[i]
+                if config.track_models and "w_history_raw" in ys:
+                    result = dataclasses.replace(
+                        result, w_history=ys["w_history_raw"][i]
+                    )
+                coef = Coefficients(
+                    means=ys["means"][i],
+                    variances=(
+                        ys["variances"][i] if "variances" in ys else None
+                    ),
                 )
-                if tracer is not None:
-                    share_s = seconds * passes[i] / total_passes
-                    span_args = {
-                        "optimizer": config.optimizer.name,
-                        "reg_weight": float(lam),
-                        "path": True,
-                        "convergence_reason": report.reason,
-                        "convergence_order": report.order,
-                    }
-                    if rec is not None and share_s > 0:
-                        span_args.update(
-                            rec.achieved(share_s, passes=passes[i])
-                        )
-                    tracer.add_span(
-                        "glm.solve",
-                        offset_us,
-                        share_s * 1e6,
-                        cat="solver",
-                        args=span_args,
-                    )
-                    obs.convergence.emit_tape_counters(
-                        report, tracer, offset_us, share_s * 1e6
-                    )
-                    offset_us += share_s * 1e6
-
-    # decode: lazy per-lambda slices of the stacked ys (each slice is an
-    # async device op, not a sync — the pipelined-solve contract)
-    if results is None:
-        from photon_ml_tpu.solvers.common import index_result
-
-        results = [
-            index_result(ys["result"], i) for i in range(len(lams))
-        ]
-    by_lambda = {}
-    for i, lam in enumerate(lams):
-        result = results[i]
-        if config.track_models and "w_history_raw" in ys:
-            result = dataclasses.replace(
-                result, w_history=ys["w_history_raw"][i]
-            )
-        coef = Coefficients(
-            means=ys["means"][i],
-            variances=(
-                ys["variances"][i] if "variances" in ys else None
-            ),
-        )
-        model = GeneralizedLinearModel(coefficients=coef, task=config.task)
-        by_lambda[lam] = TrainedModel(
-            reg_weight=lam, model=model, result=result
-        )
+                model = GeneralizedLinearModel(
+                    coefficients=coef, task=config.task
+                )
+                by_lambda[lam] = TrainedModel(
+                    reg_weight=lam, model=model, result=result
+                )
     return [by_lambda[lam] for lam in config.reg_weights]
+
+
+def _note_path_convergence(config, lams, results, tracer, t0, seconds):
+    """The synchronized decode of one scanned path (``results`` are on
+    the host's side of a sync): solver metrics and convergence reports
+    for every lambda and, under a tracer, one retro-stamped ``glm.solve``
+    span a lambda."""
+    from photon_ml_tpu.solvers.common import design_passes
+
+    passes = [design_passes(r) for r in results]
+    total_passes = sum(passes) or 1.0
+    start_s = t0
+    for i, (lam, result) in enumerate(zip(lams, results)):
+        _record_solve_metrics(config, result)
+        report = obs.decode_result(
+            result, optimizer=config.optimizer.name.lower()
+        )
+        obs.convergence.note_solve(report, label=f"lambda={float(lam):g}")
+        if tracer is not None:
+            share_s = seconds * passes[i] / total_passes
+            obs.add_span(
+                "glm.solve",
+                start_s,
+                start_s + share_s,
+                cat="solver",
+                optimizer=config.optimizer.name,
+                reg_weight=float(lam),
+                path=True,
+                passes=passes[i],
+                convergence_reason=report.reason,
+                convergence_order=report.order,
+            )
+            obs.convergence.emit_tape_counters(
+                report, tracer, tracer.us_of(start_s), share_s * 1e6
+            )
+            start_s += share_s
 
 
 def train_glm_streamed(
@@ -794,17 +737,19 @@ def _train_glm_loop(
     solve, variances_fn = _build_solver(config)
     dtype = solve_dtype(batch)
     by_lambda = {}
+    job = next(_JOB_IDS)
     for lam in sorted(config.reg_weights, reverse=True):
         with obs.span(
             "glm.solve",
             cat="solver",
+            job=job,
             optimizer=config.optimizer.name,
             reg_weight=float(lam),
         ) as sp:
             tracer = obs.get_tracer()
-            ts0 = tracer.now_us() if tracer is not None else 0.0
             t0 = time.perf_counter()
-            result = solve(w, jnp.asarray(lam, dtype), batch, norm)
+            with obs.span("glm.dispatch", cat="solver", program="solve"):
+                result = solve(w, jnp.asarray(lam, dtype), batch, norm)
             conv_enabled = (
                 tracer is not None
                 or obs.convergence.tracking_enabled()
@@ -819,18 +764,6 @@ def _train_glm_loop(
                 sp.sync(result.w)
                 seconds = time.perf_counter() - t0
                 _record_solve_metrics(config, result)
-                # live hardware attribution: counted design passes x the
-                # cost book's per-pass FLOPs/bytes over the synchronized
-                # dispatch-to-done window -> flops / achieved_tflops /
-                # mfu / bytes_per_s span args (docs/OBSERVABILITY.md)
-                from photon_ml_tpu.solvers.common import design_passes
-
-                obs.annotate_span(
-                    sp,
-                    _objective_pass_cost(config, batch, norm),
-                    seconds=seconds,
-                    passes=design_passes(result),
-                )
                 # convergence-health decode (obs/convergence.py): the
                 # in-program tapes -> reason/rate/plateau report,
                 # convergence.* metrics, a structured event carrying
@@ -848,7 +781,7 @@ def _train_glm_loop(
                 )
                 if tracer is not None:
                     obs.convergence.emit_tape_counters(
-                        report, tracer, ts0, seconds * 1e6
+                        report, tracer, tracer.us_of(t0), seconds * 1e6
                     )
         w = result.w  # warm start for the next (smaller) lambda
         if config.track_models and result.w_history is not None:

@@ -2,9 +2,12 @@
 
 ONE instrument threaded through every layer (docs/OBSERVABILITY.md):
 
-- :mod:`.trace`   — nestable thread-safe spans; Chrome trace-event JSON
-  (Perfetto-loadable) + structured JSONL event log; near-zero cost when
-  disabled (``benchmarks/obs_overhead.py`` gates <5%).
+- :mod:`.trace`   — nestable thread-safe spans. Always on: every span is
+  one record in the flight ring (:mod:`.flight`; ``obs.recent_spans()``)
+  on the ``time.perf_counter()`` clock and, while open, a
+  ``jax.profiler.TraceAnnotation``. Opt-in: a ``Tracer`` exporting the
+  same records as Chrome trace-event JSON (Perfetto-loadable) + a
+  structured JSONL event log.
 - :mod:`.metrics` — named counters/gauges/histograms; JSON snapshots
   (``metrics.json``) and Prometheus text exposition (``cli/serve.py``).
 - :mod:`.compile_events` — ``jax.monitoring`` backend-compile counter
@@ -19,8 +22,9 @@ Drivers enable all of it in one place::
 which installs the tracer, starts a periodic registry dumper, and opens a
 ``jax.profiler`` capture window; everything tears down (final metrics
 dump, trace export) on exit. Hot paths call ``obs.span(...)`` /
-``obs.emit_event(...)`` / ``obs.registry()`` unconditionally — disabled
-mode costs one global read.
+``obs.emit_event(...)`` / ``obs.registry()`` unconditionally: a span
+costs a microsecond or two and never synchronises; an event without a
+tracer costs one global read.
 """
 
 from __future__ import annotations
@@ -102,6 +106,8 @@ from photon_ml_tpu.obs.flight import (
     flight_dump,
     flight_recorder,
     install_flight_recorder,
+    recent_spans,
+    spans_dropped,
     uninstall_flight_recorder,
 )
 from photon_ml_tpu.obs.quality import (
@@ -121,6 +127,7 @@ from photon_ml_tpu.obs.sketches import (
 from photon_ml_tpu.obs.trace import (
     Span,
     Tracer,
+    add_span,
     current_span_context,
     emit_event,
     get_tracer,
@@ -151,6 +158,9 @@ __all__ = [
     "get_tracer",
     "set_tracer",
     "span",
+    "add_span",
+    "recent_spans",
+    "spans_dropped",
     "trace",
     "install_compile_listener",
     "xla_compile_events",
@@ -305,8 +315,9 @@ def observe(
     - ``flight_dir``/``flight_records``: install a crash flight recorder
       (obs.flight) holding the last ``flight_records`` observations;
       ``flight-<reason>.json`` dumps land in ``flight_dir`` (default:
-      ``trace_dir``). With ``flight_dir`` set but no ``trace_dir``, a
-      ring-only tracer is installed so spans still feed the recorder
+      ``trace_dir``). Spans are in the always-on flight ring either
+      way; with ``flight_dir`` set but no ``trace_dir``, a ring-only
+      tracer is installed so that instant events feed the recorder too,
       without accumulating an unbounded trace. ``flight_records=0``
       disables.
 
@@ -326,9 +337,9 @@ def observe(
             hbm = HbmSampler(hbm_every_s).start()
             installed_tracer = True
         elif flight_dir is not None and flight_records > 0:
-            # ring-only tracer: spans/events route to the flight
-            # recorder, nothing accumulates, nothing is written unless
-            # a dump fires
+            # ring-only tracer: events route to the flight recorder,
+            # nothing accumulates, nothing is written unless a dump
+            # fires
             ring_tracer = Tracer(None, process_name=process_name,
                                  keep_events=False)
             prev = set_tracer(ring_tracer)

@@ -87,8 +87,11 @@ class LatencyHistogram:
 
     Fixed geometric bucket edges keep recording O(1) and lock-cheap; the
     quantile interpolates within the winning bucket, so resolution is the
-    edge ratio (~12% at the default 64 bins over 1e-3..6e4 ms) — plenty
-    for p99 dashboards, and bounded memory regardless of request count.
+    edge ratio (1.32, so buckets 32% wide, at the default 64 bins over
+    1e-3..6e4 ms) — enough for the ``/metrics`` exposition's dashboards,
+    and bounded memory regardless of request count; exact quantiles of a
+    window come from the ``serving.request`` records in the flight ring
+    (``obs.recent_spans()``), not from here.
     NOT thread-safe on its own; :class:`MetricsRegistry` (and
     ``ServingStats``) hold the lock.
     """

@@ -43,8 +43,10 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
     (
         "game",
         r"game\.[a-z_]+(\.[a-z0-9_.]+)?",
-        "GAME descent spans/counters (game.pass, game.updates, "
-        "game.checkpoint.submit_ms, ...)",
+        "GAME descent spans (game.cd.run > game.dispatch / game.fetch / "
+        "game.decode; game.update on the per-coordinate paths) and "
+        "counters (game.passes, game.updates, game.checkpoint.submit_ms, "
+        "...)",
     ),
     (
         "solver",
@@ -54,7 +56,8 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
     (
         "glm",
         r"glm\.[a-z_]+",
-        "GLM driver/solve spans (glm.solve, glm.solve_path)",
+        "GLM driver/solve spans (glm.solve_path or glm.solve > "
+        "glm.dispatch / glm.decode)",
     ),
     (
         "xla",
@@ -99,7 +102,9 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
     (
         "serving",
         r"serving\.[a-z_]+(\..+)?",
-        "ServingStats registry metrics, request spans, SLO gauges",
+        "ServingStats registry metrics, SLO gauges, the per-request "
+        "record serving.request and the per-batch spans serving.score > "
+        "serving.featurize / serving.dispatch / serving.fetch",
     ),
     (
         "convergence",

@@ -1,40 +1,59 @@
-"""Structured span tracing: Chrome trace events + JSONL event log.
+"""Program spans: one primitive, kept in the flight ring, mirrored into the
+profiler's trace, exported on request.
 
 The reference's only timing instrument is ``Driver.scala:124-149`` — ad-hoc
 elapsed-millis log lines per phase. That tells you *that* a GAME pass took
 9 seconds, never *where* they went (solver iterations vs recompiles vs
-host<->device transfer). This module is the process-wide replacement: a
-thread-safe span tracer whose output loads directly into Perfetto /
-``chrome://tracing`` (trace-event JSON) and doubles as a structured JSONL
-event log written next to the run's ``log-message.txt``.
+host<->device transfer). :func:`span` is the process-wide replacement.
 
-Design constraints, in priority order:
+What a span does, always (no switch):
 
-1. **Near-zero disabled overhead.** Training hot loops call
-   :func:`span` unconditionally; with no tracer installed the call is one
-   module-global read plus returning a shared no-op singleton — no
-   allocation, no lock, no branch in the caller. ``benchmarks/obs_overhead.py``
-   gates this (<5% on a smoke GAME run, enabled vs disabled).
+1. **One record in the flight ring** (``obs.flight``) on exit:
+   ``(name, start, end, span_id, parent_id, thread, attrs)``, ``start`` and
+   ``end`` from ``time.perf_counter()``, ``parent_id`` the innermost span
+   open on the same thread. A reader (``obs.recent_spans()``) cuts the ring
+   to any window it holds ``perf_counter`` stamps for. A retro-stamped
+   form, :func:`add_span`, writes the same record for work whose stamps
+   are known only afterwards (a request's life, read off its future).
+2. **One ``jax.profiler.TraceAnnotation``** held while the span is open,
+   when ``jax`` is already imported. Outside a profiler session that is a
+   TraceMe that records nothing; inside ``jax.profiler.trace(...)`` the
+   program's spans land in the ``.xplane.pb`` host plane on the clock of
+   the device events.
+3. **Never synchronises.** ``span(...).sync(arrays)`` is for callers that
+   ask for ``jax.block_until_ready`` and want the blocked time on the span.
+
+What is opt-in: a :class:`Tracer` (``obs.trace(dir)`` /
+``obs.observe(trace_dir=...)``) exports the same records as Chrome
+trace-event JSON (Perfetto / ``chrome://tracing``) and a structured JSONL
+event log next to the run's ``log-message.txt``, and carries instant
+events and counter tracks, which exist only under a tracer.
+
+Constraints, in priority order:
+
+1. **Cheap.** Training hot loops and the serving worker call :func:`span`
+   unconditionally: a span costs one small object, two clock reads, one
+   TraceMe and one ``deque.append`` — no lock, nothing serialized.
 2. **Thread-safe.** The serving micro-batcher and stats flushers span from
-   worker threads; events append under one lock and carry the recording
-   thread id so Perfetto lays them out per-track.
-3. **No jax dependency.** Pure stdlib — the tracer must be importable from
-   CPU-only subprocesses (bench baselines) and before backend selection.
+   worker threads; records carry the recording thread id, exported events
+   append under the tracer's lock.
+3. **No jax dependency.** Importable from CPU-only subprocesses (bench
+   baselines) and before backend selection; jax is looked up in
+   ``sys.modules``, never imported.
 
 Usage::
 
     from photon_ml_tpu import obs
 
-    with obs.trace("out/trace"):            # install for the block
-        with obs.span("train", combo=0):    # nestable, thread-safe
+    with obs.span("train", combo=0):        # nestable, thread-safe
+        ...
+    obs.recent_spans()                      # -> [(name, start, end, ...)]
+
+    with obs.trace("out/trace"):            # install an exporter
+        with obs.span("train", combo=0):
             ...
         obs.emit_event("retry", label="read part-0.avro", attempt=2)
     # -> out/trace/trace.json (Perfetto) + out/trace/events.jsonl
-
-Device-time attribution: wall-clock spans lie on an async runtime — the
-dispatch returns before the device finishes. ``span(...).sync(arrays)``
-calls ``jax.block_until_ready`` on the value and annotates the span with
-the blocked time, splitting host dispatch from device completion.
 """
 
 from __future__ import annotations
@@ -42,17 +61,22 @@ from __future__ import annotations
 import atexit
 import contextlib
 import io
+import itertools
 import json
 import os
+import sys
 import threading
 import time
 from typing import Any, Dict, List, Optional
+
+from photon_ml_tpu.obs import flight as _flight
 
 __all__ = [
     "Span",
     "Tracer",
     "trace",
     "span",
+    "add_span",
     "span_context",
     "current_span_context",
     "emit_event",
@@ -94,9 +118,11 @@ class Tracer:
         # would be a leak
         self._keep_events = keep_events
         self._epoch_ns = time.perf_counter_ns()
+        self._epoch_s = self._epoch_ns / 1e9  # the same instant, as a stamp
         self._epoch_unix = time.time()
         # flight-recorder hook: a FlightRecorder (obs.flight) notes every
-        # span/instant/counter record into its bounded ring
+        # instant/counter record into its bounded ring (spans are in the
+        # flight ring whether or not a tracer is installed)
         self.recorder = None
         # pod identity (obs.dist): in a multi-process run the Chrome pid
         # IS the process index — per-host events land on distinct
@@ -156,21 +182,28 @@ class Tracer:
         """Microseconds since this tracer's epoch (monotonic)."""
         return (time.perf_counter_ns() - self._epoch_ns) / 1e3
 
+    def us_of(self, stamp_s: float) -> float:
+        """A ``time.perf_counter()`` stamp on this tracer's clock."""
+        return (stamp_s - self._epoch_s) * 1e6
+
     def _wall(self, ts_us: float) -> float:
         """Unix seconds for a tracer timestamp (JSONL human anchor)."""
         return self._epoch_unix + ts_us / 1e6
 
     # -- recording ----------------------------------------------------------
 
-    def _log_jsonl(self, record: Dict[str, Any], flush: bool = False) -> None:
+    def _log_jsonl(
+        self, record: Dict[str, Any], flush: bool = False, note: bool = True
+    ) -> None:
         """Append one JSONL record. Span records are flushed every
         ``_FLUSH_EVERY`` writes (a crash loses at most a handful of
-        timing lines — the flight recorder's ring covers that window);
+        timing lines — the flight ring covers that window);
         instant events — faults, retries, preemptions — flush
         immediately, since they exist to survive the crash that
-        follows them."""
+        follows them. ``note``: also into the flight recorder's ring
+        (not for spans: the flight ring has them already)."""
         rec = self.recorder
-        if rec is not None:
+        if note and rec is not None:
             rec.note(record)
         if self._jsonl is None or self._jsonl.closed:
             return
@@ -191,9 +224,29 @@ class Tracer:
         tid: Optional[int] = None,
         args: Optional[Dict[str, Any]] = None,
     ) -> None:
-        """Record a complete ('X') event with an explicit window — the
-        retro-emission hook for work whose per-piece timing is only known
-        after a fused dispatch returns."""
+        """Record a span with an explicit window on this tracer's clock
+        (``now_us``) — the retro-stamped form for callers that hold a
+        tracer. Writes the same flight-ring record as :func:`add_span`
+        and exports it through this tracer."""
+        start_s = self._epoch_s + ts_us / 1e6
+        _record(
+            name, start_s, start_s + max(dur_us, 0.0) / 1e6, cat, tid,
+            args if args is not None else {}, self,
+        )
+
+    def _export_span(
+        self,
+        name: str,
+        start_s: float,
+        end_s: float,
+        cat: str,
+        tid: Optional[int],
+        args: Dict[str, Any],
+    ) -> None:
+        """One flight-ring record as a complete ('X') Chrome event and a
+        JSONL line."""
+        ts_us = self.us_of(start_s)
+        dur_us = (end_s - start_s) * 1e6
         ev = {
             "ph": "X",
             "name": name,
@@ -202,7 +255,7 @@ class Tracer:
             "tid": tid if tid is not None else threading.get_ident(),
             "ts": round(ts_us, 3),
             "dur": round(max(dur_us, 0.0), 3),
-            "args": args or {},
+            "args": args,
         }
         with self._lock:
             if self._keep_events:
@@ -214,8 +267,9 @@ class Tracer:
                     "cat": cat,
                     "time_unix": round(self._wall(ts_us), 6),
                     "duration_ms": round(max(dur_us, 0.0) / 1e3, 6),
-                    **(args or {}),
-                }
+                    **args,
+                },
+                note=False,
             )
 
     def add_instant(
@@ -398,59 +452,120 @@ def trace(trace_dir: Optional[str], process_name: str = "photon_ml_tpu"):
 # Spans
 # ---------------------------------------------------------------------------
 
+# span ids: one process-wide counter (``next`` on it is atomic)
+_ids = itertools.count(1)
 
-class _NullSpan:
-    """The disabled-mode singleton: every method is a no-op. Shared and
-    stateless so ``span()`` allocates nothing when tracing is off."""
+# Per-thread stack of ``(span_id, ambient fields)``: the innermost open
+# span (a record's ``parent_id``) and the request-scoped attributes
+# (trace/request/batch ids) that cross API seams without threading kwargs
+# through them — the serving micro-batcher opens a context around its
+# score_fn call and the engine's `serving.score` span inherits the batch
+# identity. Thread-local so concurrent micro-batchers don't cross-tag.
+_span_ctx = threading.local()
 
-    __slots__ = ()
-
-    def __enter__(self) -> "_NullSpan":
-        return self
-
-    def __exit__(self, *exc) -> bool:
-        return False
-
-    def set(self, **attrs) -> None:
-        pass
-
-    def sync(self, value):
-        return value
+_annotation_cls = None
 
 
-_NULL_SPAN = _NullSpan()
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` for ``name`` when jax is already
+    imported, else None. Never imports jax."""
+    global _annotation_cls
+    cls = _annotation_cls
+    if cls is None:
+        jax = sys.modules.get("jax")
+        if jax is None:
+            return None
+        try:
+            cls = _annotation_cls = jax.profiler.TraceAnnotation
+        except AttributeError:  # jax is still being imported
+            return None
+    return cls(name)
+
+
+def _record(name, start_s, end_s, cat, tid, attrs, tracer,
+            span_id=None, parent_id=None) -> int:
+    """The one place a span becomes a record: the flight ring always, the
+    tracer's export when one is given."""
+    if span_id is None:
+        span_id = next(_ids)
+    if parent_id is None:
+        stack = getattr(_span_ctx, "stack", None)
+        parent_id = stack[-1][0] if stack else 0
+    if tid is None:
+        tid = threading.get_ident()
+    _flight.note_span(
+        (name, start_s, end_s, span_id, parent_id, tid, attrs)
+    )
+    if tracer is not None:
+        tracer._export_span(name, start_s, end_s, cat, tid, attrs)
+    return span_id
+
+
+def add_span(
+    name: str,
+    start_s: float,
+    end_s: float,
+    cat: str = "app",
+    tid: Optional[int] = None,
+    **attrs,
+) -> int:
+    """The retro-stamped form: record a span whose ``time.perf_counter()``
+    stamps are known only afterwards (a request's life, a stage timed by
+    other means). Same record as a live span; no profiler annotation, for
+    its time has passed. Returns the span id."""
+    return _record(name, start_s, end_s, cat, tid, attrs, _active)
 
 
 class Span:
-    """A live span: records a complete event on ``__exit__``.
+    """A live span: records on ``__exit__`` (see the module docstring).
 
-    ``set(**attrs)`` attaches arguments (visible in Perfetto's args pane
-    and in the JSONL record). ``sync(value)`` blocks until the device
-    work producing ``value`` is done and annotates the span with the
-    blocked milliseconds — wall time alone cannot split an async
-    dispatch from device completion. A span that exits via an exception
-    is recorded with ``error=True``; where the time went is most valuable
-    exactly when the phase died (same contract as ``timed()``).
+    ``set(**attrs)`` attaches attributes (the record's ``attrs``; Perfetto's
+    args pane and the JSONL line under a tracer). ``sync(value)`` blocks
+    until the device work producing ``value`` is done and annotates the
+    span with the blocked milliseconds — wall time alone cannot split an
+    async dispatch from device completion. A span that exits via an
+    exception is recorded with ``error=True``; where the time went is most
+    valuable exactly when the phase died (same contract as ``timed()``).
     """
 
-    __slots__ = ("_tracer", "name", "cat", "args", "_t0")
+    __slots__ = ("name", "cat", "args", "span_id", "parent_id", "_t0",
+                 "_ann")
 
-    def __init__(self, tracer: Tracer, name: str, cat: str, args: dict):
-        self._tracer = tracer
+    def __init__(self, name: str, cat: str, args: dict):
         self.name = name
         self.cat = cat
         self.args = args
-        self._t0 = tracer.now_us()
+        self.span_id = next(_ids)
+        self.parent_id = 0
+        self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self) -> "Span":
+        stack = getattr(_span_ctx, "stack", None)
+        if stack is None:
+            stack = _span_ctx.stack = []
+        ctx = None
+        if stack:
+            self.parent_id, ctx = stack[-1]
+            if ctx:
+                self.args = {**ctx, **self.args}
+        stack.append((self.span_id, ctx))
+        ann = self._ann = _annotation(self.name)
+        if ann is not None:
+            ann.__enter__()
+        self._t0 = time.perf_counter()
         return self
 
-    def __exit__(self, exc_type, *exc) -> bool:
+    def __exit__(self, exc_type, exc, tb) -> bool:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+        _span_ctx.stack.pop()
         if exc_type is not None:
             self.args["error"] = True
-        t1 = self._tracer.now_us()
-        self._tracer.add_span(
-            self.name, self._t0, t1 - self._t0, cat=self.cat, args=self.args
+        _record(
+            self.name, self._t0, t1, self.cat, None, self.args, _active,
+            self.span_id, self.parent_id,
         )
         return False
 
@@ -460,30 +575,21 @@ class Span:
     def sync(self, value):
         """``jax.block_until_ready(value)``, annotating the span with the
         blocked time (``device_wait_ms``) — the device-time attribution
-        seam. Imports jax lazily so the tracer stays stdlib-only."""
+        seam. Imports jax lazily so the module stays stdlib-only."""
         import jax
 
-        t0 = self._tracer.now_us()
+        t0 = time.perf_counter()
         out = jax.block_until_ready(value)
         self.args["device_wait_ms"] = round(
-            (self._tracer.now_us() - t0) / 1e3, 4
+            (time.perf_counter() - t0) * 1e3, 4
         )
         return out
-
-
-# Ambient span context: request-scoped attributes (trace/request ids)
-# that cross API seams without threading kwargs through them — the
-# serving micro-batcher opens a context around its score_fn call and the
-# engine's `serving.score` span inherits the batch/request identity.
-# Thread-local so concurrent micro-batchers don't cross-tag. Read ONLY
-# when a tracer is active, so disabled-mode span() cost is unchanged.
-_span_ctx = threading.local()
 
 
 def current_span_context() -> Optional[Dict[str, Any]]:
     """The innermost ambient span-context dict, or None."""
     stack = getattr(_span_ctx, "stack", None)
-    return stack[-1] if stack else None
+    return (stack[-1][1] or None) if stack else None
 
 
 @contextlib.contextmanager
@@ -494,25 +600,22 @@ def span_context(**fields):
     stack = getattr(_span_ctx, "stack", None)
     if stack is None:
         stack = _span_ctx.stack = []
-    merged = {**stack[-1], **fields} if stack else dict(fields)
-    stack.append(merged)
+    if stack:
+        parent_id, outer = stack[-1]
+        merged = {**outer, **fields} if outer else dict(fields)
+    else:
+        parent_id, merged = 0, dict(fields)
+    stack.append((parent_id, merged))
     try:
         yield
     finally:
         stack.pop()
 
 
-def span(name: str, cat: str = "app", **attrs):
-    """Open a span on the active tracer (context manager). Disabled mode
-    returns a shared no-op singleton — the unconditional-call contract
-    every hot loop relies on."""
-    tracer = _active
-    if tracer is None:
-        return _NULL_SPAN
-    ctx = current_span_context()
-    if ctx:
-        attrs = {**ctx, **attrs}
-    return Span(tracer, name, cat, attrs)
+def span(name: str, cat: str = "app", **attrs) -> Span:
+    """A span (context manager): always live, always recorded — see the
+    module docstring for what that costs and where the record goes."""
+    return Span(name, cat, attrs)
 
 
 def emit_event(name: str, cat: str = "event", **fields) -> None:

@@ -178,6 +178,7 @@ class GLMObjective:
     def grad(self, w: jax.Array, batch: LabeledBatch) -> jax.Array:
         return self.value_and_grad(w, batch)[1]
 
+    @jax.named_scope("objective_pass")
     def value_grad_curvature(self, w: jax.Array, batch: LabeledBatch):
         """(value, gradient, curvature weights) from ONE margins pass.
         The curvature weights c = w_i * l''(z_i) are what
@@ -289,6 +290,7 @@ class GLMObjective:
             self.hessian_coefficients(w, batch), v, batch
         )
 
+    @jax.named_scope("objective_pass")
     def hessian_coefficients(
         self, w: jax.Array, batch: LabeledBatch
     ) -> jax.Array:
@@ -300,6 +302,7 @@ class GLMObjective:
         z = self.margins(w, batch)
         return batch.effective_weights() * self.loss.d2(z, batch.labels)
 
+    @jax.named_scope("objective_pass")
     def hessian_vector_at(
         self, c: jax.Array, v: jax.Array, batch: LabeledBatch
     ) -> jax.Array:
@@ -327,6 +330,7 @@ class GLMObjective:
             hv = hv + self.l2_weight * v
         return hv
 
+    @jax.named_scope("objective_pass")
     def hessian_diagonal(self, w: jax.Array, batch: LabeledBatch) -> jax.Array:
         """diag(H) for coefficient variances
         (``TwiceDiffFunction.scala:179-394``, used by
@@ -363,6 +367,7 @@ class GLMObjective:
             diag = diag + self.l2_weight
         return diag
 
+    @jax.named_scope("objective_pass")
     def hessian_full(self, w: jax.Array, batch: LabeledBatch) -> jax.Array:
         """The EXPLICIT (d, d) Hessian X'^T diag(c) X' + l2 I — only
         sensible for small d, where it is one MXU-friendly pass.

@@ -422,7 +422,8 @@ def matvec(x, w: jax.Array) -> jax.Array:
     if is_feature_sharded(x):
         from photon_ml_tpu.parallel.overlap import feature_block_sum
 
-        return feature_block_sum(_block_margin_partials(x, w))
+        with jax.named_scope("sparse_gather"):
+            return feature_block_sum(_block_margin_partials(x, w))
     if is_hybrid(x):
         # dtype promotion mirrors the dense path (bf16 slab @ f32 w -> f32)
         cold = jnp.concatenate(
@@ -431,43 +432,21 @@ def matvec(x, w: jax.Array) -> jax.Array:
         return _low_precision_dot(x.dense, w[x.hot_ids]) + cold
     if not is_sparse(x):
         return _low_precision_dot(x, w)
-    if _use_pallas_for(x, w.dtype):
-        from photon_ml_tpu import kernels
+    with jax.named_scope("sparse_gather"):
+        if _use_pallas_for(x, w.dtype):
+            from photon_ml_tpu import kernels
 
-        return kernels.ell_matvec(x.indices, x.values, w, x.d)
-    gathered = w.at[x.indices].get(mode="fill", fill_value=0.0)
-    return jnp.sum(x.values * gathered, axis=-1)
+            return kernels.ell_matvec(x.indices, x.values, w, x.d)
+        gathered = w.at[x.indices].get(mode="fill", fill_value=0.0)
+        return jnp.sum(x.values * gathered, axis=-1)
 
 
 def rmatvec(x, a: jax.Array) -> jax.Array:
     """gradient back-projection: (n, d)^T @ (n,) -> (d,). Hybrid `a` is
     in stored row order."""
     if is_feature_sharded(x):
-        if x.is_balanced:
-            # route each virtual row's weight from its original row; the
-            # identity-aligned head broadcasts straight from ``a``
-            # (sentinel lanes gather-fill 0, so their slots contribute 0)
-            al = x.aligned_rows
-            if al:
-                head = jnp.broadcast_to(
-                    a[:al, None], (al, x.num_blocks)
-                )
-                tail = a.at[x.row_map[al:]].get(
-                    mode="fill", fill_value=0.0
-                )
-                a_v = jnp.concatenate([head, tail], axis=0)
-            else:
-                a_v = a.at[x.row_map].get(mode="fill", fill_value=0.0)
-            upd = x.values * a_v[..., None]
-        else:
-            upd = x.values * a[:, None, None]
-        g2 = jax.vmap(  # per-block local scatter into the block's coefficients
-            lambda idxf, updf: jnp.zeros((x.d_shard,), updf.dtype)
-            .at[idxf.reshape(-1)]
-            .add(updf.reshape(-1), mode="drop"),
-            in_axes=(1, 1),
-        )(x.indices, upd)
-        return g2.reshape(-1)
+        with jax.named_scope("sparse_scatter"):
+            return _rmatvec_feature_sharded(x, a)
     if is_hybrid(x):
         g = jnp.zeros((x.d,), a.dtype)
         for (lo, hi), seg in zip(x.segment_bounds(), x.cold_segments):
@@ -475,16 +454,47 @@ def rmatvec(x, a: jax.Array) -> jax.Array:
         return g.at[x.hot_ids].add(_low_precision_dot(a, x.dense))
     if not is_sparse(x):
         return _low_precision_dot(x.T, a)
-    if _use_pallas_for(x, a.dtype):
-        from photon_ml_tpu import kernels
+    with jax.named_scope("sparse_scatter"):
+        if _use_pallas_for(x, a.dtype):
+            from photon_ml_tpu import kernels
 
-        return kernels.ell_rmatvec(x.indices, x.values, a, x.d)
-    upd = (x.values * a[..., None]).reshape(-1)
-    return (
-        jnp.zeros((x.d,), upd.dtype)
-        .at[x.indices.reshape(-1)]
-        .add(upd, mode="drop")
-    )
+            return kernels.ell_rmatvec(x.indices, x.values, a, x.d)
+        upd = (x.values * a[..., None]).reshape(-1)
+        return (
+            jnp.zeros((x.d,), upd.dtype)
+            .at[x.indices.reshape(-1)]
+            .add(upd, mode="drop")
+        )
+
+
+def _rmatvec_feature_sharded(x, a: jax.Array) -> jax.Array:
+    """``rmatvec`` of a feature-sharded design: a per-block local scatter
+    into the block's coefficients."""
+    if x.is_balanced:
+        # route each virtual row's weight from its original row; the
+        # identity-aligned head broadcasts straight from ``a``
+        # (sentinel lanes gather-fill 0, so their slots contribute 0)
+        al = x.aligned_rows
+        if al:
+            head = jnp.broadcast_to(
+                a[:al, None], (al, x.num_blocks)
+            )
+            tail = a.at[x.row_map[al:]].get(
+                mode="fill", fill_value=0.0
+            )
+            a_v = jnp.concatenate([head, tail], axis=0)
+        else:
+            a_v = a.at[x.row_map].get(mode="fill", fill_value=0.0)
+        upd = x.values * a_v[..., None]
+    else:
+        upd = x.values * a[:, None, None]
+    g2 = jax.vmap(  # per-block local scatter into the block's coefficients
+        lambda idxf, updf: jnp.zeros((x.d_shard,), updf.dtype)
+        .at[idxf.reshape(-1)]
+        .add(updf.reshape(-1), mode="drop"),
+        in_axes=(1, 1),
+    )(x.indices, upd)
+    return g2.reshape(-1)
 
 
 def colsum(x, c: jax.Array, square: bool = False) -> jax.Array:
@@ -502,17 +512,20 @@ def colsum(x, c: jax.Array, square: bool = False) -> jax.Array:
     if not is_sparse(x):
         v = x * x if square else x
         return jnp.einsum("n,nd->d", c, v)
-    if _use_pallas_for(x, c.dtype):
-        from photon_ml_tpu import kernels
+    with jax.named_scope("sparse_scatter"):
+        if _use_pallas_for(x, c.dtype):
+            from photon_ml_tpu import kernels
 
-        return kernels.ell_colsum(x.indices, x.values, c, x.d, square=square)
-    v = x.values * x.values if square else x.values
-    upd = (v * c[..., None]).reshape(-1)
-    return (
-        jnp.zeros((x.d,), upd.dtype)
-        .at[x.indices.reshape(-1)]
-        .add(upd, mode="drop")
-    )
+            return kernels.ell_colsum(
+                x.indices, x.values, c, x.d, square=square
+            )
+        v = x.values * x.values if square else x.values
+        upd = (v * c[..., None]).reshape(-1)
+        return (
+            jnp.zeros((x.d,), upd.dtype)
+            .at[x.indices.reshape(-1)]
+            .add(upd, mode="drop")
+        )
 
 
 def matvec_and_feature_dots(
@@ -542,7 +555,8 @@ def matvec_and_feature_dots(
     from photon_ml_tpu.parallel.overlap import feature_block_sum
 
     n = x.shape[0]
-    zb = _block_margin_partials(x, w)  # (F, n) partials
+    with jax.named_scope("sparse_gather"):
+        zb = _block_margin_partials(x, w)  # (F, n) partials
     cols = [zb]
     for u, v in dot_pairs:
         ub = u.reshape(x.num_blocks, x.d_shard)

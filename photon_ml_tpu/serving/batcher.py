@@ -72,15 +72,20 @@ _INSTANCE_IDS = itertools.count(1)
 
 class _Item:
     __slots__ = ("request", "future", "enqueued", "rid", "deadline",
-                 "priority", "over_quota", "trace", "wire_ms")
+                 "priority", "over_quota", "trace", "wire_ms", "received")
 
     def __init__(self, request, rid: int = 0, deadline: Optional[float] = None,
                  priority: int = 0, over_quota: bool = False,
                  trace: Optional[str] = None,
-                 wire_ms: Optional[float] = None):
+                 wire_ms: Optional[float] = None,
+                 received: Optional[float] = None):
         self.request = request
         self.future: Future = Future()
         self.enqueued = time.perf_counter()
+        # when the caller had the request's line in hand (a
+        # perf_counter stamp): such a caller finishes the request's
+        # record itself, once the reply is out (record_request)
+        self.received = received
         self.rid = rid
         self.deadline = deadline  # absolute perf_counter seconds, or None
         self.priority = priority
@@ -391,6 +396,7 @@ class MicroBatcher:
         over_quota: bool = False,
         trace: Optional[str] = None,
         wire_read_ms: Optional[float] = None,
+        received: Optional[float] = None,
     ) -> Future:
         """Enqueue one request; the Future resolves to its float score.
 
@@ -405,7 +411,12 @@ class MicroBatcher:
         displace other over-quota work (docs/FRONTEND.md). ``trace`` /
         ``wire_read_ms``: the frontend-issued trace id and wire-read
         time, carried through to the ``serving.request`` retro-span and
-        the exemplar store (docs/OBSERVABILITY.md). Raises
+        the exemplar store (docs/OBSERVABILITY.md). ``received``: the
+        ``time.perf_counter()`` stamp at which a wire-level caller had
+        the request in hand; with it the batcher leaves the request's
+        ``serving.request`` record to that caller, who knows when the
+        reply was flushed — the stamps ride the returned future
+        (:func:`record_request`). Raises
         :class:`Backpressure` when draining or when admission control
         cannot make room."""
         if self._draining.is_set():
@@ -419,6 +430,7 @@ class MicroBatcher:
             over_quota=over_quota,
             trace=trace,
             wire_ms=wire_read_ms,
+            received=received,
         )
         try:
             self._q.put_nowait(item)
@@ -629,7 +641,6 @@ class MicroBatcher:
             self.stats.record_error()
             t_err = time.perf_counter()
             failover = any(n.get("error") for n in hop_notes)
-            tracer = obs.get_tracer()
             for it in batch:
                 if self.slo is not None:
                     self.slo.record(t_err - it.enqueued, ok=False)
@@ -637,26 +648,14 @@ class MicroBatcher:
                     it, t_err - it.enqueued, "error",
                     degraded=degraded, failover=failover,
                 )
-                if tracer is not None:
-                    # the failed request still gets its retro-span —
-                    # carrying the error instead of segments — so its
-                    # timeline reconstructs as explicitly TRUNCATED and
-                    # the batch's hop/down records are never orphaned
-                    end_us = tracer.now_us()
-                    dur_us = (t_err - it.enqueued) * 1e6
-                    args = {
-                        "request_id": it.rid,
-                        "batch_id": bid,
-                        "degraded": degraded,
-                        "failover": failover,
-                        "error": type(e).__name__,
-                    }
-                    if it.trace is not None:
-                        args["trace"] = it.trace
-                    tracer.add_span(
-                        "serving.request", end_us - dur_us, dur_us,
-                        cat="serving", args=args,
-                    )
+                # the failed request still gets its record — carrying
+                # the error instead of segments — so its timeline
+                # reconstructs as explicitly TRUNCATED and the batch's
+                # hop/down records are never orphaned
+                self._hand_over(
+                    it, (bid, t_first, t0, t_err, degraded, failover,
+                         type(e).__name__),
+                )
                 if not it.future.done():
                     it.future.set_exception(e)
             return
@@ -665,9 +664,7 @@ class MicroBatcher:
         self.stats.record_batch(len(batch), t1 - t0)
         if degraded:
             self.stats.record_degraded_batch()
-        tracer = obs.get_tracer()
-        device_ms = (t1 - t0) * 1e3
-        assembly_ms = max(t0 - t_first, 0.0) * 1e3
+        stamps = (bid, t_first, t0, t1, degraded, failover, None)
         for it, s in zip(batch, scores):
             latency = t1 - it.enqueued
             self.stats.record_request_latency(latency)
@@ -676,35 +673,58 @@ class MicroBatcher:
             self._offer_exemplar(
                 it, latency, "ok", degraded=degraded, failover=failover
             )
-            if tracer is not None:
-                # request-scoped trace: one retro-emitted span per
-                # request covering enqueue -> result, decomposed into
-                # wire read (when the frontend fed it), queue-wait
-                # (sitting in the bounded queue), batch assembly (the
-                # coalescing window), and the device call
-                end_us = tracer.now_us()
-                dur_us = latency * 1e6
-                args = {
-                    "request_id": it.rid,
-                    "batch_id": bid,
-                    "degraded": degraded,
-                    "failover": failover,
-                    "queue_wait_ms": round(
-                        max(t_first - it.enqueued, 0.0) * 1e3, 4
-                    ),
-                    "assembly_ms": round(assembly_ms, 4),
-                    "device_ms": round(device_ms, 4),
-                }
-                if it.trace is not None:
-                    args["trace"] = it.trace
-                if it.wire_ms is not None:
-                    args["wire_read_ms"] = round(it.wire_ms, 4)
-                tracer.add_span(
-                    "serving.request",
-                    end_us - dur_us,
-                    dur_us,
-                    cat="serving",
-                    args=args,
-                )
+            self._hand_over(it, stamps)
             if not it.future.done():
                 it.future.set_result(float(s))
+
+    @staticmethod
+    def _hand_over(it: _Item, stamps: tuple) -> None:
+        """One request's record, or the stamps for whoever writes it: a
+        caller that said when it received the request finishes the record
+        once its reply is out, so the stamps ride the future; every other
+        request is recorded here, from enqueue to result."""
+        ident = (it.rid, it.enqueued, it.trace, it.wire_ms)
+        if it.received is None:
+            record_request(ident + stamps, it.enqueued, stamps[3])
+        else:
+            it.future.request_stamps = ident + stamps
+
+
+def record_request(stamps: Optional[tuple], start: float,
+                   end: float) -> None:
+    """The ``serving.request`` record of one request: ONE retro-stamped
+    span a request, its window ``start``..``end`` (received..replied for
+    a wire-level caller, enqueued..scored otherwise), its attributes the
+    request-scoped decomposition: the ``time.perf_counter()`` stamps
+    ``enqueued`` / ``flush`` (the score call begins) / ``scored`` (it
+    returned), and what they span — queue wait (sitting in the bounded
+    queue), batch assembly (the coalescing window) and the engine call.
+    ``stamps`` is what :meth:`MicroBatcher._hand_over` left on the future,
+    or None for a request that never reached a batch (refused, shed,
+    expired): its record says only that, and how long the caller held it."""
+    if stamps is None:
+        obs.add_span("serving.request", start, end, cat="serving", ok=False)
+        return
+    (rid, enqueued, trace, wire_ms,
+     bid, t_first, t0, t1, degraded, failover, error) = stamps
+    attrs = {
+        "request_id": rid,
+        "batch_id": bid,
+        "ok": error is None,
+        "enqueued": enqueued,
+        "flush": t0,
+        "scored": t1,
+        "degraded": degraded,
+        "failover": failover,
+    }
+    if error is None:
+        attrs["queue_wait_ms"] = max(t_first - enqueued, 0.0) * 1e3
+        attrs["assembly_ms"] = max(t0 - t_first, 0.0) * 1e3
+        attrs["device_ms"] = (t1 - t0) * 1e3
+    else:
+        attrs["error"] = error
+    if trace is not None:
+        attrs["trace"] = trace
+    if wire_ms is not None:
+        attrs["wire_read_ms"] = wire_ms
+    obs.add_span("serving.request", start, end, cat="serving", **attrs)

@@ -208,6 +208,20 @@ class ScoreRequest:
     offset: float = 0.0
 
 
+@dataclasses.dataclass
+class _ScorePlan:
+    """One batch, prepared on the host (``ScoringEngine._prepare``):
+    everything ``_score`` needs to dispatch, fetch and finish it."""
+
+    rows: int
+    bucket: int
+    action: object  # what the serving.score fault site said
+    attrs: dict  # further attributes of the serving.score span
+    call: object  # () -> scores on the device (the compiled call)
+    finish: object  # fetched array -> (rows,) scores
+    record: object  # seconds of dispatch + fetch -> stats
+
+
 class ScoringEngine:
     """In-process online scorer for one loaded GAME model version.
 
@@ -595,21 +609,22 @@ class ScoringEngine:
         dense shards. Shares kernels with ``score_game_data`` so online
         and offline scores agree to float rounding."""
         n = feats[self._used_shards[0]].shape[0]
-        total = jnp.zeros((n,), self.dtype)
-        for name in self._coord_order:
-            p = params[name]
-            f = feats[self.shards[name]]
-            re_key = self.random_effects.get(name)
-            if re_key is None:
-                total = total + _fixed_scores(p, f)
-            elif hasattr(p, "gamma"):
-                total = total + _factored_scores(
-                    p.gamma, p.projection, f, ents[re_key]
-                )
-            else:
-                total = total + _random_scores_compact_dense(
-                    p.columns, p.values, f, ents[re_key]
-                )
+        with jax.named_scope("score"):
+            total = jnp.zeros((n,), self.dtype)
+            for name in self._coord_order:
+                p = params[name]
+                f = feats[self.shards[name]]
+                re_key = self.random_effects.get(name)
+                if re_key is None:
+                    total = total + _fixed_scores(p, f)
+                elif hasattr(p, "gamma"):
+                    total = total + _factored_scores(
+                        p.gamma, p.projection, f, ents[re_key]
+                    )
+                else:
+                    total = total + _random_scores_compact_dense(
+                        p.columns, p.values, f, ents[re_key]
+                    )
         return total
 
     def _score_padded_fixed(self, params, feats):
@@ -619,11 +634,12 @@ class ScoringEngine:
         pressure. A model with no fixed coordinate scores 0 (the
         cold-start value every random effect already returns)."""
         n = feats[self._used_shards[0]].shape[0]
-        total = jnp.zeros((n,), self.dtype)
-        for name in self._fixed_coords:
-            total = total + _fixed_scores(
-                params[name], feats[self.shards[name]]
-            )
+        with jax.named_scope("score"):
+            total = jnp.zeros((n,), self.dtype)
+            for name in self._fixed_coords:
+                total = total + _fixed_scores(
+                    params[name], feats[self.shards[name]]
+                )
         return total
 
     # -- compilation cache -------------------------------------------------
@@ -670,15 +686,6 @@ class ScoringEngine:
         if prior is compiled and fresh[0]:
             self.compile_count += 1
             self.stats.record_compile()
-            # cost-book the fresh executable (FLOPs, footprint,
-            # collectives) keyed by bucket — per-bucket score spans read
-            # this back for live MFU attribution; the analyses run on an
-            # already-compiled object, so recording costs attribute reads
-            obs.cost_book().record(
-                "serving.score",
-                compiled,
-                bucket=f"{bucket}-fixed" if fixed_only else str(bucket),
-            )
         self.stats.record_bucket(bucket, hit=False)
         return prior
 
@@ -856,7 +863,64 @@ class ScoringEngine:
         ``fixed_only`` the random-effect/factored coordinates are skipped
         (degraded mode: every row scores as if cold-start). Returns
         (B,) float scores (+ offsets when given)."""
-        entity_ids = entity_ids or {}
+        return self._score(None, features, entity_ids, offsets, fixed_only)
+
+    def score(
+        self, requests: Sequence[ScoreRequest], fixed_only: bool = False
+    ) -> np.ndarray:
+        """Featurize and score a batch of requests (scores include each
+        request's offset). ``fixed_only`` is the degraded serving mode:
+        random effects are skipped, every request scores like cold-start."""
+        return self._score(requests, None, None, None, fixed_only)
+
+    def _score(self, requests, features, entity_ids, offsets, fixed_only):
+        """One batch under its spans: ``serving.score`` is the parent of
+        ``serving.featurize`` (request objects to padded arrays, entity
+        translation, the executable looked up), ``serving.dispatch`` (the
+        compiled call, until it returns) and ``serving.fetch`` (until the
+        scores are on the host). Inside the micro-batcher every one of
+        them inherits ``batch_id`` from the ambient span context."""
+        with obs.span(
+            "serving.score",
+            cat="serving",
+            fixed_only=fixed_only,
+            sparse_kernel=self._sparse_kernel,
+        ) as sp:
+            with obs.span("serving.featurize", cat="serving") as fsp:
+                if requests is not None:
+                    features, entity_ids, offsets = self.featurize(requests)
+                plan = self._prepare(features, entity_ids or {}, fixed_only)
+                fsp.set(rows=plan.rows, bucket=plan.bucket)
+            sp.set(rows=plan.rows, bucket=plan.bucket, **plan.attrs)
+            t0 = time.perf_counter()
+            with obs.span("serving.dispatch", cat="serving"):
+                on_device = plan.call()
+            with obs.span("serving.fetch", cat="serving"):
+                on_host = np.asarray(on_device)
+            out = plan.finish(on_host)
+            if plan.action.corrupt:
+                out = np.full_like(out, np.nan)
+            # the np.asarray above synchronized, so the window is true
+            # dispatch-to-done time. Per-bucket device latency: the
+            # aggregate device_ms histogram cannot say WHICH padded size
+            # is slow
+            plan.record(time.perf_counter() - t0)
+        if offsets is not None:
+            out = out + np.asarray(offsets, out.dtype)
+        if self.drift is not None and not fixed_only:
+            # sample this batch's (unpadded) features + scores into the
+            # live drift window. Degraded batches are skipped — fixed-
+            # effect-only scores are a different distribution by design
+            # and would read as model drift.
+            self.drift.observe(
+                {s: np.asarray(features[s]) for s in self._used_shards},
+                out,
+            )
+        return out
+
+    def _prepare(self, features, entity_ids, fixed_only) -> "_ScorePlan":
+        """Host-side half of one batch: validate, pad to the bucket,
+        translate entity ids, find the bucket's executable."""
         missing = [s for s in self._used_shards if s not in features]
         if missing:
             raise KeyError(f"missing feature shard(s): {missing}")
@@ -894,61 +958,18 @@ class ScoringEngine:
             {s: feats_p[s].shape[1] for s in self._used_shards},
             fixed_only=fixed_only,
         )
-        with obs.span(
-            "serving.score",
-            cat="serving",
-            bucket=bucket,
+        args = (params, feats_p) if fixed_only else (params, feats_p, ents_p)
+        return _ScorePlan(
             rows=n,
-            fixed_only=fixed_only,
-            unknown_entities=unknown,
-            sparse_kernel=self._sparse_kernel,
-        ) as sp:
-            t0 = time.perf_counter()
-            if fixed_only:
-                out = np.asarray(compiled(params, feats_p))[:n]
-            else:
-                out = np.asarray(
-                    compiled(params, feats_p, ents_p)
-                )[:n]
-            if action.corrupt:
-                out = np.full_like(out, np.nan)
-            elapsed = time.perf_counter() - t0
-            # per-bucket device latency: the aggregate device_ms
-            # histogram cannot say WHICH padded size is slow
-            self.stats.record_bucket_latency(bucket, elapsed)
-            if obs.get_tracer() is not None:
-                # the np.asarray above already synchronized, so the
-                # window is true dispatch-to-done device time; annotate
-                # live MFU for this score bucket from the cost book
-                obs.annotate_span(
-                    sp,
-                    obs.cost_book().lookup(
-                        "serving.score",
-                        f"{bucket}-fixed" if fixed_only else str(bucket),
-                    ),
-                    seconds=elapsed,
-                )
-        if offsets is not None:
-            out = out + np.asarray(offsets, out.dtype)
-        if self.drift is not None and not fixed_only:
-            # sample this batch's (unpadded) features + scores into the
-            # live drift window. Degraded batches are skipped — fixed-
-            # effect-only scores are a different distribution by design
-            # and would read as model drift.
-            self.drift.observe(
-                {s: np.asarray(features[s]) for s in self._used_shards},
-                out,
-            )
-        return out
-
-    def score(
-        self, requests: Sequence[ScoreRequest], fixed_only: bool = False
-    ) -> np.ndarray:
-        """Featurize and score a batch of requests (scores include each
-        request's offset). ``fixed_only`` is the degraded serving mode:
-        random effects are skipped, every request scores like cold-start."""
-        feats, ents, offsets = self.featurize(requests)
-        return self.score_arrays(feats, ents, offsets, fixed_only=fixed_only)
+            bucket=bucket,
+            action=action,
+            attrs={"unknown_entities": unknown},
+            call=lambda: compiled(*args),
+            finish=lambda host: host[:n],
+            record=lambda elapsed: self.stats.record_bucket_latency(
+                bucket, elapsed
+            ),
+        )
 
     def score_data(self, data: GameData) -> np.ndarray:
         """Score a dense-sharded :class:`GameData` through the bucketed

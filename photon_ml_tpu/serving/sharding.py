@@ -67,7 +67,11 @@ from photon_ml_tpu.game.scoring import (
     shard_compact_table,
 )
 from photon_ml_tpu.resilience import faults as _faults
-from photon_ml_tpu.serving.engine import ScoringEngine, bucket_size
+from photon_ml_tpu.serving.engine import (
+    ScoringEngine,
+    _ScorePlan,
+    bucket_size,
+)
 
 __all__ = [
     "ShardedCompactTable",
@@ -512,13 +516,14 @@ class ShardedScoringEngine(ScoringEngine):
                 {rk: P(ENTITY_AXIS, None) for rk in self._re_keys},
                 P(ENTITY_AXIS, None),
             )
-            return jax.shard_map(
-                shard_body,
-                mesh=self.mesh,
-                in_specs=in_specs,
-                out_specs=P(ENTITY_AXIS, None),
-                check_vma=False,
-            )(params, feats, ents, fixed_mask)
+            with jax.named_scope("score"):
+                return jax.shard_map(
+                    shard_body,
+                    mesh=self.mesh,
+                    in_specs=in_specs,
+                    out_specs=P(ENTITY_AXIS, None),
+                    check_vma=False,
+                )(params, feats, ents, fixed_mask)
 
         self._scorer = jax.jit(sharded_scorer)
         self._scorer_fixed = jax.jit(self._score_padded_fixed)
@@ -559,22 +564,17 @@ class ShardedScoringEngine(ScoringEngine):
 
     # -- scoring -----------------------------------------------------------
 
-    def score_arrays(
-        self,
-        features: Dict[str, np.ndarray],
-        entity_ids: Optional[Dict[str, np.ndarray]] = None,
-        offsets: Optional[np.ndarray] = None,
-        fixed_only: bool = False,
-    ) -> np.ndarray:
+    def _prepare(self, features, entity_ids, fixed_only):
+        """The routed batch as the base engine's ``_score`` runs it:
+        route rows to their owner shards, scatter the features, place
+        them on the mesh; the call is the one sharded program, the finish
+        merges the per-shard partials."""
         if fixed_only:
-            return super().score_arrays(
-                features, entity_ids, offsets, fixed_only=True
-            )
+            return super()._prepare(features, entity_ids, True)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from photon_ml_tpu.parallel.mesh import ENTITY_AXIS
 
-        entity_ids = entity_ids or {}
         missing = [s for s in self._used_shards if s not in features]
         if missing:
             raise KeyError(f"missing feature shard(s): {missing}")
@@ -612,40 +612,22 @@ class ShardedScoringEngine(ScoringEngine):
             for rk, e in plan.routed_entities().items()
         }
         mask_dev = jax.device_put(plan.routed_fixed_mask(self.dtype), sh2)
-        with obs.span(
-            "serving.score",
-            cat="serving",
-            bucket=plan.bucket,
-            rows=n,
-            shards=self.num_shards,
-            fixed_only=False,
-            sparse_kernel=self._sparse_kernel,
-        ) as sp:
-            t0 = time.perf_counter()
-            partials = np.asarray(
-                compiled(self._params, feats_dev, ents_dev, mask_dev)
-            )
-            out = plan.merge(partials)
-            if action.corrupt:
-                out = np.full_like(out, np.nan)
-            elapsed = time.perf_counter() - t0
+
+        def record(elapsed):
             self.stats.record_bucket_latency(plan.bucket, elapsed)
             self.stats.record_shard_batch(plan.counts, elapsed)
-            if obs.get_tracer() is not None:
-                obs.annotate_span(
-                    sp,
-                    obs.cost_book().lookup(
-                        "serving.score", str(plan.bucket)
-                    ),
-                    seconds=elapsed,
-                )
-        if offsets is not None:
-            out = out + np.asarray(offsets, out.dtype)
-        if self.drift is not None:
-            self.drift.observe(
-                {s: feats_np[s] for s in self._used_shards}, out
-            )
-        return out
+
+        return _ScorePlan(
+            rows=n,
+            bucket=plan.bucket,
+            action=action,
+            attrs={"shards": self.num_shards},
+            call=lambda: compiled(
+                self._params, feats_dev, ents_dev, mask_dev
+            ),
+            finish=plan.merge,
+            record=record,
+        )
 
     def shard_presort_key(self, requests: Sequence[object]) -> np.ndarray:
         """Primary owner shard per request — the MicroBatcher's

@@ -38,6 +38,16 @@ def _bound_process_state():
     gc.freeze()
 
 
+@pytest.fixture(autouse=True)
+def _fresh_span_ring():
+    """The flight ring of span records is process-global and always on:
+    each test starts with it empty, at its default capacity."""
+    from photon_ml_tpu.obs import flight
+
+    flight.reset_spans()
+    yield
+
+
 @pytest.fixture(scope="session")
 def devices():
     devs = jax.devices()

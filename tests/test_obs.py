@@ -21,7 +21,7 @@ from photon_ml_tpu.obs.metrics import (
     LatencyHistogram,
     MetricsRegistry,
 )
-from photon_ml_tpu.obs.trace import _NULL_SPAN, Tracer
+from photon_ml_tpu.obs.trace import Tracer
 
 pytestmark = pytest.mark.obs
 
@@ -105,14 +105,16 @@ class TestTracer:
         seen = {(e["args"]["thread"], e["args"]["j"]) for e in spans}
         assert len(seen) == n_threads * n_spans
 
-    def test_disabled_mode_is_shared_noop(self):
+    def test_no_tracer_span_is_live_and_lands_in_the_ring(self):
         assert obs.get_tracer() is None
         s = obs.span("anything", key="value")
-        assert s is _NULL_SPAN  # no allocation: the shared singleton
         with s:
             s.set(more="attrs")
         assert s.sync([1, 2, 3]) == [1, 2, 3]
         obs.emit_event("nothing")  # must not raise
+        rec = obs.recent_spans()[-1]
+        assert rec[0] == "anything"
+        assert rec[6]["key"] == "value" and rec[6]["more"] == "attrs"
 
     def test_trace_none_dir_is_noop(self):
         with obs.trace(None) as t:
@@ -626,8 +628,30 @@ class TestGameTraceE2E:
             evs[i]["ts"] <= evs[i + 1]["ts"] for i in range(len(evs) - 1)
         )
         updates = [e for e in evs if e["name"] == "game.update"]
-        passes = [e for e in evs if e["name"] == "game.pass"]
-        assert len(passes) == self.N_ITER
+        dispatches = [e for e in evs if e["name"] == "game.dispatch"]
+        (root,) = [e for e in evs if e["name"] == "game.cd.run"]
+        assert root["args"]["iterations"] == self.N_ITER
+        for e in dispatches + updates:
+            assert e["dur"] >= 0
+            assert root["ts"] <= e["ts"]
+            assert e["ts"] + e["dur"] <= root["ts"] + root["dur"] + 1.0
+        if fused:
+            # a fused pass is ONE indivisible dispatch: one real span a
+            # pass that says how many coordinates it covers, and no
+            # per-coordinate window invented inside it
+            passes = [
+                e for e in dispatches if e["args"]["kind"] == "fused"
+            ]
+            assert [e["args"]["iteration"] for e in passes] == list(
+                range(self.N_ITER)
+            )
+            assert all(
+                e["args"]["passes"] == 1
+                and e["args"]["coordinates"] == n_coords
+                for e in passes
+            )
+            assert not updates
+            return
         # exactly one span per pass per coordinate
         assert len(updates) == self.N_ITER * n_coords
         seen = {
@@ -635,10 +659,15 @@ class TestGameTraceE2E:
             for e in updates
         }
         assert len(seen) == self.N_ITER * n_coords
-        for e in updates:
-            assert e["dur"] >= 0
-            if fused is not None:
-                assert bool(e["args"].get("fused", False)) == fused
+        assert {it for it, _ in seen} == set(range(self.N_ITER))
+        # each update's dispatches lie inside its own window
+        for u in updates:
+            inside = [
+                d for d in dispatches
+                if u["ts"] <= d["ts"]
+                and d["ts"] + d["dur"] <= u["ts"] + u["dur"] + 1.0
+            ]
+            assert any(d["args"]["kind"] == "update" for d in inside)
 
     def test_fused_run_trace_and_metrics(self, rng, tmp_path):
         cd = _build_cd(rng, fuse_passes=True)
@@ -735,7 +764,7 @@ class TestDriverSurfacing:
     def test_game_train_trace_dir_acceptance(self, rng, tmp_path):
         """The PR's acceptance artifact: a smoke GAME training run with
         trace_dir set produces (a) a valid Chrome trace with one
-        game.update span per pass per coordinate and (b) a metrics.json
+        game.dispatch span per fused pass and (b) a metrics.json
         carrying solver iteration counts, the recompile count, and
         ingest + checkpoint bytes."""
         from photon_ml_tpu.cli.game_train import run_game_training
@@ -781,23 +810,27 @@ class TestDriverSurfacing:
         finally:
             obs.set_registry(prev)
 
-        # (a) valid Chrome trace, one update span per pass per coordinate
+        # (a) valid Chrome trace, one dispatch span per fused pass
         doc = json.load(open(os.path.join(tdir, "trace.json")))
         evs = doc["traceEvents"]
         assert all(
             evs[i]["ts"] <= evs[i + 1]["ts"] for i in range(len(evs) - 1)
         )
         assert all(e.get("dur", 0) >= 0 for e in evs)
-        updates = [e for e in evs if e["name"] == "game.update"]
-        assert len(updates) == n_iter * 2
-        assert {
-            (e["args"]["iteration"], e["args"]["coordinate"])
-            for e in updates
-        } == {
-            (it, c)
-            for it in range(n_iter)
-            for c in ("global", "per-user")
+        passes = [
+            e for e in evs
+            if e["name"] == "game.dispatch"
+            and e["args"]["kind"] == "fused"
+        ]
+        assert [e["args"]["iteration"] for e in passes] == list(
+            range(n_iter)
+        )
+        assert all(e["args"]["coordinates"] == 2 for e in passes)
+        # checkpoint_every=1: each boundary's host copy is a fetch span
+        fetched = {
+            e["args"]["what"] for e in evs if e["name"] == "game.fetch"
         }
+        assert {"checkpoint", "history"} <= fetched
         # driver phases (timed() call sites) landed as spans for free
         names = {e["name"] for e in evs}
         assert "prepare data" in names and "save models" in names
@@ -987,87 +1020,6 @@ class TestCostBook:
         assert "mfu" not in got and "hbm_util" not in got
         with pytest.raises(RuntimeError, match="no roofline peaks"):
             require_device_peaks()
-
-    def test_glm_solve_span_flops_match_counted_passes(self, tmp_path):
-        """Traced train_glm spans carry flops == design_passes x the
-        cost book's per-pass FLOPs and the achieved rate over the span's
-        own window; no MFU on this CPU (unlisted device)."""
-        from photon_ml_tpu.models import (
-            GLMTrainingConfig,
-            OptimizerType,
-            TaskType,
-            train_glm,
-        )
-        from photon_ml_tpu.ops import RegularizationContext
-        from photon_ml_tpu.core.types import LabeledBatch
-        from photon_ml_tpu.solvers import design_passes
-
-        rng = np.random.default_rng(11)
-        n, d = 4096, 32
-        x = rng.standard_normal((n, d)).astype(np.float32)
-        y = (rng.uniform(size=n) < 0.5).astype(np.float32)
-        batch = LabeledBatch.create(x, y, dtype=jnp.float32)
-        cfg = GLMTrainingConfig(
-            task=TaskType.LOGISTIC_REGRESSION,
-            optimizer=OptimizerType.TRON,
-            regularization=RegularizationContext("L2"),
-            reg_weights=(1.0,),
-            max_iters=5,
-            track_states=False,
-        )
-        book = obs.CostBook()
-        prev = obs.set_cost_book(book)
-        try:
-            with obs.trace(str(tmp_path / "t")) as tracer:
-                (tm,) = train_glm(batch, cfg)
-        finally:
-            obs.set_cost_book(prev)
-        rec = book.lookup("glm.objective_pass", f"{n}x{d}")
-        assert rec is not None and rec.flops is not None
-        spans = [
-            e for e in tracer.events() if e.get("name") == "glm.solve"
-        ]
-        assert len(spans) == 1
-        args = spans[0]["args"]
-        passes = design_passes(tm.result)
-        assert args["flops"] == pytest.approx(rec.flops * passes, rel=1e-6)
-        assert args["achieved_tflops"] > 0
-        assert "mfu" not in args
-
-    def test_game_pass_spans_carry_attribution(self, rng, tmp_path):
-        """Chunked-mode GAME runs annotate game.update and game.pass
-        spans with flops/achieved_tflops from the cost book."""
-        cd = _build_cd(rng, fuse_passes="coordinate")
-        book = obs.CostBook()
-        prev = obs.set_cost_book(book)
-        try:
-            with obs.trace(str(tmp_path / "t")) as tracer:
-                cd.run(num_iterations=2)
-        finally:
-            obs.set_cost_book(prev)
-        evs = tracer.events()
-        updates = [e for e in evs if e.get("name") == "game.update"]
-        passes = [e for e in evs if e.get("name") == "game.pass"]
-        assert updates and passes
-        for e in updates + passes:
-            assert e["args"]["flops"] > 0
-            assert e["args"]["achieved_tflops"] > 0
-            assert "mfu" not in e["args"]  # CPU: unlisted device
-            assert e["args"]["timing"] == "wall"
-        assert book.lookup("game.update", "fixed") is not None
-        assert book.lookup("game.update", "per-user") is not None
-
-    def test_untraced_run_records_no_cost(self, rng):
-        """Without a tracer the cost book stays empty for GAME runs —
-        the lowering re-trace must never tax an unobserved run."""
-        cd = _build_cd(rng, fuse_passes="coordinate")
-        book = obs.CostBook()
-        prev = obs.set_cost_book(book)
-        try:
-            cd.run(num_iterations=1)
-        finally:
-            obs.set_cost_book(prev)
-        assert book.names() == []
 
 
 # ---------------------------------------------------------------------------
