@@ -404,9 +404,15 @@ def _split_minimizing_padding(sorted_counts: np.ndarray, max_buckets: int):
     millions). Returns [(lo, hi)) index ranges into sorted_counts."""
     if sorted_counts.size == 0:
         return []
-    values, first, nums = np.unique(
-        sorted_counts, return_index=True, return_counts=True
-    )
+    values, nums = np.unique(sorted_counts, return_counts=True)
+    return _split_histogram_minimizing_padding(values, nums, max_buckets)
+
+
+def _split_histogram_minimizing_padding(
+    values: np.ndarray, nums: np.ndarray, max_buckets: int
+):
+    """``_split_minimizing_padding`` from the counts' histogram: ascending
+    distinct ``values``, each held by ``nums`` entries (all above zero)."""
     m = values.size
     k = min(max_buckets, m)
     prefix = np.concatenate([[0], np.cumsum(nums)])

@@ -40,6 +40,7 @@ from photon_ml_tpu.core.normalization import (
 )
 from photon_ml_tpu.core.types import Coefficients, LabeledBatch
 from photon_ml_tpu.models.glm import GeneralizedLinearModel, TaskType
+from photon_ml_tpu.ops import sparse as sparse_ops
 from photon_ml_tpu.ops.losses import loss_for_task
 from photon_ml_tpu.ops.objective import GLMObjective, RegularizationContext
 from photon_ml_tpu.ops.stats import summarize_features
@@ -443,6 +444,69 @@ def prepare_normalization(
     )
 
 
+@jax.jit
+def _take_rows(perm, *columns):
+    return tuple(c[perm] for c in columns)
+
+
+def _hot_cold_layout(
+    batch: LabeledBatch, config: GLMTrainingConfig
+) -> LabeledBatch:
+    """The batch this solve should run on: a plain padded-ELL design split
+    hot/cold on its device where ``ops.sparse.split_hot_cold`` finds that
+    its own column counts pay for it over this solve, the row-aligned
+    columns permuted to the hybrid's stored order; any other batch as it
+    came. The models live in coefficient space, so callers see no
+    difference. Paid inside every call, under the ``glm.layout`` span,
+    and nothing of it outlives the call: a caller that solves many times
+    on one design splits once (``split_hot_cold``) and passes the
+    ``HybridFeatures``.
+
+    The one place where ``train_glm`` waits for the device before its
+    solve is enqueued: on a plain padded-ELL design on one device whose
+    kind has measured rates, the rule sorts the slots' ids and fetches the
+    largest counts before it can answer, engaged or declined, so such
+    calls no longer pipeline behind one another (every other batch is
+    answered on the host, in microseconds, as before)."""
+    x = batch.features
+    with obs.span("glm.layout", cat="solver") as sp:
+        if not sparse_ops.is_sparse(x):
+            info = {
+                "reason": "hybrid" if sparse_ops.is_hybrid(x)
+                else "feature_sharded" if sparse_ops.is_feature_sharded(x)
+                else "dense"
+            }
+        elif config.optimizer == OptimizerType.NEWTON:
+            # the explicit Hessian is built from dense features
+            info = {"reason": "dense_hessian"}
+        else:
+            itemsize = jnp.dtype(solve_dtype(batch)).itemsize
+            hybrid, info = sparse_ops.split_hot_cold(
+                x,
+                # every iteration evaluates the objective at least once
+                evaluations=len(config.reg_weights) * max(1, config.max_iters),
+                exact_squares=config.compute_variances,
+                # the quasi-Newton pairs and the solver's working vectors
+                solver_bytes=(2 * config.num_corrections + 10)
+                * x.d * itemsize,
+            )
+        if "reason" in info:
+            obs.registry().inc("sparse.split.skipped")
+            obs.registry().inc("sparse.split.skipped." + info["reason"])
+            sp.set(hot_columns=0, **info)
+            return batch
+        obs.registry().inc("sparse.split.engaged")
+        sp.set(**info)
+        labels, offsets, weights, mask = _take_rows(
+            hybrid.row_perm,
+            batch.labels, batch.offsets, batch.weights, batch.mask,
+        )
+        return dataclasses.replace(
+            batch, features=hybrid, labels=labels, offsets=offsets,
+            weights=weights, mask=mask,
+        )
+
+
 def train_glm(
     batch: LabeledBatch,
     config: GLMTrainingConfig,
@@ -471,6 +535,7 @@ def train_glm(
         if normalization is not None
         else prepare_normalization(config, batch)
     )
+    batch = _hot_cold_layout(batch, config)
     d = batch.num_features
     dtype = solve_dtype(batch)
     if initial_coefficients is not None:
@@ -500,8 +565,10 @@ def _train_glm_scan(
     """Single-dispatch regularization path: one ``lax.scan`` program over
     the descending lambda vector, decoded on the host afterwards. The
     untraced path inserts NO host syncs — results are lazy slices of the
-    stacked ys, so consecutive train_glm calls still pipeline (bench.py
-    depends on that); the traced/convergence-enabled path synchronizes
+    stacked ys, so consecutive train_glm calls still pipeline (bench.py's
+    pipelined reading, on a dense design, depends on that; the one wait
+    of ``train_glm`` is before this function, in ``_hot_cold_layout``, on
+    plain sparse designs only); the traced/convergence-enabled path synchronizes
     once and retro-emits per-lambda ``glm.solve`` spans + tape counters
     inside the one ``glm.solve_path`` span window. Spans: the root
     ``glm.solve_path`` holds ``glm.dispatch`` (the call into the compiled

@@ -151,6 +151,12 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "Pallas sparse-kernel cost records (docs/KERNELS.md)",
     ),
     (
+        "sparse",
+        r"sparse\.split\.(engaged|skipped(\.[a-z_]+)?)",
+        "the hot/cold split rule of a padded-ELL design, one a train_glm "
+        "call (sparse.split.engaged, sparse.split.skipped[.<reason>])",
+    ),
+    (
         "lint",
         r"lint\.[a-z_]+(\..+)?",
         "photon-lint analyzer metrics (docs/ANALYSIS.md)",

@@ -27,18 +27,21 @@ XLA lowering on every platform (the suite does not lower for TPU on the
 installed toolchain — kernels/dispatch.py); ``pallas`` forces the suite
 (interpret mode on CPU — the tier-1 proof; the compiler's own error on
 TPU). Dispatch happens here so every consumer — ``GLMObjective``, GAME
-random-effect batches, serving scorers, the hybrid container's cold
-segments — switches with zero call-site changes.
+random-effect batches, serving scorers — switches with zero call-site
+changes. The hybrid container's cold segments do not switch: all of them
+are contracted by one flat XLA gather / scatter-add (``_cold_matvec``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from photon_ml_tpu.kernels import dispatch as _kdispatch
 
@@ -93,13 +96,20 @@ class HybridFeatures:
     """Power-law split of a sparse matrix: dense slab for the hot columns,
     row-bucketed padded-ELL for the cold tail.
 
-    On TPU the ~130 M elem/s XLA gather/scatter bound makes every stored
-    ELL SLOT (incl. padding) cost ~8 ns, while a dense slab column costs
-    one MXU/HBM pass (~n * 4 bytes at full bandwidth) regardless of
-    sparsity — so any column with enough entries is cheaper densified
-    (the "feature-hashing into dense-ish blocks" direction of SURVEY §7
-    hard-part 3; rates measured on chip in r5, BENCH_r05). CTR-style feature
-    data is Zipf-distributed, so a small slab absorbs most entries.
+    On the v5e XLA's gather costs 8.0 ns and its scatter-add (with the
+    sort it lowers to) 10.6 ns a stored ELL SLOT, padding included,
+    whichever slot it is (ledger, PR 26, ``glm_hashed_sparse.solve``:
+    9.12 s and 12.15 s over 28 evaluations of 40.9 M slots; r5 had read
+    "~8 ns"), while a dense slab column costs one HBM pass (n * 4 bytes
+    at the 670 GB/s a slab product reads at; my chip run, PR 27)
+    regardless of sparsity — so any column with enough
+    entries is cheaper densified (the "feature-hashing into dense-ish
+    blocks" direction of SURVEY §7 hard-part 3). The rates and the
+    break-even live in one place: ``_SPLIT_RATES`` and
+    ``hot_column_break_even``. CTR-style feature data is Zipf-distributed,
+    so a small slab absorbs most entries: ``split_hot_cold`` makes the
+    split on the device from the design's own column counts, and
+    ``train_glm`` asks it on every plain padded-ELL design.
 
     Because the irregular cost scales with padded SLOTS, the cold tail is
     additionally row-bucketed: rows sorted by cold-entry count, split
@@ -374,6 +384,19 @@ def _low_precision_dot(x: jax.Array, w: jax.Array):
     return x @ w
 
 
+def _slab_dot(x: jax.Array, w: jax.Array):
+    """A hybrid slab product. A full-precision slab contracts at
+    ``Precision.HIGHEST``: left to the default, the TPU may send a float32
+    product through the MXU in one bfloat16 pass, which is another result
+    (the benchmark's bf16 control reads ``value_gap`` 2.9e-3 against a
+    limit of 3e-6), not a faster one. A slab stored low goes the way of
+    every low-precision design (``_low_precision_dot``)."""
+    low = (jnp.bfloat16, jnp.float16)
+    if x.dtype in low or w.dtype in low:
+        return _low_precision_dot(x, w)
+    return jnp.matmul(x, w, precision=lax.Precision.HIGHEST)
+
+
 def _block_margin_partials(x: "FeatureShardedSparse", w: jax.Array):
     """(F, n) per-block margin partials of a blocked container — the
     payload whose block-axis sum is THE feature-space reduction of every
@@ -416,6 +439,55 @@ def _block_margin_partials(x: "FeatureShardedSparse", w: jax.Array):
     return jax.vmap(route)(partial_v, x.row_map.T)
 
 
+def _cold_matvec(x: "HybridFeatures", w: jax.Array) -> jax.Array:
+    """Margins of the cold segments, in stored row order: ONE gather over
+    the slots of all segments, then each segment's row sums. One gather
+    (and in ``_cold_scatter`` one scatter-add with its one sort) a pass,
+    however many row buckets: XLA compiles each such op for seconds, so a
+    gather and a scatter a segment made the solve program compile for
+    minutes (PERF.md section 6, PR 27). Always XLA's lowering: the Pallas
+    suite's kernels take one ELL at a time, and do not lower on the TPU."""
+    segs = x.cold_segments
+    with jax.named_scope("sparse_gather"):
+        gathered = w.at[_cold_flat_indices(segs)].get(
+            mode="fill", fill_value=0.0
+        )
+        rows, lo = [], 0
+        for seg in segs:
+            hi = lo + seg.indices.size
+            mine = gathered[lo:hi].reshape(seg.indices.shape[::-1])
+            rows.append(jnp.sum(seg.values.T * mine, axis=0))
+            lo = hi
+        return jnp.concatenate(rows)
+
+
+def _cold_flat_indices(segs) -> jax.Array:
+    """The column ids of every slot of the cold segments as one vector,
+    each segment SLOT-major (its first slots of all rows, then its second
+    slots, ...). A gather or a scatter-add does not care in which order
+    it meets the slots, and the TPU keeps a (rows, few) array with the
+    rows along the lanes: slot-major is the order the segment is stored
+    in, row-major a transposing copy of it."""
+    return jnp.concatenate([seg.indices.T.reshape(-1) for seg in segs])
+
+
+def _cold_scatter(x: "HybridFeatures", a: jax.Array, payload):
+    """(d,) sum over the cold segments' slots of ``payload(seg) * a_row``
+    by ONE scatter-add (see ``_cold_matvec``). ``a`` is in stored row
+    order."""
+    segs = x.cold_segments
+    with jax.named_scope("sparse_scatter"):
+        upd = jnp.concatenate([
+            (payload(seg).T * a[lo:hi]).reshape(-1)
+            for (lo, hi), seg in zip(x.segment_bounds(), segs)
+        ])
+        return (
+            jnp.zeros((x.d,), upd.dtype)
+            .at[_cold_flat_indices(segs)]
+            .add(upd, mode="drop")
+        )
+
+
 def matvec(x, w: jax.Array) -> jax.Array:
     """margins contraction: (n, d) @ (d,) -> (n,). Hybrid output is in
     STORED (permuted) row order, matching the permuted batch."""
@@ -426,10 +498,7 @@ def matvec(x, w: jax.Array) -> jax.Array:
             return feature_block_sum(_block_margin_partials(x, w))
     if is_hybrid(x):
         # dtype promotion mirrors the dense path (bf16 slab @ f32 w -> f32)
-        cold = jnp.concatenate(
-            [matvec(seg, w) for seg in x.cold_segments]
-        )
-        return _low_precision_dot(x.dense, w[x.hot_ids]) + cold
+        return _slab_dot(x.dense, w[x.hot_ids]) + _cold_matvec(x, w)
     if not is_sparse(x):
         return _low_precision_dot(x, w)
     with jax.named_scope("sparse_gather"):
@@ -448,10 +517,8 @@ def rmatvec(x, a: jax.Array) -> jax.Array:
         with jax.named_scope("sparse_scatter"):
             return _rmatvec_feature_sharded(x, a)
     if is_hybrid(x):
-        g = jnp.zeros((x.d,), a.dtype)
-        for (lo, hi), seg in zip(x.segment_bounds(), x.cold_segments):
-            g = g + rmatvec(seg, a[lo:hi])
-        return g.at[x.hot_ids].add(_low_precision_dot(a, x.dense))
+        g = _cold_scatter(x, a, lambda seg: seg.values)
+        return g.at[x.hot_ids].add(_slab_dot(a, x.dense))
     if not is_sparse(x):
         return _low_precision_dot(x.T, a)
     with jax.named_scope("sparse_scatter"):
@@ -504,10 +571,13 @@ def colsum(x, c: jax.Array, square: bool = False) -> jax.Array:
         return rmatvec(dataclasses.replace(x, values=v), c)
     if is_hybrid(x):
         v = x.dense * x.dense if square else x.dense
-        hot = jnp.einsum("n,nh->h", c, v)
-        g = jnp.zeros((x.d,), c.dtype)
-        for (lo, hi), seg in zip(x.segment_bounds(), x.cold_segments):
-            g = g + colsum(seg, c[lo:hi], square=square)
+        hot = jnp.einsum(
+            "n,nh->h", c, v, precision=lax.Precision.HIGHEST
+        )
+        g = _cold_scatter(
+            x, c,
+            lambda seg: seg.values * seg.values if square else seg.values,
+        )
         return g.at[x.hot_ids].add(hot)
     if not is_sparse(x):
         v = x * x if square else x
@@ -894,13 +964,17 @@ def to_hybrid(
     max_slab_bytes: int = 1 << 30,
     num_row_buckets: int = 8,
 ) -> HybridFeatures:
-    """Split an ELL matrix into dense-hot + bucketed sparse-cold
-    (host-side, once per dataset).
+    """Split an ELL matrix into dense-hot + bucketed sparse-cold on the
+    HOST (numpy, once per dataset): the form the ``hot_columns`` option of
+    the drivers builds. A design that already lives on the device is
+    split there by ``split_hot_cold``, which is where the rule lives:
+    ``train_glm`` asks it on every plain padded-ELL design.
 
     ``hot_columns`` = H picks the H highest-count columns; -1 sizes the
-    slab automatically: columns whose stored-entry count exceeds
-    ``min_count`` (the v5e break-even measured in r5 — ~64 irregular
-    accesses cost about one dense n-row column pass), hottest
+    slab as r5 did: columns whose stored-entry count exceeds ``min_count``
+    (64, r5's reading of the break-even; the ledger's line of PR 26 puts
+    it near 670 entries at 2^20 float32 rows, ``hot_column_break_even``,
+    and this default is left as it was until the option goes), hottest
     first, until the slab reaches ``max_slab_bytes`` at the target dtype.
     A slab with zero qualifying columns degrades to H=1 so shapes stay
     static.
@@ -918,7 +992,10 @@ def to_hybrid(
     Duplicate slots would sum into one slab cell, changing the SQUARED
     statistics (colsum(square=True) -> Hessian diagonal / variances)
     relative to the ELL, so they are rejected here rather than silently
-    diverging.
+    diverging. The device path accepts them: a pair stored twice sums in
+    the slab cell, as ``matvec`` / ``rmatvec`` sum it in the ELL, and
+    where the caller will ask for squared sums (``exact_squares``) the
+    later copies stay in the cold segments, so no slab cell is a sum.
     """
     from photon_ml_tpu.game.data import _split_minimizing_padding
 
@@ -1002,6 +1079,325 @@ def to_hybrid(
         cold_segments=tuple(segments),
         row_perm=jnp.asarray(row_perm),
     )
+
+
+# -- the hot/cold split on the device ----------------------------------------
+
+# What the split rule weighs, by ``device_kind``. A kind that is not here
+# has no measured rates, and its designs stay as they are.
+#   gather_s, scatter_s: XLA's gather and scatter-add (with the sort it
+#     lowers to) a stored ELL slot, padding included, whichever slot it is:
+#     9.12 s and 9.28 + 2.86 s over 28 evaluations of 2^20 x 39 slots
+#     (ledger, PR 26, ``glm_hashed_sparse.solve``: ``fusion.60`` and
+#     ``fusion.61`` + ``sort.4`` of its ``breakdown``).
+#   slab_bytes_per_s: what a float32 slab product reads at, either way
+#     round and at ``Precision.HIGHEST`` as at the default: 6.4 ms a pass
+#     of a 2^20 x 1,024 slab, against 5.2 ms at the published 819 GB/s.
+#   count_s: the exact counts sweep a slot (0.27 s at 2^20 x 39);
+#     compare_s: the split's work a (slot x hot column) pair, both of its
+#     compare-and-reduce sweeps together (45 + 31.5 ms at 1,024 columns);
+#     fixed_s: its dispatches and its two blocking fetches.
+#   (The last four: my chip runs, PR 27; PERF.md section 6.)
+_SPLIT_RATES = {
+    "TPU v5 lite": {
+        "gather_s": 8.0e-9,
+        "scatter_s": 10.6e-9,
+        "slab_bytes_per_s": 670e9,
+        "count_s": 6.6e-9,
+        "compare_s": 1.9e-12,
+        "fixed_s": 3e-3,
+    },
+}
+# device bytes a stored slot costs the solve program beside the design
+# itself (gathered values, scatter updates and the sort's copies): 4.69 GB
+# reserved at 40.9 M slots less the solver's 30 coefficient vectors
+# (ledger, PR 26, ``memory_stats`` of ``glm_hashed_sparse.solve``), rounded
+# up. Counted for every slot of the plain design, though the solve on the
+# split design gathers and scatters only the cold ones.
+_SOLVE_SCRATCH_BYTES_A_SLOT = 80
+# and what the split holds a slot while it runs, beside the design and the
+# slab: the rows' compacted cold ids and values (8), the cut segments (up
+# to 8), its programs' temporaries (the compiler's memory analysis for the
+# v5e at 2^20 x 39: 13 B for the counts sort, 7 B for ``_split_rows``; PR
+# 27), rounded up twofold. The loaded solve program keeps its scratch
+# reserved meanwhile, so the two add up.
+_SPLIT_SCRATCH_BYTES_A_SLOT = 40
+_TOP_COLUMNS = 4096  # counts the host looks at: no slab is wider
+_SLAB_LANES = 128  # a slab is whole lanes wide; the spare columns stay empty
+
+
+def _device_profile(device):
+    """(rates, free bytes, bytes the runtime holds reserved for the loaded
+    programs' scratch) the split rule may count on for ``device``, or the
+    reason why it cannot: ``"no_rates"`` for a device kind nobody measured,
+    ``"no_memory_stats"`` where the backend reports none (the CPU)."""
+    rates = _SPLIT_RATES.get(device.device_kind)
+    if rates is None:
+        return "no_rates"
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats:
+        return "no_memory_stats"
+    # ``largest_free_block_bytes`` is left out: it moved between the jobs
+    # of one process by gigabytes (5.0 against 11 GB free), the hot count
+    # with it, and every program of the job compiled again (PR 27)
+    reserved = stats.get("bytes_reserved", 0)
+    free = stats["bytes_limit"] - stats.get("bytes_in_use", 0) - reserved
+    return rates, int(free), int(reserved)
+
+
+def hot_column_break_even(
+    n: int, k: int, itemsize: int, rates: dict, evaluations: float
+) -> float:
+    """Stored entries a column needs before a dense slab column is cheaper:
+    every entry costs a gather and a scatter-add an evaluation, a slab
+    column two reads of ``n`` values at the slab rate, and making the column
+    its share of the split's two compare sweeps, once."""
+    dense_s = 2.0 * n * itemsize / rates["slab_bytes_per_s"]
+    make_s = n * k * rates["compare_s"] / max(float(evaluations), 1.0)
+    return (dense_s + make_s) / (rates["gather_s"] + rates["scatter_s"])
+
+
+@partial(jax.jit, static_argnames=("d", "h_max"))
+def _top_column_counts(indices, *, d, h_max):
+    """The ``h_max`` largest stored-slot counts of the design's columns,
+    descending, and their column ids. Exact: designs that differ by a
+    relabelling get the same counts. By a sort of the slots' ids and the
+    lengths of its runs, which costs half of what a scatter-add of ones
+    into ``d`` counters does (0.27 s against 0.51 s at 2^20 x 39, my chip
+    run, PR 27); the slots in stored order, which is slot-major on the TPU
+    (``_cold_flat_indices``)."""
+    with jax.named_scope("split_counts"):
+        ids = jnp.sort(indices.T.reshape(-1))
+        size = ids.shape[0]
+        at = jnp.arange(size, dtype=jnp.int32)
+        first = jnp.concatenate([jnp.ones((1,), bool), ids[1:] != ids[:-1]])
+        # a run ends where the next one starts
+        next_first = lax.cummin(jnp.where(first, at, size), reverse=True)
+        ends = jnp.concatenate(
+            [next_first[1:], jnp.full((1,), size, jnp.int32)]
+        )
+        runs = jnp.where(first & (ids < d), ends - at, 0)  # padding: id d
+        top, where = lax.top_k(runs, h_max)
+        return top, ids[where]
+
+
+def _first_occurrence(indices):
+    """(n, k) bool: False on the second and later slots of a row that hold
+    a column an earlier slot of the row holds."""
+    k = indices.shape[-1]
+    same = indices[:, :, None] == indices[:, None, :]  # [row, slot, other]
+    earlier = jnp.tril(jnp.ones((k, k), bool), -1)  # other < slot
+    return ~jnp.any(same & earlier, axis=-1)
+
+
+@partial(jax.jit, static_argnames=("d", "h", "width", "exact_squares"))
+def _split_rows(indices, values, top_ids, *, d, h, width, exact_squares):
+    """Everything of the split whose shapes the cold counts do not set: the
+    rows sorted by cold count, the slab, and every row's cold slots moved to
+    its front. Slots go to the slab by compare-and-sum over the row's own
+    slots, so nothing is gathered or scattered and a pair stored twice
+    sums, as ``matvec`` / ``rmatvec`` treat it in the ELL. The slab is
+    ``width >= h`` columns wide; the columns past ``h`` stay empty (their
+    slots stay cold)."""
+    n, k = indices.shape
+    hot_ids = top_ids[:width]
+    acc = jnp.promote_types(values.dtype, jnp.float32)
+
+    with jax.named_scope("split_mark"):
+        # padding holds column d, which is no hot column
+        hot = jnp.any(indices[:, :, None] == hot_ids[:h], axis=-1)
+        if exact_squares:
+            # a later copy of a pair stays cold, so that no slab cell is a
+            # sum and the squared column sums are the ELL's
+            hot = hot & _first_occurrence(indices)
+        cold = ~hot & (indices < d)
+        cold_count = jnp.sum(cold, axis=-1, dtype=jnp.int32)
+        histogram = jnp.sum(
+            cold_count[:, None] == jnp.arange(k + 1, dtype=jnp.int32),
+            axis=0, dtype=jnp.int32,
+        )
+        stored = jnp.sum(indices < d, dtype=jnp.int32)
+        row_perm = jnp.argsort(cold_count, stable=True).astype(jnp.int32)
+
+    with jax.named_scope("split_permute"):
+        idx, val, cold = indices[row_perm], values[row_perm], cold[row_perm]
+
+    with jax.named_scope("split_slab"):
+        mine = idx[:, :, None] == jnp.where(
+            jnp.arange(width) < h, hot_ids, -1
+        )
+        if exact_squares:
+            mine = mine & hot[row_perm][:, :, None]
+        dense = jnp.sum(
+            jnp.where(mine, val[:, :, None].astype(acc), 0), axis=1
+        ).astype(values.dtype)
+
+    with jax.named_scope("split_compact"):
+        _, cold_idx, cold_val = lax.sort(
+            (
+                (~cold).astype(jnp.int8),
+                jnp.where(cold, idx, d),
+                jnp.where(cold, val, 0),
+            ),
+            dimension=1, is_stable=True, num_keys=1,
+        )
+    counts = jnp.concatenate([histogram, stored[None]])
+    return dense, hot_ids, cold_idx, cold_val, row_perm, counts
+
+
+@partial(jax.jit, static_argnames=("d", "cuts"))
+def _cut_segments(cold_idx, cold_val, *, d, cuts):
+    return tuple(
+        SparseFeatures(cold_idx[lo:hi, :w], cold_val[lo:hi, :w], d)
+        for lo, hi, w in cuts
+    )
+
+
+def split_on_device(
+    sf: SparseFeatures,
+    top_ids: jax.Array,
+    hot_columns: int,
+    exact_squares: bool = False,
+    num_row_buckets: int = 8,
+    slab_columns: Optional[int] = None,
+):
+    """The mechanics of the device-side split: ``sf`` with the first
+    ``hot_columns`` of ``top_ids`` densified, in a slab of ``slab_columns``
+    (the same, unless given; the further columns take the next ids and
+    stay empty). Returns the ``HybridFeatures``, the histogram of the rows'
+    cold counts and the stored slots. Compiled device code and one fetch
+    of ``k + 2`` integers; the static shapes of the cold segments come
+    from that fetch."""
+    from photon_ml_tpu.game.data import _split_histogram_minimizing_padding
+
+    n, k = sf.indices.shape
+    dense, hot_ids, cold_idx, cold_val, row_perm, counts = _split_rows(
+        sf.indices, sf.values, top_ids,
+        d=sf.d, h=int(hot_columns), width=int(slab_columns or hot_columns),
+        exact_squares=bool(exact_squares),
+    )
+    counts = np.asarray(counts)
+    histogram, stored = counts[:-1], int(counts[-1])
+    held = np.flatnonzero(histogram)
+    bounds = _split_histogram_minimizing_padding(
+        held, histogram[held], max(1, num_row_buckets)
+    ) or [(0, n)]
+    ends = np.cumsum(histogram)
+    cuts = tuple(
+        # a segment is as wide as its last row's cold count
+        (lo, hi, max(1, int(np.searchsorted(ends, hi, side="left"))))
+        for lo, hi in bounds
+    )
+    segments = _cut_segments(cold_idx, cold_val, d=sf.d, cuts=cuts)
+    hf = HybridFeatures(
+        dense=dense, hot_ids=hot_ids, cold_segments=segments,
+        row_perm=row_perm,
+    )
+    return hf, histogram, stored
+
+
+def split_hot_cold(
+    sf: SparseFeatures,
+    evaluations: float,
+    exact_squares: bool = False,
+    solver_bytes: int = 0,
+):
+    """The rule: split ``sf`` hot/cold on its device where its own column
+    counts say that pays over ``evaluations`` objective evaluations.
+
+    Returns ``(HybridFeatures, info)``, or ``(None, info)`` with
+    ``info["reason"]`` where the design stays as it is. Which columns are
+    hot changes the speed of a pass and never its result, so the rule needs
+    no option: it reads the design (its column counts, where its arrays
+    live), the device (``_device_profile``: measured rates, free memory)
+    and what the caller will do with it (``evaluations``; ``exact_squares``
+    when it will ask for ``colsum(square=True)``; ``solver_bytes`` it will
+    hold beside the design).
+
+    A column goes to the slab when its stored entries pass
+    ``hot_column_break_even``, cut at a whole count (columns that tie stay
+    together, so designs that differ by a relabelling split alike and
+    compile the same programs), as many as the free memory holds. The split
+    is declined when no column passes, or when what it saves over the
+    solve is under what it costs to make.
+    """
+    n, k = sf.indices.shape
+    if not isinstance(sf.indices, jax.Array) or isinstance(
+        sf.indices, jax.core.Tracer
+    ):
+        return None, {"reason": "not_on_device"}
+    devices = sf.indices.sharding.device_set | sf.values.sharding.device_set
+    if len(devices) > 1:
+        return None, {"reason": "sharded"}
+    profile = _device_profile(next(iter(devices)))
+    if isinstance(profile, str):
+        return None, {"reason": profile}
+    rates, free, reserved = profile
+    slot_s = rates["gather_s"] + rates["scatter_s"]
+    itemsize = jnp.dtype(sf.values.dtype).itemsize
+    evaluations = max(float(evaluations), 1.0)
+    if evaluations * n * k * slot_s <= rates["fixed_s"] + (
+        n * k * rates["count_s"]
+    ):
+        # not even a split that removed every slot would pay
+        return None, {"reason": "does_not_pay"}
+
+    # memory: the slab may take HALF of what is free beside what the solve
+    # and the split will hold. The other half is for a second copy of the
+    # slab, which XLA has kept in another layout (PERF.md section 6, PR 27,
+    # finding 3). And down to a power of two: what is free follows the
+    # process's state by some hundred MB, and a cap that followed it in
+    # small steps would give the jobs of one process different shapes, each
+    # with its own compile.
+    scratch = solver_bytes + n * k * (
+        _SOLVE_SCRATCH_BYTES_A_SLOT + _SPLIT_SCRATCH_BYTES_A_SLOT
+    )
+    h_cap = (free - max(scratch - reserved, 0)) // (2 * n * itemsize)
+    if h_cap < 1:
+        return None, {"reason": "no_memory"}
+    h_cap = 1 << (int(h_cap).bit_length() - 1)
+
+    top, top_ids = _top_column_counts(
+        # a design of fewer slots than that has no more columns either
+        sf.indices, d=sf.d, h_max=min(sf.d, n * k, _TOP_COLUMNS)
+    )
+    top = np.asarray(top)  # descending
+    need = hot_column_break_even(n, k, itemsize, rates, evaluations)
+    paying = int(np.searchsorted(-top, -need, side="left"))  # counts > need
+    h = min(paying, h_cap)
+    if h < paying or (h == len(top) and h < sf.d):
+        # the cap cut columns that pay: cut at a whole count, so that the
+        # columns that tie with the first one left out stay out with it
+        h = int(np.searchsorted(-top, -top[min(h, len(top) - 1)], "left"))
+    if h < 1:
+        return None, {"reason": "no_hot_column"}
+    hot_slots = int(top[:h].sum())
+    saved_s = evaluations * (
+        hot_slots * slot_s
+        - 2.0 * h * n * itemsize / rates["slab_bytes_per_s"]
+    )
+    cost_s = rates["fixed_s"] + n * k * (
+        rates["count_s"] + h * rates["compare_s"]
+    )
+    if saved_s <= cost_s:
+        return None, {"reason": "does_not_pay"}
+
+    # whole lanes: at a width that is no multiple of 128 the solve program
+    # may keep a second copy of the slab in another layout (9.9 GB of
+    # scratch at 1,586 columns against 3.0 GB at 1,536 or 1,664; PR 27)
+    width = min(-(-h // _SLAB_LANES) * _SLAB_LANES, len(top))
+    hf, histogram, stored = split_on_device(
+        sf, top_ids, h, exact_squares=exact_squares,
+        slab_columns=width if width <= max(h, h_cap) else h,
+    )
+    return hf, {
+        "hot_columns": h,
+        "hot_slot_share": 1.0 - float(
+            np.dot(histogram, np.arange(k + 1))
+        ) / max(stored, 1),
+        "cold_padded_slots": cold_padded_slots(hf),
+        "segments": len(hf.cold_segments),
+    }
 
 
 def from_dense(x: np.ndarray, nnz_per_row: int = 0, dtype=jnp.float32) -> SparseFeatures:
