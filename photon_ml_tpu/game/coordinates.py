@@ -567,15 +567,17 @@ def _make_multi_bucket_update_cached(config: CoordinateConfig):
     ):
         trackers = []
         for eidx, bucket in zip(entity_indices, buckets):
-            offsets = bucket.gather_offsets(full_offsets)
-            w0 = jnp.take(table, eidx, axis=0, mode="clip")
-            lam = jnp.take(reg_weights, eidx, mode="clip")
+            with jax.named_scope("re_gather"):
+                offsets = bucket.gather_offsets(full_offsets)
+                w0 = jnp.take(table, eidx, axis=0, mode="clip")
+                lam = jnp.take(reg_weights, eidx, mode="clip")
             with jax.named_scope("re_newton_solve"):
                 result = solve(
                     w0, lam, bucket.features, bucket.labels, offsets,
                     bucket.weights, bucket.mask,
                 )
-            table = table.at[eidx].set(result.w, mode="drop")
+            with jax.named_scope("re_scatter"):
+                table = table.at[eidx].set(result.w, mode="drop")
             # final per-entity gradient norm rides the tracker tuple
             # (valid with tracking on or off), feeding the fleet-level
             # convergence summaries' worst-k signal for free — it is
@@ -584,7 +586,8 @@ def _make_multi_bucket_update_cached(config: CoordinateConfig):
                 (result.reason, result.iterations, final_grad_norm(result))
             )
         # full-row rescore in the same dispatch
-        scores = _score_rows_by_entity(table, row_features, row_entities)
+        with jax.named_scope("re_score"):
+            scores = _score_rows_by_entity(table, row_features, row_entities)
         return table, tuple(trackers), scores
 
     return update_all

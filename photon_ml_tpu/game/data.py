@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from photon_ml_tpu import obs
 from photon_ml_tpu.core.types import LabeledBatch, _pytree_dataclass
 
 
@@ -456,11 +457,44 @@ def build_bucketed_random_effect_design(
     min_support: int = 0,
 ) -> BucketedRandomEffectDesign:
     """Like :func:`build_random_effect_design` but with per-size-class row
-    caps. Entities (those with data) are sorted by row count and split into
+    caps. Entities (those with data) are sorted by ACTIVE row count (the
+    count, or `active_cap` where the count passes it) and split into
     `num_buckets` contiguous groups; each bucket's row cap is its own max
-    count (still bounded by `active_cap`, with the same weight-preserving
-    rescale). `entity_multiple` pads each bucket's entity axis up to a
-    multiple (the entity-mesh-axis size) so buckets shard evenly."""
+    active count (rows beyond `active_cap` are passive, with the same
+    weight-preserving rescale). `entity_multiple` pads each bucket's
+    entity axis up to a multiple (the entity-mesh-axis size) so buckets
+    shard evenly.
+
+    The whole build is one ``game.design`` span whose attributes are the
+    design's own counts, and feeds the ``game.re.capped_entities`` /
+    ``game.re.passive_rows`` counters."""
+    with obs.span(
+        "game.design", cat="data", random_effect=random_effect
+    ) as sp:
+        design, counted = _build_bucketed_design(
+            data, random_effect, shard, num_entities,
+            num_buckets=num_buckets, active_cap=active_cap,
+            entity_multiple=entity_multiple, seed=seed, dtype=dtype,
+            feature_ratio=feature_ratio, min_support=min_support,
+        )
+        sp.set(
+            buckets=design.num_buckets,
+            active_slots=design.active_slots,
+            bucket_caps=[b.rows_per_entity for b in design.buckets],
+            **counted,
+        )
+    obs.registry().inc("game.re.capped_entities", counted["capped_entities"])
+    obs.registry().inc("game.re.passive_rows", counted["passive_rows"])
+    return design
+
+
+def _build_bucketed_design(
+    data, random_effect, shard, num_entities, *, num_buckets, active_cap,
+    entity_multiple, seed, dtype, feature_ratio, min_support,
+):
+    """(design, its host-side counts: entities with rows, active and
+    passive rows, entities over the cap) of
+    :func:`build_bucketed_random_effect_design`."""
     from photon_ml_tpu.ops.sparse import is_structured
 
     if is_structured(data.features[shard]):
@@ -491,11 +525,16 @@ def build_bucketed_random_effect_design(
                 np.full(entity_multiple, num_entities, np.int32)
             ],
             num_entities=num_entities,
-        )
+        ), dict(entities=0, active_rows=0, passive_rows=0, capped_entities=0)
 
-    # per-entity active cap under the bucket policy
-    by_count = np.argsort(counts, kind="stable")
-    splits = _split_minimizing_padding(counts[by_count], num_buckets)
+    # per-entity active cap under the bucket policy. The split sees what a
+    # bucket will hold: an entity over the cap pads like one AT the cap, so
+    # splitting on raw counts would spend buckets on sizes the cap removes
+    active_counts = (
+        counts if active_cap is None else np.minimum(counts, active_cap)
+    )
+    by_count = np.argsort(active_counts, kind="stable")
+    splits = _split_minimizing_padding(active_counts[by_count], num_buckets)
     splits = [by_count[lo:hi] for lo, hi in splits]
 
     cap_of_entity = np.zeros(num_entities, np.int64)
@@ -552,6 +591,11 @@ def build_bucketed_random_effect_design(
 
     return BucketedRandomEffectDesign(
         buckets=buckets, entity_index=entity_index, num_entities=num_entities
+    ), dict(
+        entities=int(uniq.size),
+        active_rows=int(rows.size),
+        passive_rows=int(order.size - rows.size),
+        capped_entities=int(np.sum(counts > active_counts)),
     )
 
 

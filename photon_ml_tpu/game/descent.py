@@ -180,7 +180,9 @@ def _pass_body(live, names, loss_fn, labels, base_offsets, weights,
         total = sum(scores.values())
         partial = total - scores[name]
         key, sub = jax.random.split(key)
-        p, tr, s = live[name].update_step(params[name], partial, sub)
+        # the coordinate's name scopes its device time in a trace
+        with jax.named_scope(name):
+            p, tr, s = live[name].update_step(params[name], partial, sub)
         params = {**params, name: p}
         scores = {**scores, name: s}
         reg = sum(reg_term(n, params[n]) for n in names)
@@ -602,9 +604,10 @@ class CoordinateDescent:
                     }
                     total = sum(scores.values())
                     partial = total - scores[name]
-                    p, tr, s = live[name].update_step(
-                        params[name], partial, key
-                    )
+                    with jax.named_scope(name):
+                        p, tr, s = live[name].update_step(
+                            params[name], partial, key
+                        )
                     params = {**params, name: p}
                     scores = {**scores, name: s}
                     reg = sum(

@@ -44,9 +44,12 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "game",
         r"game\.[a-z_]+(\.[a-z0-9_.]+)?",
         "GAME descent spans (game.cd.run > game.dispatch / game.fetch / "
-        "game.decode; game.update on the per-coordinate paths) and "
-        "counters (game.passes, game.updates, game.checkpoint.submit_ms, "
-        "...)",
+        "game.decode; game.update on the per-coordinate paths), the "
+        "bucketed design build game.design (one a random effect: "
+        "entities, buckets, bucket_caps, active_rows, active_slots, "
+        "capped_entities, passive_rows) and counters (game.passes, "
+        "game.updates, game.checkpoint.submit_ms, game.re.capped_entities, "
+        "game.re.passive_rows, ...)",
     ),
     (
         "solver",

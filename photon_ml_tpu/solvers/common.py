@@ -259,16 +259,27 @@ def check_convergence(
 
     Order matters and follows ``AbstractOptimizer.convergenceReason:49-63``:
     max-iterations, then function values, then gradient.
+
+    A ``tolerance`` of zero asks for the whole iteration budget: the two
+    relative tests would then fire only on a value that did not change in
+    its last bit (or a gradient of exactly zero), which in float32 is
+    rounding luck, so the same solve would do more or fewer passes by the
+    order of its rows (PERF.md section 6, PR 29).
     """
     reason = jnp.int32(ConvergenceReason.NOT_CONVERGED)
-    grad_conv = grad_norm_cur <= tolerance * grad_norm_initial
-    reason = jnp.where(
-        grad_conv, jnp.int32(ConvergenceReason.GRADIENT_CONVERGED), reason
-    )
-    func_conv = jnp.abs(value_prev - value_cur) <= tolerance * jnp.abs(value_initial)
-    reason = jnp.where(
-        func_conv, jnp.int32(ConvergenceReason.FUNCTION_VALUES_CONVERGED), reason
-    )
+    if tolerance > 0.0:
+        grad_conv = grad_norm_cur <= tolerance * grad_norm_initial
+        reason = jnp.where(
+            grad_conv, jnp.int32(ConvergenceReason.GRADIENT_CONVERGED), reason
+        )
+        func_conv = jnp.abs(value_prev - value_cur) <= tolerance * jnp.abs(
+            value_initial
+        )
+        reason = jnp.where(
+            func_conv,
+            jnp.int32(ConvergenceReason.FUNCTION_VALUES_CONVERGED),
+            reason,
+        )
     reason = jnp.where(
         iteration >= max_iters, jnp.int32(ConvergenceReason.MAX_ITERATIONS), reason
     )

@@ -11,10 +11,13 @@ fixed-effect coordinates (d ~ 10^1..10^3) and vmaps cleanly over the
 per-entity random-effect subproblems (d ~ 10^1).
 
 Damped for global convergence: backtracking halving on the Armijo
-condition (``SolverConfig.ls_c1`` / ``ls_max_evals``), plus a
+condition (``SolverConfig.ls_c1`` / ``ls_max_evals``, with room for the
+evaluation's own noise: ``_ARMIJO_ROUNDING_ULPS``), plus a
 Levenberg-style jitter retry when the Cholesky meets a non-PD matrix
 (possible only with l2 = 0 on degenerate data). Convergence criteria
-match ``AbstractOptimizer.scala:52-62`` exactly like the other solvers.
+match ``AbstractOptimizer.scala:52-62`` exactly like the other solvers
+(a tolerance of zero runs the whole iteration budget:
+``common.check_convergence``).
 """
 
 from __future__ import annotations
@@ -93,6 +96,23 @@ def _small_cho_solve(h: jax.Array, b: jax.Array) -> jax.Array:
     return x
 
 
+# Room the Armijo test leaves for the noise of the objective's own
+# evaluation, in units in the last place of the current value. Near a
+# solve's end, and from its first step for an entity of a few rows, the
+# decrease a Newton step promises falls under what two evaluations of the
+# objective can tell apart: the test becomes a coin, and every tail costs
+# a halving and a whole pass; vmapped over the entities of a bucket, one
+# lane's tail is the trip count of all of them. Measured on the v5e
+# (PERF.md section 6, PR 29): the same job took 5.12, 5.16 or 5.18 s by
+# the seed's row order alone; two float32 evaluations there differ by
+# 1e-5 of the value and more (with room for 32 ulps, 4e-6, 1,876 of a
+# bucket's 457,222 lanes still lost the coin in the first pass; on the
+# CPU none did). 1,024 ulps are 1.2e-4 of a float32 value and 2e-13 of a
+# float64 one; a step that overshoots misses by far more and is halved
+# as before.
+_ARMIJO_ROUNDING_ULPS = 1024.0
+
+
 def _newton_direction(h: jax.Array, grad: jax.Array) -> jax.Array:
     """Solve H p = -grad by Cholesky, retrying with a Levenberg jitter
     when H is not positive definite (all branchless: the jittered solve
@@ -169,6 +189,10 @@ def minimize_newton(
         )
         dphi0 = jnp.where(bad_dir, jnp.vdot(s.grad, direction), dphi0)
 
+        # what the objective's own sum cannot resolve is no increase: see
+        # _ARMIJO_ROUNDING_ULPS
+        slack = _ARMIJO_ROUNDING_ULPS * jnp.finfo(dtype).eps * jnp.abs(s.value)
+
         def ls_cond(c):
             alpha, _, _, k, accepted = c
             return (~accepted) & (k < config.ls_max_evals)
@@ -177,7 +201,7 @@ def minimize_newton(
             alpha, _, _, k, _ = c
             wt = s.w + alpha * direction
             vt, gt = value_and_grad_fn(wt)
-            ok = vt <= s.value + config.ls_c1 * alpha * dphi0
+            ok = vt <= s.value + config.ls_c1 * alpha * dphi0 + slack
             return (
                 jnp.where(ok, alpha, alpha * 0.5),
                 vt,
@@ -188,7 +212,7 @@ def minimize_newton(
 
         w_full = s.w + direction
         v_full, g_full = value_and_grad_fn(w_full)
-        acc0 = v_full <= s.value + config.ls_c1 * dphi0
+        acc0 = v_full <= s.value + config.ls_c1 * dphi0 + slack
         alpha, v_new, g_new, ls_evals, ls_ok = lax.while_loop(
             ls_cond,
             ls_body,

@@ -510,3 +510,85 @@ class TestTwoLoopGramForm:
             assert np.max(np.abs(r_seq - r_gram)) / scale < 1e-12, (
                 count, head,
             )
+
+
+class TestWorkDoesNotTurnOnRounding:
+    """At a float32 solve's end neither the line search nor the stopping
+    rule may read the rounding of the objective's own sum: under vmap one
+    lane's luck is every lane's trip count (PERF.md section 6, PR 29)."""
+
+    @staticmethod
+    def _float32_problem(seed, n=4096, d=8, l2=10.0):
+        rng = np.random.default_rng(seed)
+        x = jnp.asarray(rng.normal(size=(n, d)), jnp.float32)
+        y = jnp.asarray(rng.uniform(size=n) < 0.5, jnp.float32)
+
+        def vg(w):
+            z = x @ w
+            val = jnp.sum(jax.nn.softplus(z) - y * z) + 0.5 * l2 * jnp.vdot(w, w)
+            return val, x.T @ (jax.nn.sigmoid(z) - y) + l2 * w
+
+        def hess(w):
+            s = jax.nn.sigmoid(x @ w)
+            return jnp.einsum("ni,n,nj->ij", x, s * (1 - s), x) + l2 * jnp.eye(
+                d, dtype=jnp.float32
+            )
+
+        return vg, hess, d
+
+    @pytest.mark.parametrize("seed", [0, 1, 2, 3, 4, 5])
+    def test_newton_at_its_end_does_one_pass_an_iteration(self, seed):
+        from photon_ml_tpu.solvers.newton import minimize_newton
+
+        vg, hess, d = self._float32_problem(seed)
+        cfg = SolverConfig(max_iters=2, tolerance=0.0)
+        w = jnp.zeros((d,), jnp.float32)
+        for _ in range(4):  # far past what float32 can still improve
+            w = minimize_newton(vg, hess, w, cfg).w
+        res = minimize_newton(vg, hess, w, cfg)
+        assert int(res.iterations) == 2
+        assert int(res.reason) == ConvergenceReason.MAX_ITERATIONS
+        # the start's pass and one trial an iteration, none halved
+        assert int(res.evals) == 3
+        np.testing.assert_array_equal(np.asarray(res.step_tape)[-1:], 1.0)
+
+    def test_armijo_still_halves_a_step_that_overshoots(self):
+        from photon_ml_tpu.solvers.newton import minimize_newton
+
+        # f(w) = sqrt(1 + w^2) from w = 2: the Newton step -w(1 + w^2) lands
+        # at -8, a larger value by far more than any rounding
+        def vg(w):
+            r = jnp.sqrt(1.0 + jnp.vdot(w, w))
+            return r, w / r
+
+        def hess(w):
+            return jnp.eye(1, dtype=w.dtype) / (1.0 + jnp.vdot(w, w)) ** 1.5
+
+        res = minimize_newton(
+            vg, hess, jnp.asarray([2.0], jnp.float32),
+            SolverConfig(max_iters=1, tolerance=0.0),
+        )
+        assert float(res.value) < float(vg(jnp.asarray([2.0]))[0])
+        assert int(res.evals) > 2
+
+    def test_tolerance_zero_runs_the_whole_budget(self):
+        from photon_ml_tpu.solvers.newton import minimize_newton
+
+        c = jnp.asarray([1.0, -2.0, 3.0])
+        res = minimize_newton(
+            lambda w: (0.5 * jnp.vdot(w - c, w - c), w - c),
+            lambda w: jnp.eye(3),
+            jnp.zeros(3),
+            SolverConfig(max_iters=3, tolerance=0.0),
+        )
+        # solved by the first step; the value cannot change again
+        np.testing.assert_allclose(np.asarray(res.w), np.asarray(c))
+        assert int(res.iterations) == 3
+        assert int(res.reason) == ConvergenceReason.MAX_ITERATIONS
+        with_tolerance = minimize_newton(
+            lambda w: (0.5 * jnp.vdot(w - c, w - c), w - c),
+            lambda w: jnp.eye(3),
+            jnp.zeros(3),
+            SolverConfig(max_iters=3, tolerance=1e-9),
+        )
+        assert int(with_tolerance.iterations) == 1
