@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 import numpy as np
 
+from photon_ml_tpu import obs
 from photon_ml_tpu.core.tasks import TaskType
 from photon_ml_tpu.core.types import LabeledBatch
 from photon_ml_tpu.game.data import (
@@ -49,6 +50,7 @@ from photon_ml_tpu.solvers import (
     minimize_owlqn,
     minimize_tron,
 )
+from photon_ml_tpu.solvers.newton import solves_elementwise
 
 
 @dataclasses.dataclass(frozen=True)
@@ -113,6 +115,12 @@ def _make_solve_cached(config: CoordinateConfig, batched: bool):
             "needs a twice-differentiable loss (use LBFGS)"
         )
 
+    def entity_minor(dim: int) -> bool:
+        """Whether the small-d algebra of a solve at this dimension is
+        element by element (entities on the lanes under ``vmap``): both
+        the Hessian's choice and what ``game.solve_layout`` books."""
+        return use_newton and solves_elementwise(dim)
+
     def solve_one(w0, reg_weight, features, labels, offsets, weights, mask):
         l1 = reg_weight * config.l1_ratio
         l2 = reg_weight * (1.0 - config.l1_ratio)
@@ -129,11 +137,34 @@ def _make_solve_cached(config: CoordinateConfig, batched: bool):
                 vgc_fn=lambda w: obj.value_grad_curvature(w, batch),
             )
         if use_newton:
-            hess = lambda w: obj.hessian_full(w, batch)
+            # one Hessian form a dimension, batched or not, so that a
+            # bucket's lane and the same entity solved alone sum alike
+            if entity_minor(features.shape[-1]):
+                hess = lambda w: obj.hessian_row_sum(w, batch)
+            else:
+                hess = lambda w: obj.hessian_full(w, batch)
             return minimize_newton(vg, hess, w0, scfg)
         return minimize_lbfgs(vg, w0, scfg)
 
-    return jax.jit(jax.vmap(solve_one) if batched else solve_one)
+    if not batched:
+        return jax.jit(solve_one)
+
+    def solve_bucket(w0, reg_weight, features, labels, offsets, weights, mask):
+        # runs while a bucket's solve is traced, never in a pass: says
+        # which form the bucket took and how long its trace was
+        entities, depth, dim = features.shape
+        layout = "entity_minor" if entity_minor(dim) else "block_minor"
+        obs.registry().inc("game.solve_layout." + layout)
+        with obs.span(
+            "game.solve_layout", cat="solver", layout=layout,
+            optimizer=config.optimizer.name, dim=dim, depth=depth,
+            entities=entities,
+        ):
+            return jax.vmap(solve_one)(
+                w0, reg_weight, features, labels, offsets, weights, mask
+            )
+
+    return jax.jit(solve_bucket)
 
 
 def _downsample_budget(

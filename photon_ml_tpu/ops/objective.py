@@ -367,6 +367,31 @@ class GLMObjective:
             diag = diag + self.l2_weight
         return diag
 
+    def _dense_hessian(self, w, batch, contract, caller: str) -> jax.Array:
+        """X'^T diag(c) X' + l2 I with ``contract(x, c)`` as the sum over
+        the rows: what :meth:`hessian_full` and :meth:`hessian_row_sum`
+        share, which is all but the order of that sum."""
+        norm = self.normalization
+        if norm.shifts is not None:
+            raise ValueError(
+                f"{caller} supports scale-only normalization (whiten "
+                "shifts change X densely; use hessian_vector instead)"
+            )
+        x = batch.features
+        from photon_ml_tpu.ops.sparse import is_structured
+
+        if is_structured(x):
+            raise ValueError(f"{caller} requires dense features")
+        z = self.margins(w, batch)
+        c = batch.effective_weights() * self.loss.d2(z, batch.labels)
+        h = contract(x, c)
+        if norm.factors is not None:
+            h = h * jnp.outer(norm.factors, norm.factors)
+        h = _maybe_psum(h, self.axis_name)
+        if self._has_l2:
+            h = h + self.l2_weight * jnp.eye(w.shape[-1], dtype=h.dtype)
+        return h
+
     @jax.named_scope("objective_pass")
     def hessian_full(self, w: jax.Array, batch: LabeledBatch) -> jax.Array:
         """The EXPLICIT (d, d) Hessian X'^T diag(c) X' + l2 I — only
@@ -378,26 +403,31 @@ class GLMObjective:
         (n d^2 matmul FLOPs, d^2 output), enabling exact Newton steps —
         one pass replaces an entire inner CG loop. Dense features with
         scale-only (or no) normalization."""
-        norm = self.normalization
-        if norm.shifts is not None:
-            raise ValueError(
-                "hessian_full supports scale-only normalization (whiten "
-                "shifts change X densely; use hessian_vector instead)"
-            )
-        x = batch.features
-        from photon_ml_tpu.ops.sparse import is_structured
+        return self._dense_hessian(
+            w, batch, lambda x, c: jnp.einsum("ni,n,nj->ij", x, c, x),
+            "hessian_full",
+        )
 
-        if is_structured(x):
-            raise ValueError("hessian_full requires dense features")
-        z = self.margins(w, batch)
-        c = batch.effective_weights() * self.loss.d2(z, batch.labels)
-        h = jnp.einsum("ni,n,nj->ij", x, c, x)
-        if norm.factors is not None:
-            h = h * jnp.outer(norm.factors, norm.factors)
-        h = _maybe_psum(h, self.axis_name)
-        if self._has_l2:
-            h = h + self.l2_weight * jnp.eye(w.shape[-1], dtype=h.dtype)
-        return h
+    @jax.named_scope("objective_pass")
+    def hessian_row_sum(self, w: jax.Array, batch: LabeledBatch) -> jax.Array:
+        """:meth:`hessian_full` as a sum over the batch's rows of c_n x_n
+        x_n^T, by multiply and reduce in the features' own precision: for
+        the small-d Newton solve of one entity's rows.
+
+        Under ``vmap`` over entities the einsum of :meth:`hessian_full` is
+        E matmuls of (d, r) x (r, d): the TPU runs them with the block,
+        not the entities, on the lanes, after a transposing copy of the
+        design, and in a bucket of many thin entities that is most of the
+        solve (PERF.md section 6, PR 31). This form reads the design once
+        in the layout it is stored in, entities minor, and stays in
+        float32. Same restrictions as :meth:`hessian_full`."""
+
+        def contract(x, c):
+            with jax.named_scope("hessian_row_sum"):
+                xc = x * c[:, None]
+                return jnp.sum(xc[:, :, None] * x[:, None, :], axis=0)
+
+        return self._dense_hessian(w, batch, contract, "hessian_row_sum")
 
     # -- variations ------------------------------------------------------
 

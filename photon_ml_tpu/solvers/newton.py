@@ -65,35 +65,107 @@ class _NewtonState(NamedTuple):
     eval_tape: jax.Array
 
 
-# Dimension bound for the unrolled Cholesky path. Measured on the real
-# chip in r5: XLA's batched lax Cholesky on (30000, 16, 16) costs ~50 ms
-# per factor+solve — it was ~80% of every vmapped per-entity Newton
-# solve and THE random-effect throughput floor (the (E, r, d, d) Hessian
-# einsums first blamed measure ~1-4 ms once the fetch latency is
-# subtracted). The unrolled
-# static-d factorization below lowers to plain elementwise/matvec ops
-# that vmap into (E,)-wide kernels with no lax.linalg loop machinery and
-# measures ~0 ms at the same shape (6.7e-4 max rel err, f32).
+# Dimension bound for the unrolled Cholesky path. What the v5e reads
+# (ledger, PRs 29 to 31; PERF.md section 6): the first unrolled form kept
+# the factor in a (d, d) array and wrote it with ``L.at[j:, j].set`` /
+# ``y.at[i].set``, about 170 slice updates at d 16. Under ``vmap`` each
+# became an update of a whole (E, 16, 16) array, and what XLA did not do
+# in place it copied: a bucket of 641,842 two-row entities took 295 ms a
+# solve of two Newton iterations, 50 ms of it in the updates and 80 ms in
+# the copies and slices around them, and the vmapped solve was 60% of
+# ``game_fe_re.cd``'s device time and 47% of ``game_music_2re.cd``'s (PR
+# 29 lines: operations numbered ``fusion.1524`` / ``copy.34446``, 220 MB
+# of code). In the form below every entry of the factor is a value of its
+# own and nothing is updated in place: the same bucket takes 27 ms, 14 of
+# them the two Cholesky solves (PR 30's lines hold the driver's
+# measurement of the mechanism: 2.839 -> 1.253 s and 4.521 -> 2.559 s a
+# job; PR 31's are in PERF.md). What it costs is the count of operations
+# to trace and compile, d^3 / 6 multiplies and as many subtractions: 1.1 k
+# at d 16, 7 k at d 32. The bound is where the chip was read (PR 31, a
+# bucket of 65,536 entities 8 rows deep, two Newton iterations, this form
+# against lax's Cholesky, ms a solve and seconds to compile): d 16 1.2 ms /
+# 7 s against 454 ms / 2 s; d 24 4.4 ms / 29 s against 695 ms / 2 s; d 32
+# 22 ms / 80 s against 958 ms / 4 s, in 0.9 GB of scratch against 3.2 GB.
+# Batched, d 32 has paid for its compile after 81 solves of such a bucket
+# (a GAME job makes 16 to 64). A solve that is not batched gains nothing
+# at run time and pays the same compile once a shape (22 s on a CPU at d
+# 32, under 1 s at d 16); it takes this form too, so that an entity solved
+# alone and the same entity in a bucket compute the same numbers. Above
+# 32 nothing is measured. tests/test_newton_entity_minor.py holds the
+# count of operations a lowered solve has at the bound.
 _UNROLLED_CHO_MAX_DIM = 32
 
+# Columns of the factor, and entries of a substitution, between two
+# ``optimization_barrier``s. Left to itself XLA fuses an entry's whole
+# history into each of its readers, and a late entry recomputes every
+# earlier one (d 16 under vmap: 17 GB read where the matrix is 0.7 GB);
+# a barrier after every value makes each a pass over memory of its own.
+# Measured on the v5e (PERF.md section 6, PR 31): groups of four.
+_CHO_GROUP = 4
 
-def _small_cho_solve(h: jax.Array, b: jax.Array) -> jax.Array:
-    """h (d, d) SPD, b (d,) -> h^{-1} b with the Cholesky factorization
-    unrolled over the STATIC small d (column-Crout order, then forward /
-    back substitution). A non-PD h yields NaNs exactly like the lax
-    factorization, so the jitter-retry detection below is unchanged."""
-    d = h.shape[-1]
-    L = jnp.zeros_like(h)
+
+def solves_elementwise(dim: int) -> bool:
+    """Whether a Newton step at this dimension is solved by the unrolled
+    :func:`_small_cho_solve`: a coordinate's solve asks, to build its
+    Hessian in the same form, entity-minor under ``vmap``
+    (``GLMObjective.hessian_row_sum``)."""
+    return dim <= _UNROLLED_CHO_MAX_DIM
+
+
+def _settled(values: list, total: int) -> list:
+    """``values`` with its last group behind an ``optimization_barrier``
+    once the group is whole (``_CHO_GROUP`` entries, or what is left of
+    ``total``): computed once, whoever reads them."""
+    n = len(values)
+    if n % _CHO_GROUP and n != total:
+        return values
+    start = (n - 1) // _CHO_GROUP * _CHO_GROUP
+    return values[:start] + list(lax.optimization_barrier(values[start:]))
+
+
+@jax.jit
+def _small_cho_solve(h: jax.Array, b: jax.Array, shift=0.0) -> jax.Array:
+    """(H + shift I)^{-1} b for SPD H (d, d) of STATIC small d: Cholesky
+    (left-looking), then forward and back substitution, every entry of
+    the factor and of both solutions a scalar of its own.
+
+    Only multiplies, subtracts and an ``rsqrt`` a column combine them: no
+    matrix is built or updated in place, so under ``vmap`` each value is
+    an (E,) vector with the entities on the lanes. A non-PD matrix yields
+    NaNs exactly like the lax factorization (``rsqrt`` of a pivot that is
+    not positive), so the retry detection of :func:`_newton_direction` is
+    unchanged. Jitted so that its few thousand operations are traced once
+    a process and dimension, not once a bucket and caller (the plain and
+    the jittered solve are one program: ``shift`` is an argument)."""
+    d = b.shape[-1]
+    low = []  # low[j][i - j] = L[i, j], scaled by inv[j] = 1 / L[j, j]
+    inv = []
     for j in range(d):
-        col = h[j:, j] - L[j:, :j] @ L[j, :j]
-        L = L.at[j:, j].set(col / jnp.sqrt(col[0]))
-    y = jnp.zeros_like(b)
+        col = []
+        for i in range(j, d):
+            acc = h[i, j] + shift if i == j else h[i, j]
+            for k in range(j):
+                acc = lax.sub(acc, lax.mul(low[k][i - k], low[k][j - k]))
+            if i == j:
+                inv.append(lax.rsqrt(acc))
+            col.append(lax.mul(acc, inv[j]))
+        low.append(col)
+        low, inv = _settled(low, d), _settled(inv, d)
+    y = []
     for i in range(d):
-        y = y.at[i].set((b[i] - L[i, :i] @ y[:i]) / L[i, i])
-    x = jnp.zeros_like(b)
+        acc = b[i]
+        for k in range(i):
+            acc = lax.sub(acc, lax.mul(low[k][i - k], y[k]))
+        y.append(lax.mul(acc, inv[i]))
+        y = _settled(y, d)
+    x = []  # from the last entry up: x[n] is entry d - 1 - n
     for i in reversed(range(d)):
-        x = x.at[i].set((y[i] - L[i + 1 :, i] @ x[i + 1 :]) / L[i, i])
-    return x
+        acc = y[i]
+        for k in range(i + 1, d):
+            acc = lax.sub(acc, lax.mul(low[i][k - i], x[d - 1 - k]))
+        x.append(lax.mul(acc, inv[i]))
+        x = _settled(x, d)
+    return jnp.stack(x[::-1])
 
 
 # Room the Armijo test leaves for the noise of the objective's own
@@ -113,22 +185,64 @@ def _small_cho_solve(h: jax.Array, b: jax.Array) -> jax.Array:
 _ARMIJO_ROUNDING_ULPS = 1024.0
 
 
+def _levenberg_shift(h: jax.Array) -> jax.Array:
+    """What the retry adds to the diagonal of a Hessian that is not
+    positive definite: 1e-6 of one plus the diagonal's mean."""
+    d = h.shape[-1]
+    return 1e-6 * (1.0 + jnp.trace(h, axis1=-2, axis2=-1) / d)
+
+
+@jax.custom_batching.custom_vmap
+def _small_direction(h: jax.Array, grad: jax.Array) -> jax.Array:
+    """-H^{-1} grad by :func:`_small_cho_solve`, from the jittered matrix
+    where the plain factorization met one that is not positive definite.
+    The jittered solve runs only then."""
+    p = _small_cho_solve(h, -grad, jnp.zeros((), h.dtype))
+    bad = ~jnp.all(jnp.isfinite(p))
+    return lax.cond(
+        bad,
+        lambda: _small_cho_solve(h, -grad, _levenberg_shift(h)),
+        lambda: p,
+    )
+
+
+@_small_direction.def_vmap
+def _small_direction_over_entities(axis_size, in_batched, h, grad):
+    """The same over a batch of entities, which sees what one entity
+    cannot: whether ANY lane needs the retry. ``vmap`` alone turns the
+    ``cond`` into a select and every pass pays both solves; here the
+    second, as many operations again, runs only if some lane's first
+    failed (with l2 > 0 none does), and is selected lane by lane."""
+    lead = lambda x, batched: (
+        x if batched else jnp.broadcast_to(x, (axis_size,) + x.shape)
+    )
+    h, grad = lead(h, in_batched[0]), lead(grad, in_batched[1])
+    solve = jax.vmap(_small_cho_solve)
+    # a shift for every lane in both solves: one batched program
+    p = solve(h, -grad, jnp.zeros((axis_size,), h.dtype))
+    bad = ~jnp.all(jnp.isfinite(p), axis=-1)
+
+    def retry():
+        jittered = solve(h, -grad, _levenberg_shift(h))
+        return jnp.where(bad[:, None], jittered, p)
+
+    return lax.cond(jnp.any(bad), retry, lambda: p), True
+
+
+@jax.named_scope("newton_direction")
 def _newton_direction(h: jax.Array, grad: jax.Array) -> jax.Array:
     """Solve H p = -grad by Cholesky, retrying with a Levenberg jitter
-    when H is not positive definite (all branchless: the jittered solve
-    is selected where the plain factorization produced NaNs)."""
-    eye = jnp.eye(h.shape[-1], dtype=h.dtype)
-
-    def solve(mat):
-        if mat.shape[-1] <= _UNROLLED_CHO_MAX_DIM:
-            return _small_cho_solve(mat, -grad)
-        factor = jax.scipy.linalg.cho_factor(mat)
-        return jax.scipy.linalg.cho_solve(factor, -grad)
-
+    when H is not positive definite: the jittered solve is selected where
+    the plain factorization produced NaNs."""
+    d = grad.shape[-1]
+    if solves_elementwise(d):
+        return _small_direction(h, grad)
+    solve = lambda mat: jax.scipy.linalg.cho_solve(
+        jax.scipy.linalg.cho_factor(mat), -grad
+    )
     p = solve(h)
     bad = ~jnp.all(jnp.isfinite(p))
-    jitter = 1e-6 * (1.0 + jnp.trace(h) / h.shape[-1])
-    p_jittered = solve(h + jitter * eye)
+    p_jittered = solve(h + _levenberg_shift(h) * jnp.eye(d, dtype=h.dtype))
     return jnp.where(bad, p_jittered, p)
 
 

@@ -562,6 +562,50 @@ class TestEntityShardedGame:
             h_sh[-1].objective, h_local[-1].objective, rtol=1e-10
         )
 
+    @pytest.mark.parametrize("n_shards", [2, 4])
+    def test_newton_matches_unsharded(self, rng, devices, n_shards):
+        """The batched Newton solve under ``shard_map`` (its small-d
+        algebra entity-minor: ``hessian_row_sum`` and the unrolled
+        Cholesky solve) gives the unsharded coordinate's numbers, with
+        every shard's buckets booked as ``entity_minor``."""
+        from photon_ml_tpu import obs
+        from photon_ml_tpu.game import CoordinateConfig
+        from photon_ml_tpu.models.training import OptimizerType
+
+        from photon_ml_tpu.game import coordinates as coordinates_mod
+
+        # a solve traced by an earlier test would book nothing here
+        coordinates_mod._make_solve_cached.cache_clear()
+        newton = dict(optimizer=OptimizerType.NEWTON, max_iters=6)
+        fe_cfg = CoordinateConfig(**{**_FE_CFG, **newton})
+        re_cfg = CoordinateConfig(**{**_RE_CFG, **newton})
+        data, _, n_users = _mixed_effects(rng, n_users=17)
+        m_local, h_local = _build_local_cd(
+            data, n_users, fe_cfg, re_cfg
+        ).run(num_iterations=2)
+        cd, re, _, _ = _build_sharded_cd(
+            data, n_users, n_shards, fe_cfg, re_cfg
+        )
+        m_sh, h_sh = cd.run(num_iterations=2)
+        np.testing.assert_allclose(
+            np.asarray(m_sh.params["fixed"]),
+            np.asarray(m_local.params["fixed"]),
+            atol=1e-10,
+        )
+        np.testing.assert_allclose(
+            re.global_table(m_sh.params["per-user"]),
+            np.asarray(m_local.params["per-user"]),
+            atol=1e-10,
+        )
+        np.testing.assert_allclose(
+            h_sh[-1].objective, h_local[-1].objective, rtol=1e-10
+        )
+        layouts = {
+            s[6]["layout"] for s in obs.recent_spans()
+            if s[0] == "game.solve_layout"
+        }
+        assert layouts == {"entity_minor"}
+
     def test_zero_collectives_in_re_update(self, rng, devices):
         from photon_ml_tpu.game import CoordinateConfig
 
