@@ -1605,96 +1605,6 @@ def bench_sparse_feature_scaling():
     return result
 
 
-def bench_sparse_kernel_passes():
-    """Per-kernel ELL pass microbench: xla vs pallas for the three
-    contractions plus the fused objective pass, median ms over chained
-    repeats (``sparse_pass_ms.*`` in the record; sentinel-tracked so a
-    kernel regression fails ``--sentinel``). On TPU the pallas column is
-    the hand-written Mosaic kernel; on CPU it is INTERPRET mode — a
-    semantics/regression probe, not a perf claim, so the shape stays
-    small enough that the interpreter finishes in seconds."""
-    import jax
-    import jax.numpy as jnp
-
-    from photon_ml_tpu.core.types import LabeledBatch
-    from photon_ml_tpu.kernels import dispatch as kdispatch
-    from photon_ml_tpu.ops.losses import LOGISTIC_LOSS
-    from photon_ml_tpu.ops.objective import GLMObjective
-    from photon_ml_tpu.ops.sparse import (
-        SparseFeatures,
-        colsum,
-        matvec,
-        rmatvec,
-    )
-
-    on_tpu = jax.default_backend() == "tpu"
-    # TPU: bench-scale shape (the BENCH_r05 sparse config's row block
-    # regime); CPU interpret mode: small enough to stay in seconds
-    n, k, d = (200_000, 32, 120_000) if on_tpu else (4_096, 16, 2_048)
-    reps = 5
-    rng = np.random.default_rng(17)
-    sf = SparseFeatures(
-        indices=jnp.asarray(
-            rng.integers(0, d, size=(n, k)).astype(np.int32)
-        ),
-        values=jnp.asarray(rng.standard_normal((n, k)).astype(np.float32)),
-        d=d,
-    )
-    w = jnp.asarray(rng.standard_normal(d).astype(np.float32))
-    a = jnp.asarray(rng.standard_normal(n).astype(np.float32))
-    y = (rng.uniform(size=n) < 0.5).astype(np.float32)
-    batch = LabeledBatch.create(sf, y, dtype=jnp.float32)
-    obj = GLMObjective(loss=LOGISTIC_LOSS, l2_weight=1.0)
-
-    passes = {
-        "matvec": lambda: matvec(sf, w),
-        "rmatvec": lambda: rmatvec(sf, a),
-        "colsum": lambda: colsum(sf, a, square=True),
-        "fused": lambda: obj.value_grad_curvature(w, batch),
-    }
-
-    def _block(out):
-        jax.tree_util.tree_map(
-            lambda x: x.block_until_ready(), out
-        )
-
-    out = {name: {} for name in passes}
-    old = os.environ.get(kdispatch.ENV_VAR)
-    try:
-        # the Pallas suite does not lower for TPU on the installed
-        # toolchain (kernels/dispatch.py): forcing it there raises by
-        # design, so only --cpu runs time it (in the interpreter)
-        for mode in ("xla",) if on_tpu else ("xla", "pallas"):
-            os.environ[kdispatch.ENV_VAR] = mode
-            for name, thunk in passes.items():
-                # fresh closure per (op, mode): dispatch is trace-time,
-                # so a cached jit from the other mode must not be reused
-                fn = jax.jit(lambda t=thunk: t())
-                _block(fn())  # compile + warm
-                times = []
-                for _ in range(reps):
-                    t0 = time.perf_counter()
-                    _block(fn())
-                    times.append(time.perf_counter() - t0)
-                times.sort()
-                out[name][f"{mode}_ms"] = round(
-                    times[len(times) // 2] * 1e3, 3
-                )
-            log(
-                f"sparse kernel passes [{mode}] "
-                + " ".join(
-                    f"{nm}={out[nm][f'{mode}_ms']}ms" for nm in passes
-                )
-            )
-    finally:
-        if old is None:
-            os.environ.pop(kdispatch.ENV_VAR, None)
-        else:
-            os.environ[kdispatch.ENV_VAR] = old
-    out["shape"] = {"n": n, "k": k, "d": d}
-    return out
-
-
 def bench_ingest():
     """Avro ingest throughput: native C++ decoder vs the Python codec on
     the same file (records/s, decode + vocab join to COO triplets)."""
@@ -2557,9 +2467,6 @@ def main():
     game_wide = _phase("game_wide_sparse", bench_game_wide_sparse)
     linear_en = _phase("linear_elastic_net", bench_linear_elastic_net)
     sparse = _phase("sparse", bench_sparse)
-    sparse_kernels = _phase(
-        "sparse_kernel_passes", bench_sparse_kernel_passes
-    )
     sparse_scaling = _phase("sparse_scaling_cpu", _sparse_scaling_cpu)
     ingest = _phase("ingest", bench_ingest)
     ingest_pipe = _phase("ingest_pipeline", bench_ingest_pipeline)
@@ -2611,9 +2518,6 @@ def main():
                 3,
             ),
         },
-        # per-kernel pass microbench, xla vs pallas (sentinel-tracked:
-        # *_ms keys are lower-is-better; pallas on CPU = interpret mode)
-        "sparse_pass_ms": sparse_kernels,
         "sparse_zipf_hybrid_s": round(sparse["hybrid_s"], 3),
         "sparse_zipf_hybrid_vs_ell": round(
             sparse["zipf_ell_s"] / sparse["hybrid_s"], 3

@@ -21,8 +21,8 @@ the driver reads, exactly
 with the device as jax reports it. The line before it is the summary
 (also written to ``chiprun_out/chip_smoke/summary.json``): the same two
 keys, then versions, per-phase seconds / compile requests / persistent-
-cache hits, the cache directory, which sparse lowering ran, the native
-codec's state, the sizes used, every measured agreement, and
+cache hits, the cache directory, the native codec's state, the sizes
+used, every measured agreement, and
 ``"claim": null`` — this script observes; it claims no speed.
 
 With >= 4 devices the sharded paths run too (entity-sharded GAME,
@@ -666,7 +666,6 @@ def check_sparse_gradient(fixture, glm, lam):
     import scipy.sparse as sp
 
     from photon_ml_tpu.io.ingest import IngestSource
-    from photon_ml_tpu.obs.xla_cost import cost_book
     from photon_ml_tpu.ops.losses import LOGISTIC_LOSS
     from photon_ml_tpu.ops.objective import GLMObjective
 
@@ -699,9 +698,7 @@ def check_sparse_gradient(fixture, glm, lam):
         f"|g_device - g_csr| / |g_csr| = {rel:.2e} at lambda={lam:g}, "
         f"|g| {np.linalg.norm(ref):.3g} (tol {SPARSE_GRAD_RTOL})",
     )
-    pallas_traced = [k for k, _ in cost_book().names() if
-                     k.startswith("kernels.")]
-    return rel, pallas_traced
+    return rel
 
 
 def run_streamed_dense_glm(game_fx, sizes):
@@ -851,7 +848,6 @@ def main(argv=None) -> int:
     import jaxlib
 
     from photon_ml_tpu import obs
-    from photon_ml_tpu.kernels import dispatch
     from photon_ml_tpu.obs.xla_cost import require_device_peaks
     from photon_ml_tpu.utils import enable_compilation_cache
     from photon_ml_tpu.utils.compile_cache import CACHE_DIR_ENV
@@ -927,19 +923,7 @@ def main(argv=None) -> int:
 
         with run.phase("4_sparse_glm"):
             glm = run_sparse_glm(sparse_fx, "one")
-            grad_rel, pallas_traced = check_sparse_gradient(
-                sparse_fx, glm, 1.0
-            )
-            rule_pallas = dispatch.use_pallas(
-                d=sizes["sparse_d"], n=sizes["sparse_rows"],
-                nnz_per_row=sizes["sparse_nnz"],
-            )
-            check(
-                "glm.lowering_is_the_rule's",
-                bool(pallas_traced) == rule_pallas,
-                f"rule selects {'pallas' if rule_pallas else 'xla'}; "
-                f"Pallas kernels traced: {pallas_traced or 'none'}",
-            )
+            grad_rel = check_sparse_gradient(sparse_fx, glm, 1.0)
 
         with run.phase("4b_glm_streamed_dense"):
             streamed_rel = run_streamed_dense_glm(game_fx, sizes)
@@ -991,8 +975,6 @@ def main(argv=None) -> int:
         "cache_dir": cache_dir,
         "cache_dir_from_env": bool(os.environ.get(CACHE_DIR_ENV)),
         "jax_compilation_cache_dir": jax.config.jax_compilation_cache_dir,
-        "sparse_kernel_mode": dispatch.kernel_mode(),
-        "sparse_lowering": "pallas" if rule_pallas else "xla",
         "native_available": True,
         "native_library": os.path.relpath(native_so, HERE),
         "sizes": sizes_used,

@@ -34,6 +34,7 @@ import numpy as np
 
 from photon_ml_tpu import obs
 from photon_ml_tpu.core.types import LabeledBatch, _pytree_dataclass
+from photon_ml_tpu.ops.bucketing import split_minimizing_padding
 
 
 @dataclasses.dataclass
@@ -397,52 +398,6 @@ class BucketedRandomEffectDesign:
         return sum(b.num_entities * b.rows_per_entity for b in self.buckets)
 
 
-def _split_minimizing_padding(sorted_counts: np.ndarray, max_buckets: int):
-    """Optimal contiguous split of ascending per-entity row counts into at
-    most `max_buckets` groups minimizing total padded slots
-    Σ_b |entities_b| · max_count_b (exact DP over distinct counts — the
-    number of distinct entity sizes is small even when entities number
-    millions). Returns [(lo, hi)) index ranges into sorted_counts."""
-    if sorted_counts.size == 0:
-        return []
-    values, nums = np.unique(sorted_counts, return_counts=True)
-    return _split_histogram_minimizing_padding(values, nums, max_buckets)
-
-
-def _split_histogram_minimizing_padding(
-    values: np.ndarray, nums: np.ndarray, max_buckets: int
-):
-    """``_split_minimizing_padding`` from the counts' histogram: ascending
-    distinct ``values``, each held by ``nums`` entries (all above zero)."""
-    m = values.size
-    k = min(max_buckets, m)
-    prefix = np.concatenate([[0], np.cumsum(nums)])
-    INF = float("inf")
-    # dp[j] = min cost covering distinct values [0, j) ; rebuilt per layer
-    dp = np.full(m + 1, INF)
-    dp[0] = 0.0
-    choice = np.zeros((k, m + 1), np.int64)
-    for layer in range(k):
-        nxt = np.full(m + 1, INF)
-        for j in range(1, m + 1):
-            # bucket = distinct values [i, j) with cap values[j-1]
-            costs = dp[:j] + (prefix[j] - prefix[:j]) * values[j - 1]
-            i = int(np.argmin(costs))
-            nxt[j] = costs[i]
-            choice[layer, j] = i
-        dp = nxt
-    # backtrack
-    bounds = []
-    j = m
-    layer = k - 1
-    while j > 0:
-        i = int(choice[layer, j])
-        bounds.append((int(prefix[i]), int(prefix[j])))
-        j = i
-        layer -= 1
-    return bounds[::-1]
-
-
 def build_bucketed_random_effect_design(
     data: GameData,
     random_effect: str,
@@ -534,7 +489,7 @@ def _build_bucketed_design(
         counts if active_cap is None else np.minimum(counts, active_cap)
     )
     by_count = np.argsort(active_counts, kind="stable")
-    splits = _split_minimizing_padding(active_counts[by_count], num_buckets)
+    splits = split_minimizing_padding(active_counts[by_count], num_buckets)
     splits = [by_count[lo:hi] for lo, hi in splits]
 
     cap_of_entity = np.zeros(num_entities, np.int64)

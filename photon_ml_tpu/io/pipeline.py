@@ -1223,10 +1223,8 @@ def _streaming_passes(loss, dtype_str: str):
     """jitted per-chunk partial passes + the donated-carry accumulator.
     One compilation per (loss, dtype) x chunk shape — the l2/l1 terms
     stay OUTSIDE (pure functions of w, added once per sweep), so every
-    lambda of a regularization path shares these executables. On
-    Pallas-eligible designs the passes route through the PR-5 fused
-    kernels exactly like the in-core objective (same GLMObjective
-    methods)."""
+    lambda of a regularization path shares these executables (the
+    in-core objective's own GLMObjective methods)."""
     import jax
     import jax.numpy as jnp
 

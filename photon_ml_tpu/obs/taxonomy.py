@@ -149,11 +149,6 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "barrier-backed clock-sync anchors for trace-shard merging",
     ),
     (
-        "kernels",
-        r"kernels\.[a-z_]+(\..+)?",
-        "Pallas sparse-kernel cost records (docs/KERNELS.md)",
-    ),
-    (
         "sparse",
         r"sparse\.split\.(engaged|skipped(\.[a-z_]+)?)",
         "the hot/cold split rule of a padded-ELL design, one a train_glm "

@@ -46,7 +46,6 @@ from photon_ml_tpu.ops.losses import PointwiseLoss
 from photon_ml_tpu.ops.sparse import (
     colsum,
     is_feature_sharded,
-    is_sparse,
     matvec,
     matvec_and_feature_dots,
     rmatvec,
@@ -190,11 +189,7 @@ class GLMObjective:
         Collectives: the value/grad partials reduce in ONE tuple psum
         (one collective per pass, not two); on feature-sharded designs
         the L2 value dot and margin shift additionally ride the margins
-        block-sum (``matvec_and_feature_dots``). On Pallas-eligible ELL
-        designs the whole pass is the single-design-read fused kernel
-        (``kernels.fused_value_grad_curvature``)."""
-        if self._use_fused_kernel(batch.features, w.dtype):
-            return self._value_grad_curvature_fused(w, batch)
+        block-sum (``matvec_and_feature_dots``)."""
         norm = self.normalization
         wdot = None
         if (
@@ -230,55 +225,6 @@ class GLMObjective:
             grad = grad + self.l2_weight * w
         return val, grad, c
 
-    # -- fused Pallas passes (one design read per pass) ------------------
-
-    def _use_fused_kernel(self, feats, w_dtype) -> bool:
-        """Take the single-read fused Pallas pass? Plain ELL designs
-        only (the hybrid/blocked containers keep their per-segment
-        dispatch through matvec/rmatvec/colsum), under the same
-        mode/VMEM eligibility as the per-op kernels."""
-        from photon_ml_tpu.ops.sparse import _use_pallas_for
-
-        return is_sparse(feats) and _use_pallas_for(feats, w_dtype)
-
-    def _fused_inputs(self, w: jax.Array, batch: LabeledBatch):
-        """(effective coefficients, shift-folded offsets, weights) for
-        the fused kernels: the margin shift is a scalar, so it folds
-        into the per-row offsets outside the kernel."""
-        norm = self.normalization
-        eff = norm.effective_coefficients(w)
-        off = batch.offsets + norm.margin_shift(w)
-        return eff, off, batch.effective_weights()
-
-    def _correct_backprojection(self, g, total_a):
-        """The normalization algebra of :meth:`_backproject` applied to
-        a raw X^T a from a fused kernel, with sum(a) already reduced
-        in-kernel."""
-        norm = self.normalization
-        if norm.factors is not None:
-            g = g * norm.factors
-        if norm.shifts is not None:
-            shift_eff = norm.shifts * (
-                norm.factors if norm.factors is not None else 1.0
-            )
-            g = g - shift_eff * total_a
-        return g
-
-    def _value_grad_curvature_fused(self, w: jax.Array, batch: LabeledBatch):
-        from photon_ml_tpu import kernels
-
-        x = batch.features
-        eff, off, ew = self._fused_inputs(w, batch)
-        val, g, asum, c = kernels.fused_value_grad_curvature(
-            x.indices, x.values, batch.labels, off, ew, eff, x.d, self.loss
-        )
-        grad = self._correct_backprojection(g, asum)
-        val, grad = _maybe_psum((val, grad), self.axis_name)
-        if self._has_l2:
-            val = val + 0.5 * self.l2_weight * jnp.vdot(w, w)
-            grad = grad + self.l2_weight * w
-        return val, grad, c
-
     # -- second-order ----------------------------------------------------
 
     def hessian_vector(
@@ -308,23 +254,9 @@ class GLMObjective:
     ) -> jax.Array:
         """H @ v with the curvature weights ``c`` precomputed by
         :meth:`hessian_coefficients`. TRON's inner CG loop is almost
-        entirely this call, so on Pallas-eligible ELL designs it takes
-        the fused single-read sweep (``kernels.fused_hessian_vector``:
-        the v-margins gather and the back-projection scatter share one
-        walk of the stored design)."""
-        if self._use_fused_kernel(batch.features, v.dtype):
-            from photon_ml_tpu import kernels
-
-            norm = self.normalization
-            x = batch.features
-            eff_v = norm.effective_coefficients(v)
-            hv0, usum = kernels.fused_hessian_vector(
-                x.indices, x.values, c, eff_v, norm.margin_shift(v), x.d
-            )
-            hv = self._correct_backprojection(hv0, usum)
-        else:
-            zv = self._dmargin_dot(v, batch)
-            hv = self._backproject(c * zv, batch)
+        entirely this call."""
+        zv = self._dmargin_dot(v, batch)
+        hv = self._backproject(c * zv, batch)
         hv = _maybe_psum(hv, self.axis_name)
         if self._has_l2:
             hv = hv + self.l2_weight * v
@@ -337,29 +269,15 @@ class GLMObjective:
         ``OptimizationProblem.updateCoefficientsVariances``)."""
         norm = self.normalization
         x = batch.features
-        if self._use_fused_kernel(x, w.dtype):
-            from photon_ml_tpu import kernels
-
-            eff, off, ew = self._fused_inputs(w, batch)
-            d_x2, d_x, csum = kernels.fused_hessian_diagonal(
-                x.indices, x.values, batch.labels, off, ew, eff, x.d,
-                self.loss,
-            )
-            if norm.shifts is not None:
-                s = norm.shifts
-                diag = d_x2 - 2.0 * s * d_x + s * s * csum
-            else:
-                diag = d_x2
+        z = self.margins(w, batch)
+        c = batch.effective_weights() * self.loss.d2(z, batch.labels)
+        d_x2 = colsum(x, c, square=True)
+        if norm.shifts is not None:
+            d_x = colsum(x, c)
+            s = norm.shifts
+            diag = d_x2 - 2.0 * s * d_x + s * s * jnp.sum(c)
         else:
-            z = self.margins(w, batch)
-            c = batch.effective_weights() * self.loss.d2(z, batch.labels)
-            d_x2 = colsum(x, c, square=True)
-            if norm.shifts is not None:
-                d_x = colsum(x, c)
-                s = norm.shifts
-                diag = d_x2 - 2.0 * s * d_x + s * s * jnp.sum(c)
-            else:
-                diag = d_x2
+            diag = d_x2
         if norm.factors is not None:
             diag = diag * norm.factors**2
         diag = _maybe_psum(diag, self.axis_name)

@@ -19,17 +19,14 @@ All are single XLA ops (gather / scatter-add) that shard cleanly over the
 'data' mesh axis: indices/values are row-leading, so batch sharding and the
 psum-reduced partials work exactly as for dense features.
 
-The three ELL contractions DISPATCH between that XLA lowering and the
-hand-written Pallas suite in ``photon_ml_tpu/kernels/`` (VMEM-resident
-table/accumulator, streamed row blocks — docs/KERNELS.md) per
-``PHOTON_SPARSE_KERNEL={auto,pallas,xla}``: ``auto`` and ``xla`` take the
-XLA lowering on every platform (the suite does not lower for TPU on the
-installed toolchain — kernels/dispatch.py); ``pallas`` forces the suite
-(interpret mode on CPU — the tier-1 proof; the compiler's own error on
-TPU). Dispatch happens here so every consumer — ``GLMObjective``, GAME
-random-effect batches, serving scorers — switches with zero call-site
-changes. The hybrid container's cold segments do not switch: all of them
-are contracted by one flat XLA gather / scatter-add (``_cold_matvec``).
+Each contraction has ONE implementation per container, chosen by the
+container's type and nothing else: plain ELL (``SparseFeatures``) is the
+XLA gather / scatter-add above; the hybrid container adds a dense slab
+product for its hot columns and contracts all of its cold segments with
+one flat gather / scatter-add (``_cold_matvec``); the feature-sharded
+container scatters per block. Every consumer (``GLMObjective``, GAME
+random-effect batches, serving scorers) calls ``matvec`` / ``rmatvec`` /
+``colsum`` and never names a lowering.
 """
 
 from __future__ import annotations
@@ -43,7 +40,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from photon_ml_tpu.kernels import dispatch as _kdispatch
+from photon_ml_tpu.ops.bucketing import (
+    split_histogram_minimizing_padding,
+    split_minimizing_padding,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -300,18 +300,6 @@ jax.tree_util.register_pytree_node(
 # -- kernels (dispatch on representation) -----------------------------------
 
 
-def _use_pallas_for(sf: "SparseFeatures", other_dtype) -> bool:
-    """Route this ELL contraction to the Pallas suite? Centralizes the
-    eligibility call so matvec/rmatvec/colsum cannot drift: mode knob,
-    VMEM budget at the contraction's COMPUTE dtype, and
-    degenerate/sharded-batch exclusions (kernels.dispatch)."""
-    n, k = sf.indices.shape[-2], sf.indices.shape[-1]
-    cd = jnp.result_type(sf.values.dtype, other_dtype)
-    return _kdispatch.use_pallas(
-        d=sf.d, itemsize=jnp.dtype(cd).itemsize, n=n, nnz_per_row=k
-    )
-
-
 def is_sparse(x) -> bool:
     return isinstance(x, SparseFeatures)
 
@@ -445,8 +433,7 @@ def _cold_matvec(x: "HybridFeatures", w: jax.Array) -> jax.Array:
     (and in ``_cold_scatter`` one scatter-add with its one sort) a pass,
     however many row buckets: XLA compiles each such op for seconds, so a
     gather and a scatter a segment made the solve program compile for
-    minutes (PERF.md section 6, PR 27). Always XLA's lowering: the Pallas
-    suite's kernels take one ELL at a time, and do not lower on the TPU."""
+    minutes (PERF.md section 6, PR 27)."""
     segs = x.cold_segments
     with jax.named_scope("sparse_gather"):
         gathered = w.at[_cold_flat_indices(segs)].get(
@@ -502,10 +489,6 @@ def matvec(x, w: jax.Array) -> jax.Array:
     if not is_sparse(x):
         return _low_precision_dot(x, w)
     with jax.named_scope("sparse_gather"):
-        if _use_pallas_for(x, w.dtype):
-            from photon_ml_tpu import kernels
-
-            return kernels.ell_matvec(x.indices, x.values, w, x.d)
         gathered = w.at[x.indices].get(mode="fill", fill_value=0.0)
         return jnp.sum(x.values * gathered, axis=-1)
 
@@ -522,10 +505,6 @@ def rmatvec(x, a: jax.Array) -> jax.Array:
     if not is_sparse(x):
         return _low_precision_dot(x.T, a)
     with jax.named_scope("sparse_scatter"):
-        if _use_pallas_for(x, a.dtype):
-            from photon_ml_tpu import kernels
-
-            return kernels.ell_rmatvec(x.indices, x.values, a, x.d)
         upd = (x.values * a[..., None]).reshape(-1)
         return (
             jnp.zeros((x.d,), upd.dtype)
@@ -583,12 +562,6 @@ def colsum(x, c: jax.Array, square: bool = False) -> jax.Array:
         v = x * x if square else x
         return jnp.einsum("n,nd->d", c, v)
     with jax.named_scope("sparse_scatter"):
-        if _use_pallas_for(x, c.dtype):
-            from photon_ml_tpu import kernels
-
-            return kernels.ell_colsum(
-                x.indices, x.values, c, x.d, square=square
-            )
         v = x.values * x.values if square else x.values
         upd = (v * c[..., None]).reshape(-1)
         return (
@@ -997,8 +970,6 @@ def to_hybrid(
     where the caller will ask for squared sums (``exact_squares``) the
     later copies stay in the cold segments, so no slab cell is a sum.
     """
-    from photon_ml_tpu.game.data import _split_minimizing_padding
-
     out_dtype = np.dtype(jnp.dtype(dtype or sf.values.dtype))
     ind = np.asarray(sf.indices)
     val = np.asarray(sf.values)
@@ -1037,7 +1008,7 @@ def to_hybrid(
     cold_counts = cold_entry.sum(axis=1)
     row_perm = np.argsort(cold_counts, kind="stable").astype(np.int32)
     sorted_counts = cold_counts[row_perm]
-    bounds = _split_minimizing_padding(
+    bounds = split_minimizing_padding(
         sorted_counts, max(1, num_row_buckets)
     ) or [(0, n)]
 
@@ -1268,8 +1239,6 @@ def split_on_device(
     cold counts and the stored slots. Compiled device code and one fetch
     of ``k + 2`` integers; the static shapes of the cold segments come
     from that fetch."""
-    from photon_ml_tpu.game.data import _split_histogram_minimizing_padding
-
     n, k = sf.indices.shape
     dense, hot_ids, cold_idx, cold_val, row_perm, counts = _split_rows(
         sf.indices, sf.values, top_ids,
@@ -1279,7 +1248,7 @@ def split_on_device(
     counts = np.asarray(counts)
     histogram, stored = counts[:-1], int(counts[-1])
     held = np.flatnonzero(histogram)
-    bounds = _split_histogram_minimizing_padding(
+    bounds = split_histogram_minimizing_padding(
         held, histogram[held], max(1, num_row_buckets)
     ) or [(0, n)]
     ends = np.cumsum(histogram)

@@ -284,10 +284,7 @@ def hierarchical_value_and_grad(objective: GLMObjective, mesh: Mesh):
         check_vma=False,
     )
     def vg(w, batch: LabeledBatch):
-        from photon_ml_tpu.kernels import dispatch as _kdispatch
-
-        with _kdispatch.shard_local():
-            val, grad = obj0.value_and_grad(w, batch)
+        val, grad = obj0.value_and_grad(w, batch)
         val, grad = hierarchical_psum(
             (val, grad), intra_axis=DEVICE_AXIS, inter_axis=HOST_AXIS
         )
@@ -343,14 +340,7 @@ def shard_map_value_and_grad(
         out_specs=(P(), P()),
     )
     def vg_raw(w, batch: LabeledBatch):
-        # shard-local by construction: per-shard rows with replicated w,
-        # partials psum-reduced — so the Pallas ELL suite stays eligible
-        # under this >1-device mesh (kernels.dispatch.shard_local; the
-        # GSPMD jit path keeps the XLA fallback + one-shot signal)
-        from photon_ml_tpu.kernels import dispatch as _kdispatch
-
-        with _kdispatch.shard_local():
-            return obj.value_and_grad(w, batch)
+        return obj.value_and_grad(w, batch)
 
     def vg(w, batch: LabeledBatch):
         if not _eager_and_traced(w, batch):

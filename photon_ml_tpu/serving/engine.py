@@ -347,16 +347,6 @@ class ScoringEngine:
         # AOT bucket ladder instead of N
         self._shared_cache = compile_cache
         self.shared_compile_hits = 0
-        # which ELL backend this engine's executables traced with
-        # (PHOTON_SPARSE_KERNEL dispatch in ops.sparse) — pinned at
-        # construction so score spans attribute kernel provenance even
-        # if the env var changes under a running server
-        try:
-            from photon_ml_tpu.kernels import kernel_mode
-
-            self._sparse_kernel = kernel_mode()
-        except Exception:
-            self._sparse_kernel = "unknown"
 
     # -- construction hooks (overridden by the entity-sharded engine) ------
 
@@ -698,7 +688,6 @@ class ScoringEngine:
         return (
             type(self).__name__,
             self._placement_fingerprint(),
-            self._sparse_kernel,
             tuple(self._coord_order),
             tuple(sorted(self.shards.items())),
             tuple(sorted(self.random_effects.items())),
@@ -881,10 +870,7 @@ class ScoringEngine:
         scores are on the host). Inside the micro-batcher every one of
         them inherits ``batch_id`` from the ambient span context."""
         with obs.span(
-            "serving.score",
-            cat="serving",
-            fixed_only=fixed_only,
-            sparse_kernel=self._sparse_kernel,
+            "serving.score", cat="serving", fixed_only=fixed_only
         ) as sp:
             with obs.span("serving.featurize", cat="serving") as fsp:
                 if requests is not None:

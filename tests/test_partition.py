@@ -11,8 +11,7 @@
 - entity-sharded GAME descent == single-device descent <= 1e-10 across
   widths 2/4/8, incl. a shard-count-not-dividing-entity-count remainder
   case and resume-from-sharded-checkpoint at a DIFFERENT width, with a
-  zero-collective assertion on the compiled random-effect update;
-- the kernels.dispatch multidevice-fallback signal + shard_local lift.
+  zero-collective assertion on the compiled random-effect update.
 
 All drills run on the 8-virtual-CPU-device tier-1 pod
 (``jax_num_cpu_devices`` via conftest).
@@ -721,38 +720,6 @@ class TestEntityShardedGame:
         )
 
 
-class TestDispatchFallbackSignal:
-    def test_multidevice_fallback_counted_and_lifted(
-        self, devices, monkeypatch
-    ):
-        from photon_ml_tpu import obs
-        from photon_ml_tpu.kernels import dispatch as kd
-
-        # the signal belongs to FORCED Pallas: `auto` never selects the
-        # suite, so there is nothing to fall back from
-        monkeypatch.setenv(kd.ENV_VAR, "pallas")
-        mesh = make_mesh()
-        before = obs.registry().counter(
-            "kernels.dispatch.multidevice_fallback"
-        ).value
-        with jax.set_mesh(mesh):
-            assert kd.active_mesh_devices() == 8
-            assert not kd.use_pallas(d=64, itemsize=8, n=4, nnz_per_row=2)
-            after = obs.registry().counter(
-                "kernels.dispatch.multidevice_fallback"
-            ).value
-            assert after == before + 1
-            # shard-local extents (explicit shard_map paths) lift the
-            # exclusion
-            with kd.shard_local():
-                assert kd.in_shard_local()
-                assert kd.use_pallas(
-                    d=64, itemsize=8, n=4, nnz_per_row=2
-                )
-            assert not kd.in_shard_local()
-        assert kd.active_mesh_devices() == 1
-
-
 class TestSentinelAndTaxonomy:
     def test_raised_scaling_floors(self):
         from photon_ml_tpu.obs.sentinel import metric_floor
@@ -793,9 +760,6 @@ class TestSentinelAndTaxonomy:
         assert taxonomy.matches("partition.entity_layout")
         assert taxonomy.matches(
             "collective.overlap.objective_pass.w8.wall_frac"
-        )
-        assert taxonomy.matches(
-            "kernels.dispatch.multidevice_fallback"
         )
 
     def test_collective_share_gauge(self):

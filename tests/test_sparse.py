@@ -4,11 +4,14 @@ dense oracle on the support, and the d >= 100k regime must work without ever
 materializing an (n, d) matrix (the reference's PalDB >200k-feature regime,
 ``util/PalDBIndexMap.scala:43``)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from photon_ml_tpu.core.normalization import NormalizationContext
 from photon_ml_tpu.core.types import LabeledBatch
 from photon_ml_tpu.ops.losses import LOGISTIC_LOSS
 from photon_ml_tpu.ops.objective import GLMObjective
@@ -17,8 +20,10 @@ from photon_ml_tpu.ops.sparse import (
     from_coo,
     from_dense,
     matvec,
+    matvec_and_feature_dots,
     rmatvec,
     colsum,
+    shard_columns,
     to_dense,
 )
 
@@ -28,6 +33,55 @@ def random_sparse(rng, n, d, nnz):
     cols = rng.integers(0, d, size=n * nnz)
     vals = rng.normal(size=n * nnz)
     return rows, cols, vals
+
+
+def random_ell(rng, n, k, d, dtype=np.float32, pad_rows=0, dup_row=False):
+    """Random ELL with the padding invariant (padding slots: id=d,
+    value=0). ``pad_rows`` leading rows are ALL padding; ``dup_row``
+    plants duplicate column ids inside row 0's slots."""
+    idx = rng.integers(0, max(d, 1), size=(n, k)).astype(np.int32)
+    val = rng.standard_normal((n, k)).astype(dtype)
+    if dup_row and n > 0 and k >= 2:
+        idx[0, :] = idx[0, 0]  # every slot of row 0 hits one column
+    if pad_rows:
+        idx[:pad_rows, :] = d
+        val[:pad_rows, :] = 0
+    return SparseFeatures(
+        indices=jnp.asarray(idx), values=jnp.asarray(val), d=d
+    )
+
+
+def _dense_of_slots(sf, square=False):
+    """(n, d) float64 matrix of the stored slots, padding dropped. A pair
+    stored twice sums; with ``square`` each SLOT is squared first, which
+    is what ``colsum(square=True)`` sums."""
+    v = sf.values.astype(jnp.float64)
+    return to_dense(SparseFeatures(sf.indices, v * v if square else v, sf.d))
+
+
+def _ell_case(id, n, k, d, pad=0, dup=False, values="float64",
+              vectors="float64", build="ell"):
+    """``pad`` leading rows of padding only; ``dup``: row 0 holds one
+    column in every slot; dtypes of the stored values and of the vectors."""
+    return pytest.param(build, n, k, d, pad, dup, values, vectors, id=id)
+
+
+ELL_CASES = [
+    _ell_case("coo", 64, 7, 50, build="coo"),
+    # d = 300 / 157 are no multiple of the 128-lane tile
+    _ell_case("ragged-d", 37, 5, 300),
+    _ell_case("leading-padding-rows", 37, 5, 300, pad=7),
+    _ell_case("every-row-padding", 16, 4, 300, pad=16),
+    _ell_case("one-slot-a-row", 23, 1, 157),
+    _ell_case("duplicate-columns-in-a-row", 12, 6, 157, dup=True),
+    _ell_case("d-1", 9, 3, 1),
+    _ell_case("d-one-lane-tile", 40, 8, 128),
+    _ell_case("bf16-values-f32-vectors", 33, 4, 270, values="bfloat16",
+              vectors="float32"),
+    _ell_case("bf16-values-bf16-vectors", 33, 4, 270, values="bfloat16",
+              vectors="bfloat16"),
+    _ell_case("empty-batch", 0, 4, 90, values="float32", vectors="float32"),
+]
 
 
 class TestKernels:
@@ -44,28 +98,48 @@ class TestKernels:
         expect[1, 0] = 5.0
         np.testing.assert_array_equal(dense, expect)
 
-    def test_matvec_rmatvec_colsum_match_dense(self, rng):
-        n, d, nnz = 64, 50, 7
-        sf = from_coo(*random_sparse(rng, n, d, nnz), n, d, dtype=jnp.float64)
-        x = to_dense(sf)
-        w = rng.normal(size=d)
-        a = rng.normal(size=n)
-        np.testing.assert_allclose(
-            np.asarray(matvec(sf, jnp.asarray(w))), x @ w, rtol=1e-12
+    @pytest.mark.parametrize(
+        "build,n,k,d,pad,dup,values_dtype,vector_dtype", ELL_CASES
+    )
+    def test_matvec_rmatvec_colsum_match_dense(
+        self, rng, build, n, k, d, pad, dup, values_dtype, vector_dtype
+    ):
+        """The three contractions (and the squared column sums) of a
+        padded ELL against float64 numpy products of its stored slots."""
+        if build == "coo":
+            sf = from_coo(
+                *random_sparse(rng, n, d, k), n, d, dtype=jnp.float64
+            )
+        else:
+            sf = random_ell(
+                rng, n, k, d, dtype=jnp.dtype(values_dtype), pad_rows=pad,
+                dup_row=dup,
+            )
+        x, x2 = _dense_of_slots(sf), _dense_of_slots(sf, square=True)
+        vdt = jnp.dtype(vector_dtype)
+        w = jnp.asarray(rng.normal(size=d), dtype=vdt)
+        a = jnp.asarray(rng.normal(size=n), dtype=vdt)
+        c = jnp.asarray(rng.uniform(0.1, 1.0, size=n), dtype=vdt)
+        w64, a64, c64 = (np.asarray(v.astype(jnp.float64)) for v in (w, a, c))
+        # float64 agrees to rounding; a bfloat16 design is held to what
+        # its 8 bits of mantissa can give
+        tol = 1e-12 if values_dtype == "float64" else (
+            1e-2 if values_dtype == "bfloat16" else 1e-6
         )
-        np.testing.assert_allclose(
-            np.asarray(rmatvec(sf, jnp.asarray(a))), x.T @ a, rtol=1e-12
-        )
-        np.testing.assert_allclose(
-            np.asarray(colsum(sf, jnp.asarray(a))),
-            np.einsum("n,nd->d", a, x),
-            rtol=1e-12,
-        )
-        np.testing.assert_allclose(
-            np.asarray(colsum(sf, jnp.asarray(a), square=True)),
-            np.einsum("n,nd->d", a, x * x),
-            rtol=1e-12,
-        )
+        for got, want in (
+            (matvec(sf, w), x @ w64),
+            (rmatvec(sf, a), x.T @ a64),
+            (colsum(sf, c), x.T @ c64),
+            (colsum(sf, c, square=True), x2.T @ c64),
+        ):
+            assert got.shape == want.shape
+            assert got.dtype == jnp.result_type(sf.values.dtype, vdt)
+            scale = max(1.0, float(np.max(np.abs(want), initial=0.0)))
+            np.testing.assert_allclose(
+                np.asarray(got.astype(jnp.float64)), want,
+                rtol=tol,
+                atol=tol * scale,
+            )
 
     def test_padding_is_invisible(self, rng):
         # widen rows with explicit padding slots; results must not change
@@ -83,6 +157,9 @@ class TestKernels:
             from_dense(x, nnz_per_row=3)
 
 
+_ALL_PASSES = ("value_grad_curvature", "hessian_vector", "hessian_diagonal")
+
+
 class TestSparseObjective:
     def _batches(self, rng, n=128, d=40, nnz=6):
         sf = from_coo(*random_sparse(rng, n, d, nnz), n, d, dtype=jnp.float64)
@@ -93,25 +170,74 @@ class TestSparseObjective:
         sparse = LabeledBatch.create(sf, y, dtype=jnp.float64)
         return dense, sparse, w_true
 
-    def test_objective_value_grad_hvp_match_dense(self, rng):
-        dense, sparse, _ = self._batches(rng)
-        obj = GLMObjective(loss=LOGISTIC_LOSS, l2_weight=0.3)
-        w = jnp.asarray(rng.normal(size=dense.num_features))
-        v = jnp.asarray(rng.normal(size=dense.num_features))
-        vd, gd = obj.value_and_grad(w, dense)
-        vs, gs = jax.jit(obj.value_and_grad)(w, sparse)
-        np.testing.assert_allclose(float(vs), float(vd), rtol=1e-12)
-        np.testing.assert_allclose(np.asarray(gs), np.asarray(gd), rtol=1e-10)
-        np.testing.assert_allclose(
-            np.asarray(obj.hessian_vector(w, v, sparse)),
-            np.asarray(obj.hessian_vector(w, v, dense)),
-            rtol=1e-10,
+    @pytest.mark.parametrize(
+        "passes,with_norm,weighted",
+        [pytest.param(_ALL_PASSES, False, False, id="all-plain")]
+        + [
+            pytest.param((name,), norm, True, id=f"{name}-{tag}")
+            for name in _ALL_PASSES
+            for norm, tag in ((False, "weighted"), (True, "shift-and-scale"))
+        ],
+    )
+    def test_objective_value_grad_hvp_match_dense(
+        self, rng, passes, with_norm, weighted
+    ):
+        """Every pass of the objective on a padded ELL against the same
+        pass on a dense matrix. Under a shift-and-scale normalization the
+        dense side holds the WHITENED matrix (x - shifts) * factors and no
+        context, so the normalization algebra of ``_backproject`` and of
+        the Hessian diagonal is held to the matrix it stands for."""
+        n, d, nnz = 128, 40, 6
+        rows, cols, vals = random_sparse(rng, n, d, nnz)
+        if weighted:  # three leading rows of padding only
+            rows, cols, vals = rows[3 * nnz:], cols[3 * nnz:], vals[3 * nnz:]
+        sf = from_coo(rows, cols, vals, n, d, dtype=jnp.float64)
+        x = to_dense(sf)
+        y = (rng.uniform(size=n) < 0.5).astype(float)
+        extras = {}
+        if weighted:
+            extras = dict(
+                offsets=rng.normal(size=n) * 0.1,
+                weights=rng.uniform(0.5, 2.0, size=n),
+            )
+        norm = None
+        if with_norm:
+            factors = rng.uniform(0.5, 2.0, size=d)
+            shifts = rng.normal(size=d) * 0.05
+            norm = NormalizationContext(
+                factors=jnp.asarray(factors), shifts=jnp.asarray(shifts)
+            )
+            x = (x - shifts) * factors
+        sparse = LabeledBatch.create(sf, y, dtype=jnp.float64, **extras)
+        dense = LabeledBatch.create(x, y, dtype=jnp.float64, **extras)
+        obj_d = GLMObjective(loss=LOGISTIC_LOSS, l2_weight=0.3)
+        obj_s = (
+            dataclasses.replace(obj_d, normalization=norm) if norm else obj_d
         )
-        np.testing.assert_allclose(
-            np.asarray(obj.hessian_diagonal(w, sparse)),
-            np.asarray(obj.hessian_diagonal(w, dense)),
-            rtol=1e-10,
-        )
+        w = jnp.asarray(rng.normal(size=d))
+        v = jnp.asarray(rng.normal(size=d))
+        if "value_grad_curvature" in passes:
+            vd, gd, cd = obj_d.value_grad_curvature(w, dense)
+            vs, gs, cs = jax.jit(obj_s.value_grad_curvature)(w, sparse)
+            np.testing.assert_allclose(float(vs), float(vd), rtol=1e-12)
+            np.testing.assert_allclose(
+                np.asarray(gs), np.asarray(gd), rtol=1e-10, atol=1e-12
+            )
+            np.testing.assert_allclose(
+                np.asarray(cs), np.asarray(cd), rtol=1e-10, atol=1e-14
+            )
+        if "hessian_vector" in passes:
+            np.testing.assert_allclose(
+                np.asarray(obj_s.hessian_vector(w, v, sparse)),
+                np.asarray(obj_d.hessian_vector(w, v, dense)),
+                rtol=1e-10, atol=1e-12,
+            )
+        if "hessian_diagonal" in passes:
+            np.testing.assert_allclose(
+                np.asarray(obj_s.hessian_diagonal(w, sparse)),
+                np.asarray(obj_d.hessian_diagonal(w, dense)),
+                rtol=1e-10,
+            )
 
     def test_training_matches_dense_oracle(self, rng):
         from photon_ml_tpu.models import (
@@ -331,4 +457,115 @@ class TestSparseIngest:
         )
         np.testing.assert_allclose(
             np.asarray(sparse.weights), np.asarray(dense.weights), rtol=1e-12
+        )
+
+
+class TestFeatureShardedBucketedReduction:
+    def test_unsharded_is_bit_identical(self, rng):
+        sf = random_ell(rng, 21, 4, 97)
+        w = jnp.asarray(rng.standard_normal(97).astype(np.float32))
+        u = jnp.asarray(rng.standard_normal(97).astype(np.float32))
+        z, (du, dw) = matvec_and_feature_dots(sf, w, ((u, w), (w, w)))
+        np.testing.assert_array_equal(
+            np.asarray(z), np.asarray(matvec(sf, w))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(du), np.asarray(jnp.vdot(u, w))
+        )
+        np.testing.assert_array_equal(
+            np.asarray(dw), np.asarray(jnp.vdot(w, w))
+        )
+
+    def test_blocked_container_matches_unfused(self, rng):
+        n, k, d = 30, 4, 96
+        sf = random_ell(rng, n, k, d)
+        blocked = shard_columns(sf, 2)
+        d_block = 2 * blocked.d_shard
+        w = jnp.asarray(rng.standard_normal(d_block).astype(np.float32))
+        u = jnp.asarray(rng.standard_normal(d_block).astype(np.float32))
+        z, (du,) = matvec_and_feature_dots(blocked, w, ((u, w),))
+        np.testing.assert_allclose(
+            np.asarray(z), np.asarray(matvec(blocked, w)),
+            rtol=1e-6, atol=1e-6,
+        )
+        np.testing.assert_allclose(
+            float(du), float(jnp.vdot(u, w)), rtol=1e-6
+        )
+
+    def test_coalesced_pass_reduces_one_payload(self, rng, devices):
+        # What the CODE controls: with fuse_feature_reductions the pass
+        # builds ONE (n + P,) feature-space reduction (margins + every
+        # scalar dot) where the unfused pass builds 1 + P. How many
+        # all-reduce INSTRUCTIONS that becomes is the compiler's call:
+        # on jax 0.9.0's XLA the all-reduce combiner merges the unfused
+        # pass's reductions too and both compile to the same count, so
+        # asserting fused < unfused there tested the compiler, not this
+        # code. Asserted here: the traced payload geometry, that fusing
+        # never ADDS a collective, and numerical equality.
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from photon_ml_tpu.obs.xla_cost import count_collectives
+        from photon_ml_tpu.ops.sparse import FeatureShardedSparse
+        from photon_ml_tpu.parallel import make_feature_mesh
+        from photon_ml_tpu.parallel.mesh import DATA_AXIS, FEATURE_AXIS
+
+        n, k, d = 64, 4, 256
+        sf = random_ell(rng, n, k, d)
+        y = (rng.uniform(size=n) < 0.5).astype(np.float32)
+        batch = LabeledBatch.create(sf, y)
+        mesh = make_feature_mesh(1, 2)
+        blocked = shard_columns(batch.features, 2)
+        spec = NamedSharding(mesh, P(DATA_AXIS, FEATURE_AXIS, None))
+        placed = FeatureShardedSparse(
+            indices=jax.device_put(blocked.indices, spec),
+            values=jax.device_put(blocked.values, spec),
+            d_shard=blocked.d_shard,
+            d_orig=blocked.d_orig,
+        )
+        pb = dataclasses.replace(batch, features=placed)
+        d_block = 2 * blocked.d_shard
+        w0 = jax.device_put(
+            jnp.zeros((d_block,), jnp.float32),
+            NamedSharding(mesh, P(FEATURE_AXIS)),
+        )
+
+        from photon_ml_tpu import obs
+
+        def traced_payloads():
+            snap = obs.registry().snapshot()["counters"]
+            key = "collective.traced.matvec_and_feature_dots.w2"
+            return (
+                snap.get(f"{key}.count", 0), snap.get(f"{key}.bytes", 0)
+            )
+
+        def compile_pass(fuse):
+            obj = GLMObjective(
+                loss=LOGISTIC_LOSS,
+                l2_weight=1.0,
+                fuse_feature_reductions=fuse,
+            )
+            before = traced_payloads()
+            with jax.set_mesh(mesh):
+                comp = (
+                    jax.jit(lambda w, b: obj.value_and_grad(w, b))
+                    .lower(w0, pb)
+                    .compile()
+                )
+            after = traced_payloads()
+            return comp, (after[0] - before[0], after[1] - before[1])
+
+        fused_c, fused_note = compile_pass(True)
+        unfused_c, unfused_note = compile_pass(False)
+        # one coalesced reduction of n margins + the one L2 dot (f32)
+        assert fused_note == (1, (n + 1) * 4)
+        assert unfused_note == (0, 0)
+        n_fused = sum(count_collectives(fused_c.as_text()).values())
+        n_unfused = sum(count_collectives(unfused_c.as_text()).values())
+        assert 1 <= n_fused <= n_unfused, (n_fused, n_unfused)
+        # numerically identical up to reduction order
+        vf, gf = fused_c(w0, pb)
+        vu, gu = unfused_c(w0, pb)
+        np.testing.assert_allclose(float(vf), float(vu), rtol=1e-6)
+        np.testing.assert_allclose(
+            np.asarray(gf), np.asarray(gu), rtol=1e-6, atol=1e-6
         )
