@@ -572,9 +572,12 @@ class RandomEffectUpdateSummary:
 
 def _make_multi_bucket_update(config: CoordinateConfig):
     """ONE jitted call updating ALL buckets of a random effect: per bucket,
-    gather residual offsets and warm starts from the global table, solve
-    the bucket's entities in one vmapped call, scatter solutions back.
-    Sentinel indices (== num_entities) clip on gather and drop on scatter.
+    gather residual offsets and warm starts from the global table and
+    solve the bucket's entities in one vmapped call; then write every
+    solution into the table at once, by a gather through the static
+    entity -> lane map (``_lane_of_entity``): a table row reads its lane,
+    or keeps its value where no lane holds it. Sentinel indices
+    (== num_entities) clip on gather and no table row points at them.
 
     Fusing the whole multi-bucket pass into a single dispatch saves
     per-dispatch launch latency (a 4-bucket update would otherwise cost
@@ -593,22 +596,30 @@ def _make_multi_bucket_update_cached(config: CoordinateConfig):
 
     @jax.jit
     def update_all(
-        table, reg_weights, full_offsets, entity_indices, buckets,
-        row_features, row_entities,
+        table, reg_weights, full_offsets, entity_indices, lane_of_entity,
+        buckets, row_features, row_entities,
     ):
+        # runs while a coordinate's update is traced, never in a pass
+        obs.registry().inc("game.table_write.inverse_gather")
+        solved = []
         trackers = []
         for eidx, bucket in zip(entity_indices, buckets):
+            # every bucket warm-starts from the table as it came in: an
+            # entity sits in at most one lane, so no bucket reads a row
+            # that another wrote
             with jax.named_scope("re_gather"):
-                offsets = bucket.gather_offsets(full_offsets)
-                w0 = jnp.take(table, eidx, axis=0, mode="clip")
-                lam = jnp.take(reg_weights, eidx, mode="clip")
+                with jax.named_scope("offsets"):
+                    offsets = bucket.gather_offsets(full_offsets)
+                with jax.named_scope("warm_start"):
+                    w0 = jnp.take(table, eidx, axis=0, mode="clip")
+                with jax.named_scope("reg_weight"):
+                    lam = jnp.take(reg_weights, eidx, mode="clip")
             with jax.named_scope("re_newton_solve"):
                 result = solve(
                     w0, lam, bucket.features, bucket.labels, offsets,
                     bucket.weights, bucket.mask,
                 )
-            with jax.named_scope("re_scatter"):
-                table = table.at[eidx].set(result.w, mode="drop")
+            solved.append(result.w)
             # final per-entity gradient norm rides the tracker tuple
             # (valid with tracking on or off), feeding the fleet-level
             # convergence summaries' worst-k signal for free — it is
@@ -616,12 +627,45 @@ def _make_multi_bucket_update_cached(config: CoordinateConfig):
             trackers.append(
                 (result.reason, result.iterations, final_grad_norm(result))
             )
+        # the lanes are a fixed permutation of the table rows they hold,
+        # so the write is read from the table's side: one gather of
+        # num_entities rows where a scatter a bucket cost eleven times a
+        # row (PERF.md section 6, PR 33)
+        with jax.named_scope("re_scatter"):
+            lanes = jnp.concatenate(solved)
+            written = jnp.take(
+                lanes, jnp.maximum(lane_of_entity, 0), axis=0, mode="clip"
+            )
+            table = jnp.where(lane_of_entity[:, None] >= 0, written, table)
         # full-row rescore in the same dispatch
         with jax.named_scope("re_score"):
             scores = _score_rows_by_entity(table, row_features, row_entities)
         return table, tuple(trackers), scores
 
     return update_all
+
+
+def _lane_of_entity(entity_index, num_entities: int) -> np.ndarray:
+    """(num_entities,) int32, entity -> position of its lane in the
+    concatenation of the buckets' lanes (bucket 0's first, in lane order);
+    -1 for an entity that sits in no lane. Sentinel lanes (index ==
+    num_entities, the sharding pads) are never pointed at. The inverse of
+    ``design.entity_index``, which is static for the coordinate's life."""
+    lanes = np.concatenate(
+        [np.asarray(ei, np.int64).reshape(-1) for ei in entity_index]
+    )
+    position = np.flatnonzero(lanes < num_entities)
+    entities = lanes[position]
+    twice = np.flatnonzero(np.bincount(entities, minlength=1) > 1)
+    if twice.size:
+        raise ValueError(
+            f"{twice.size} entities sit in more than one lane of the "
+            f"bucketed design (first: {int(twice[0])}); the table write "
+            "needs every entity in at most one lane"
+        )
+    lane_of = np.full(num_entities, -1, np.int32)
+    lane_of[entities] = position
+    return lane_of
 
 
 def _score_rows_by_entity(table, feats, ents):
@@ -689,6 +733,9 @@ class RandomEffectCoordinate:
         self._entity_indices = tuple(
             jnp.asarray(ei) for ei in design.entity_index
         )
+        self._lane_of_entity = jnp.asarray(
+            _lane_of_entity(design.entity_index, design.num_entities)
+        )
         # static per-bucket masks of real (non-sharding-pad) lanes
         self._valid_lanes = [
             np.asarray(ei) < design.num_entities
@@ -741,6 +788,7 @@ class RandomEffectCoordinate:
             self.reg_weights,
             self.full_offsets_base + partial_scores,
             self._entity_indices,
+            self._lane_of_entity,
             tuple(self.design.buckets),
             self.row_features,
             self.row_entities,
@@ -764,6 +812,7 @@ class RandomEffectCoordinate:
             self.reg_weights,
             self.full_offsets_base,
             self._entity_indices,
+            self._lane_of_entity,
             tuple(self.design.buckets),
             self.row_features,
             self.row_entities,
@@ -780,9 +829,10 @@ class RandomEffectCoordinate:
 
         SAME-OBJECT CONTRACT (see the fixed-effect counterpart): only
         the freshly-built per-entity weight vector may vary per call;
-        the design buckets, row features, entity indices, and offsets
-        must be the SAME objects every time so run_grid broadcasts them
-        instead of stacking n_combo copies of the dataset."""
+        the design buckets, row features, entity indices, the entity ->
+        lane map and offsets must be the SAME objects every time so
+        run_grid broadcasts them instead of stacking n_combo copies of
+        the dataset."""
         if not getattr(self, "_uniform_reg", True):
             raise ValueError(
                 "grid sweeps replace the coordinate's shared reg weight; "
@@ -795,6 +845,7 @@ class RandomEffectCoordinate:
             ),
             self.full_offsets_base,
             self._entity_indices,
+            self._lane_of_entity,
             tuple(self.design.buckets),
             self.row_features,
             self.row_entities,
@@ -808,6 +859,7 @@ class RandomEffectCoordinate:
             c.reg_weights,
             c.full_offsets_base,
             c._entity_indices,
+            c._lane_of_entity,
             buckets,
             c.row_features,
             c.row_entities,

@@ -49,7 +49,7 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "entities, buckets, bucket_caps, active_rows, active_slots, "
         "capped_entities, passive_rows) and counters (game.passes, "
         "game.updates, game.checkpoint.submit_ms, game.re.capped_entities, "
-        "game.re.passive_rows, ...)",
+        "game.re.passive_rows, game.table_write.inverse_gather, ...)",
     ),
     (
         "solver",

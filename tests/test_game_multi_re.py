@@ -346,6 +346,9 @@ def _op_names_of_the_pass():
     "per-song/jit(update_all)/re_newton_solve/",
     "per-song/jit(update_all)/re_scatter/",
     "per-song/jit(update_all)/re_score/",
+    "per-song/jit(update_all)/re_gather/offsets/",
+    "per-song/jit(update_all)/re_gather/warm_start/",
+    "per-song/jit(update_all)/re_gather/reg_weight/",
 ])
 def test_scope_is_in_the_compiled_pass(scope):
     names = _op_names_of_the_pass()
