@@ -521,6 +521,9 @@ class GameDriverParams:
                 f"entity_shards must be >= 0, got {self.entity_shards}"
             )
         if self.entity_shards > 1:
+            # every PLAIN random effect shards, each in its own row
+            # partition (docs/PARALLEL.md); the other kinds hold state
+            # the shard-local update does not carry
             plain_res = [
                 n
                 for n, c in self.coordinates.items()
@@ -534,11 +537,13 @@ class GameDriverParams:
                 for n, c in self.coordinates.items()
                 if c.random_effect is not None and n not in plain_res
             ]
-            if len(plain_res) != 1 or other_res:
+            if not plain_res or other_res:
                 raise ValueError(
-                    "entity_shards requires exactly one PLAIN random-"
-                    "effect coordinate (identity projector, dense "
-                    f"shard); got plain={plain_res} other={other_res}"
+                    "entity_shards shards PLAIN random-effect coordinates "
+                    "(identity projector, dense shard), any number of "
+                    "them, and needs at least one; factored, projected "
+                    "and sparse-shard random effects cannot be entity-"
+                    f"sharded; got plain={plain_res} other={other_res}"
                 )
         sparse = set(self.sparse_shards)
         for name, spec in self.coordinates.items():

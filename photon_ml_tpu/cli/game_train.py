@@ -203,12 +203,14 @@ def build_coordinates(
     "local_entity_counts"} — local builds are globalized into
     mesh-spanning arrays (``parallel.multihost``).
 
-    ``entity_sharded`` (docs/PARALLEL.md): {"mesh", "assignment",
-    "partition"} — ``data`` is already in the entity-PARTITIONED row
-    order; fixed-effect batches place row-sharded over the 'entity'
-    mesh and the (single, plain) random-effect coordinate builds as an
-    :class:`EntityShardedRandomEffectCoordinate` (zero collectives in
-    its update)."""
+    ``entity_sharded`` (docs/PARALLEL.md): {"mesh", "layouts": random
+    effect -> :class:`game.data.EntityShardLayout`} —
+    ``data`` is already in the CANONICAL entity-partitioned row order
+    (the first random effect's); fixed-effect batches place row-sharded
+    over the 'entity' mesh and every (plain) random-effect coordinate
+    builds as an :class:`EntityShardedRandomEffectCoordinate` over its
+    own layout (zero collectives in the canonical one's update, the row
+    exchange in the others')."""
     coords = {}
     for name in params.updating_sequence:
         spec = params.coordinates[name]
@@ -253,7 +255,15 @@ def build_coordinates(
         else:
             from photon_ml_tpu.ops import sparse as sparse_ops
 
-            if sparse_ops.is_sparse(data.features[spec.shard]):
+            # an entity-sharded random effect's design and per-row inputs
+            # live in ITS row order; its offsets stay canonical
+            es_layout = (
+                entity_sharded["layouts"][spec.random_effect]
+                if entity_sharded is not None
+                else None
+            )
+            own = data if es_layout is None else es_layout.data
+            if sparse_ops.is_sparse(own.features[spec.shard]):
                 # wide-sparse random effect: INDEX_MAP projection straight
                 # from the ELL (config.validate() guarantees the projector)
                 cache_key = f"{name}\x00sparse_projected"
@@ -279,21 +289,32 @@ def build_coordinates(
             if design_cache is not None and name in design_cache:
                 design = design_cache[name]
             else:
-                design = build_bucketed_random_effect_design(
-                    data,
-                    spec.random_effect,
-                    spec.shard,
-                    (
-                        multiproc["local_entity_counts"][spec.random_effect]
-                        if multiproc is not None
-                        else entity_counts[spec.random_effect]
-                    ),
+                design_options = dict(
                     num_buckets=spec.num_buckets,
                     active_cap=spec.active_cap,
                     dtype=dtype,
                     feature_ratio=spec.feature_ratio,
                     min_support=spec.min_support,
                 )
+                if es_layout is not None:
+                    # kept off the first chip: the coordinate shards it
+                    design = es_layout.bucketed_design(
+                        spec.random_effect, spec.shard, **design_options
+                    )
+                else:
+                    design = build_bucketed_random_effect_design(
+                        data,
+                        spec.random_effect,
+                        spec.shard,
+                        (
+                            multiproc["local_entity_counts"][
+                                spec.random_effect
+                            ]
+                            if multiproc is not None
+                            else entity_counts[spec.random_effect]
+                        ),
+                        **design_options,
+                    )
                 if multiproc is not None:
                     from photon_ml_tpu.parallel import (
                         make_global_re_design,
@@ -311,7 +332,13 @@ def build_coordinates(
                     )
                 if design_cache is not None:
                     design_cache[name] = design
-            if multiproc is None:
+            if es_layout is not None:
+                # host arrays: the coordinate puts each shard's rows
+                # straight on its chip
+                row_features = np.asarray(own.features[spec.shard], dtype)
+                row_entities = np.asarray(own.entity_ids[spec.random_effect])
+                offsets_base = np.asarray(data.offsets, dtype)
+            elif multiproc is None:
                 row_features = jnp.asarray(data.features[spec.shard], dtype)
                 row_entities = jnp.asarray(
                     data.entity_ids[spec.random_effect]
@@ -390,8 +417,8 @@ def build_coordinates(
                         full_offsets_base=offsets_base,
                         config=cfg,
                         mesh=entity_sharded["mesh"],
-                        assignment=entity_sharded["assignment"],
-                        partition=entity_sharded["partition"],
+                        assignment=es_layout.assignment,
+                        partition=es_layout.partition,
                     )
                 else:
                     coords[name] = RandomEffectCoordinate(
@@ -798,46 +825,45 @@ def _run_game_training(
                 f"entity_shards={params.entity_shards} exceeds "
                 f"{jax.device_count()} visible devices"
             )
-        from photon_ml_tpu.game import (
-            entity_partition_game_data,
-            entity_shard_assignment,
-        )
+        from photon_ml_tpu.game import entity_shard_layouts
         from photon_ml_tpu.parallel.mesh import make_entity_mesh
 
-        re_name = next(
-            n
-            for n, c in params.coordinates.items()
-            if c.random_effect is not None
-        )
-        re_key = params.coordinates[re_name].random_effect
         es_mesh = make_entity_mesh(
             params.entity_shards,
             devices=jax.devices()[: params.entity_shards],
         )
-        es_assignment = entity_shard_assignment(
-            entity_counts[re_key], params.entity_shards
+        # one layout step a random effect, in update order: the first
+        # one's partition is the canonical row order (``data`` from here
+        # on), every other gets its own partition and the exchange plan
+        # to and from the canonical one
+        sharded_res = {}
+        for name in params.updating_sequence:
+            spec = params.coordinates[name]
+            if spec.random_effect is not None:
+                sharded_res.setdefault(spec.random_effect, set()).add(
+                    spec.shard
+                )
+        layouts = entity_shard_layouts(
+            data,
+            {re_key: entity_counts[re_key] for re_key in sharded_res},
+            params.entity_shards,
+            sharded_res,
         )
-        from photon_ml_tpu import obs as _obs_mod
-
-        with _obs_mod.span(
-            "partition.entity_layout", cat="partition",
-            shards=params.entity_shards,
-            entities=entity_counts[re_key],
-        ):
-            data, es_partition = entity_partition_game_data(
-                data, re_key, es_assignment
+        for re_key, layout in layouts.items():
+            plan = layout.partition.exchange
+            logger.info(
+                f"entity-sharded descent, {re_key}: "
+                f"{params.entity_shards} shards, "
+                f"{layout.assignment.rows_per_shard} entities/shard, "
+                f"{layout.partition.rows_per_shard} rows/shard, "
+                + (
+                    "the canonical row order"
+                    if plan is None
+                    else f"exchange blocks of {plan.block_rows} rows"
+                )
             )
-        entity_sharded = {
-            "mesh": es_mesh,
-            "assignment": es_assignment,
-            "partition": es_partition,
-        }
-        logger.info(
-            f"entity-sharded descent: {params.entity_shards} shards, "
-            f"{es_assignment.rows_per_shard} entities/shard, "
-            f"{es_partition.rows_per_shard} rows/shard "
-            f"(padded from {es_partition.row_perm.size} stored rows)"
-        )
+        data = next(iter(layouts.values())).data
+        entity_sharded = {"mesh": es_mesh, "layouts": layouts}
 
     # ---- grid sweep ------------------------------------------------------
     shards_by_coord = {
@@ -865,9 +891,9 @@ def _run_game_training(
                 # the device table is stored SHARD-MAJOR (padded); label
                 # its rows in that order so checkpoint shards carry the
                 # keys the restore re-keys by (pad rows keyed uniquely)
-                ordered = entity_sharded[
-                    "assignment"
-                ].stored_entity_keys(ordered)
+                ordered = entity_sharded["layouts"][
+                    re_key
+                ].assignment.stored_entity_keys(ordered)
             ckpt_entity_keys[n] = ordered
 
     def validation_metric(model: GameModel) -> float:

@@ -26,13 +26,17 @@ from photon_ml_tpu.game.data import (
     BucketedRandomEffectDesign,
     EntityRowPartition,
     EntityShardAssignment,
+    EntityShardLayout,
     GameData,
     RandomEffectDesign,
+    RowExchangePlan,
     build_bucketed_random_effect_design,
     build_random_effect_design,
     entity_partition_game_data,
     entity_partition_rows,
     entity_shard_assignment,
+    entity_shard_layouts,
+    row_exchange_plan,
 )
 from photon_ml_tpu.game.coordinates import (
     CoordinateConfig,
@@ -69,6 +73,8 @@ __all__ = [
     "CoordinateConfig",
     "EntityRowPartition",
     "EntityShardAssignment",
+    "EntityShardLayout",
+    "RowExchangePlan",
     "EntityShardedRandomEffectCoordinate",
     "FixedEffectCoordinate",
     "RandomEffectCoordinate",
@@ -77,4 +83,6 @@ __all__ = [
     "entity_partition_game_data",
     "entity_partition_rows",
     "entity_shard_assignment",
+    "entity_shard_layouts",
+    "row_exchange_plan",
 ]

@@ -591,10 +591,19 @@ def _make_multi_bucket_update(config: CoordinateConfig):
 
 @lru_cache(maxsize=128)
 def _make_multi_bucket_update_cached(config: CoordinateConfig):
+    return jax.jit(_multi_bucket_update_body(config))
+
+
+def _multi_bucket_update_body(config: CoordinateConfig):
+    """The un-jitted update of every bucket of one random effect: THE
+    body :class:`RandomEffectCoordinate` runs over the whole table and
+    :class:`EntityShardedRandomEffectCoordinate` runs a shard, under its
+    ``shard_map``, over the shard's block of it (every index then
+    shard-local). One definition, so a change to either path is a change
+    to both."""
     solve = _make_solve(config, batched=True)
     from photon_ml_tpu.solvers.common import final_grad_norm
 
-    @jax.jit
     def update_all(
         table, reg_weights, full_offsets, entity_indices, lane_of_entity,
         buckets, row_features, row_entities,
@@ -881,22 +890,59 @@ class RandomEffectCoordinate:
         return jnp.sum(0.5 * l2 * sq + l1 * ab)
 
 
+def _exchange_rows(x, send, recv, num_shards: int, scope: str):
+    """One direction of a :class:`game.data.RowExchangePlan` on one shard,
+    inside a ``shard_map`` over the 'entity' axis: pack this shard's rows
+    into one block a destination (a gather through ``send``), move block
+    (p, q) to shard q with one ``all_to_all``, and read every row of the
+    arriving order out of what came by one gather through the static
+    inverse map ``recv``. A pad slot or pad row (-1) carries 0."""
+    from photon_ml_tpu.parallel.mesh import ENTITY_AXIS
+
+    with jax.named_scope("re_exchange"), jax.named_scope(scope):
+        packed = jnp.where(
+            send >= 0, jnp.take(x, jnp.maximum(send, 0), mode="clip"), 0
+        )
+        arrived = jax.lax.all_to_all(
+            packed.reshape(num_shards, -1), ENTITY_AXIS, 0, 0
+        )
+        return jnp.where(
+            recv >= 0,
+            jnp.take(arrived.reshape(-1), jnp.maximum(recv, 0), mode="clip"),
+            0,
+        )
+
+
 class EntityShardedRandomEffectCoordinate:
     """Entity-sharded random-effect coordinate: the per-entity vmapped
-    solves run under ``shard_map`` over the 'entity' mesh axis with ZERO
-    collectives in the update (docs/PARALLEL.md) — each shard gathers
-    warm starts from ITS table block, solves ITS entities, scatters back
-    locally, and rescores ITS rows. Only the fixed-effect coordinate's
-    objective reduces across devices.
+    solves run under ``shard_map`` over the 'entity' mesh axis
+    (docs/PARALLEL.md) — each shard gathers warm starts from ITS table
+    block, solves ITS entities, writes them back locally and rescores
+    ITS rows, through the same update body as
+    :class:`RandomEffectCoordinate` (``_multi_bucket_update_body``) with
+    every index shard-local.
 
     Contract (``game.data``): entity ownership follows the sharded
     checkpoint writer's round-robin rule (``EntityShardAssignment``),
-    the table is stored SHARD-MAJOR (pad rows zero), and the batch row
-    space is entity-PARTITIONED (``EntityRowPartition``) so every
+    the table is stored SHARD-MAJOR (pad rows zero), and the design, the
+    row features and the row entities are in the coordinate's OWN
+    entity-partitioned row order (``EntityRowPartition``), so every
     entity's rows live on its owner shard — the device analog of the
-    reference's ``RandomEffectIdPartitioner`` placement. All per-row
-    inputs here are in the PERMUTED row order; sentinel lanes/rows mask
-    to zero and their scattered solutions drop.
+    reference's ``RandomEffectIdPartitioner`` placement. Sentinel
+    lanes/rows mask to zero and no table row reads a sentinel lane.
+
+    Residual offsets come in, and scores go out, in the CANONICAL row
+    order (the first sharded random effect's partition, which the
+    descent's labels, weights and every other coordinate's scores live
+    in). Where ``partition.exchange`` is None the two orders are one and
+    the update has ZERO collectives. Where it is a
+    :class:`game.data.RowExchangePlan` (any later random effect: rows
+    cannot be grouped by two entity types at once) the one program is
+    ``offsets_own = exchange(base + partial_scores)``, the per-shard
+    update, ``scores = exchange(scores_own)``: two ``all_to_all``s with
+    static shapes, packed and unpacked by gathers, the reference's
+    shuffle between two partitioners. No option selects this: the
+    coordinate sees it from the partition it was given.
 
     Exposes the full fused surface (update_step / fused_state /
     with_fused_state / wrap_tracker), so whole-pass and superpass
@@ -905,14 +951,14 @@ class EntityShardedRandomEffectCoordinate:
 
     def __init__(
         self,
-        design,  # BucketedRandomEffectDesign on the PERMUTED rows, GLOBAL ids
-        row_features: jax.Array,  # (n_pad, d) permuted
-        row_entities: jax.Array,  # (n_pad,) permuted GLOBAL ids, -1 unknown
-        full_offsets_base: jax.Array,  # (n_pad,) permuted
+        design,  # BucketedRandomEffectDesign on the OWN-order rows, GLOBAL ids
+        row_features: jax.Array,  # (n_pad, d) own order
+        row_entities: jax.Array,  # (n_pad,) own order, GLOBAL ids, -1 unknown
+        full_offsets_base: jax.Array,  # (n_canonical_pad,) CANONICAL order
         config: CoordinateConfig,
         mesh,
         assignment,  # game.data.EntityShardAssignment
-        partition,  # game.data.EntityRowPartition
+        partition,  # game.data.EntityRowPartition (+ its exchange plan)
         reg_weights: Optional[jax.Array] = None,  # (E,) GLOBAL order
     ):
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -955,22 +1001,30 @@ class EntityShardedRandomEffectCoordinate:
                 f"row arrays must be in the partitioned row space "
                 f"({n_pad} rows), got {np.shape(row_entities)[0]}"
             )
+        plan = partition.exchange
+        n_canonical = n_pad if plan is None else plan.canonical_padded_rows
+        if int(np.shape(full_offsets_base)[0]) != n_canonical:
+            raise ValueError(
+                f"full_offsets_base must be in the canonical row order "
+                f"({n_canonical} rows), got {np.shape(full_offsets_base)[0]}"
+            )
         self.config = config
         self.mesh = mesh
         self.assignment = assignment
         self.partition = partition
-        self.design = design
+        self._dim = design.dim
 
         ent_spec = lambda nd: NamedSharding(
             mesh, P(ENTITY_AXIS, *([None] * (nd - 1)))
         )
 
         def place(x):
-            x = jnp.asarray(x)
-            return jax.device_put(x, ent_spec(x.ndim))
+            # straight to its shards: a host array staged through
+            # ``jnp.asarray`` would sit whole on the first device
+            return jax.device_put(x, ent_spec(np.ndim(x)))
 
         # per-entity reg weights, stored shard-major (pad rows keep the
-        # config weight — their scattered solutions drop anyway)
+        # config weight — no lane holds them)
         self._uniform_reg = reg_weights is None
         if reg_weights is None:
             reg_stored = np.full(
@@ -1022,12 +1076,15 @@ class EntityShardedRandomEffectCoordinate:
                 b_rows,
             ).astype(np.int32)
 
+            old_lane = np.full(new_lanes, -1, np.int64)
+            old_lane[lane_of] = order
+
             def regroup(x, fill=0.0):
-                x = np.asarray(x)
-                out = np.full(
-                    (new_lanes,) + x.shape[1:], fill, x.dtype
+                # one gather of whole lanes, the pad lanes overwritten
+                out = np.take(
+                    np.asarray(x), np.maximum(old_lane, 0), axis=0
                 )
-                out[lane_of] = x[order]
+                out[old_lane < 0] = fill
                 return out
 
             ri = np.asarray(bucket.row_index, np.int64)
@@ -1047,7 +1104,7 @@ class EntityShardedRandomEffectCoordinate:
                     row_index=place(ri_local),
                 )
             )
-            eidx_local.append(place(local))
+            eidx_local.append(local)
             self._valid_lanes.append(
                 new_stored < assignment.padded_rows
             )
@@ -1056,7 +1113,25 @@ class EntityShardedRandomEffectCoordinate:
             glob[real] = assignment.stored_to_global[new_stored[real]]
             self._lane_entities.append(glob.astype(np.int32))
         self._buckets = tuple(buckets)
-        self._entity_indices = tuple(eidx_local)
+        # (every bucket's lanes, their shard-local inverse: block p maps
+        # the rows of ITS table block to the concatenation of ITS lanes)
+        self._entity_indices = (
+            tuple(place(li) for li in eidx_local),
+            place(
+                np.concatenate(
+                    [
+                        _lane_of_entity(
+                            [
+                                li.reshape(n_shards, -1)[p]
+                                for li in eidx_local
+                            ],
+                            b_rows,
+                        )
+                        for p in range(n_shards)
+                    ]
+                )
+            ),
+        )
 
         # per-row scoring inputs, shard-local entity rows
         re_ids = np.asarray(row_entities, np.int64)
@@ -1069,69 +1144,82 @@ class EntityShardedRandomEffectCoordinate:
         self.row_features = place(row_features)
         self.row_entities_local = place(ents_local)
         self.full_offsets_base = place(full_offsets_base)
-
-        solve = _make_solve(config, batched=True)
-        from photon_ml_tpu.solvers.common import final_grad_norm
-
-        spec_of = lambda x: P(ENTITY_AXIS, *([None] * (jnp.ndim(x) - 1)))
-
-        def update_all(table, reg, offsets, eidx, buckets_in, feats, ents):
-            def update_shard(
-                table_blk, reg_blk, off_blk, eidx_blk, bks, f_blk, e_blk
-            ):
-                trackers = []
-                for li, bucket in zip(eidx_blk, bks):
-                    offs = bucket.gather_offsets(off_blk)
-                    w0 = jnp.take(table_blk, li, axis=0, mode="clip")
-                    lam = jnp.take(reg_blk, li, mode="clip")
-                    with jax.named_scope("re_newton_solve"):
-                        result = solve(
-                            w0, lam, bucket.features, bucket.labels, offs,
-                            bucket.weights, bucket.mask,
-                        )
-                    table_blk = table_blk.at[li].set(
-                        result.w, mode="drop"
-                    )
-                    trackers.append(
-                        (
-                            result.reason,
-                            result.iterations,
-                            final_grad_norm(result),
-                        )
-                    )
-                scores = _score_rows_by_entity(table_blk, f_blk, e_blk)
-                return table_blk, tuple(trackers), scores
-
-            args = (table, reg, offsets, eidx, buckets_in, feats, ents)
-            in_specs = jax.tree_util.tree_map(spec_of, args)
-            out_shape = jax.eval_shape(
-                lambda *a: update_shard(*a), *args
+        # the exchange plan's four index arrays, a shard its segment; ()
+        # where the own order is the canonical one
+        self._exchange = (
+            ()
+            if plan is None
+            else tuple(
+                place(a)
+                for a in (
+                    plan.send_to_owner,
+                    plan.recv_at_owner,
+                    plan.send_to_canonical,
+                    plan.recv_at_canonical,
+                )
             )
-            out_specs = jax.tree_util.tree_map(spec_of, out_shape)
+        )
+
+        body = _multi_bucket_update_body(
+            dataclasses.replace(config, reg_weight=0.0)
+        )
+
+        def sharded(fn):
+            """``fn`` a shard, every argument and result split on its
+            leading axis over the 'entity' mesh axis."""
             return jax.shard_map(
-                update_shard,
+                fn,
                 mesh=mesh,
-                in_specs=in_specs,
-                out_specs=out_specs,
+                in_specs=P(ENTITY_AXIS),
+                out_specs=P(ENTITY_AXIS),
                 # per-shard solver while_loops have no replication rule;
                 # every output is genuinely shard-varying anyway
                 check_vma=False,
-            )(*args)
+            )
+
+        def to_owner(x, exchange):
+            return _exchange_rows(
+                x, exchange[0], exchange[1], n_shards, "to_owner"
+            )
+
+        def to_canonical(x, exchange):
+            return _exchange_rows(
+                x, exchange[2], exchange[3], n_shards, "to_canonical"
+            )
+
+        def update_shard(
+            table, reg, offsets, lanes, bks, feats, ents, exchange
+        ):
+            if exchange:
+                offsets = to_owner(offsets, exchange)
+            table, trackers, scores = body(
+                table, reg, offsets, *lanes, bks, feats, ents
+            )
+            if exchange:
+                scores = to_canonical(scores, exchange)
+            return table, trackers, scores
+
+        def update_all(
+            table, reg, offsets, lanes, bks, feats, ents, exchange=()
+        ):
+            if exchange:
+                # runs while an update is traced, never in a pass
+                obs.registry().inc("game.exchange.programs")
+                obs.registry().set_gauge(
+                    "game.exchange.bytes_per_pass",
+                    2 * plan.exchanged_rows * offsets.dtype.itemsize,
+                )
+            return sharded(update_shard)(
+                table, reg, offsets, lanes, bks, feats, ents, exchange
+            )
 
         self._update_all = jax.jit(update_all)
 
-        def score_fn(table, feats, ents):
-            args = (table, feats, ents)
-            in_specs = jax.tree_util.tree_map(spec_of, args)
-            return jax.shard_map(
-                _score_rows_by_entity,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=P(ENTITY_AXIS),
-                check_vma=False,
-            )(*args)
+        def score_shard(table, feats, ents, exchange):
+            scores = _score_rows_by_entity(table, feats, ents)
+            return to_canonical(scores, exchange) if exchange else scores
 
-        self._score = jax.jit(score_fn)
+        self._score = jax.jit(sharded(score_shard))
 
     @property
     def num_entities(self) -> int:
@@ -1139,7 +1227,7 @@ class EntityShardedRandomEffectCoordinate:
 
     @property
     def dim(self) -> int:
-        return self.design.dim
+        return self._dim
 
     def initial_params(self) -> jax.Array:
         from jax.sharding import NamedSharding, PartitionSpec as P
@@ -1174,8 +1262,10 @@ class EntityShardedRandomEffectCoordinate:
 
     def update_step(self, table, partial_scores, key=None):
         """Trace-safe: the whole multi-bucket update + rescore is ONE
-        shard_map'd program with no collective instructions (asserted in
-        tests/test_partition.py via the compiled HLO)."""
+        shard_map'd program whose only collectives are the exchange's
+        two ``all_to_all``s — none at all for the canonical coordinate
+        (asserted in tests/test_partition.py and
+        tests/test_game_sharded_multi_re.py via the compiled HLO)."""
         return self._update_all(
             table,
             self.reg_weights,
@@ -1184,6 +1274,7 @@ class EntityShardedRandomEffectCoordinate:
             self._buckets,
             self.row_features,
             self.row_entities_local,
+            self._exchange,
         )
 
     def wrap_tracker(self, trackers: tuple) -> "RandomEffectUpdateSummary":
@@ -1204,6 +1295,7 @@ class EntityShardedRandomEffectCoordinate:
             self._buckets,
             self.row_features,
             self.row_entities_local,
+            self._exchange,
         )
 
     def with_fused_state(self, state):
@@ -1217,12 +1309,14 @@ class EntityShardedRandomEffectCoordinate:
             c._buckets,
             c.row_features,
             c.row_entities_local,
+            c._exchange,
         ) = state
         return c
 
     def score(self, table: jax.Array) -> jax.Array:
         return self._score(
-            table, self.row_features, self.row_entities_local
+            table, self.row_features, self.row_entities_local,
+            self._exchange,
         )
 
     def reg_term(self, table: jax.Array) -> jax.Array:

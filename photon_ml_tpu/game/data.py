@@ -25,8 +25,9 @@ scored through the coefficient table (the reference's passive data,
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
-from typing import Dict, Mapping, Optional
+from typing import Collection, Dict, Mapping, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -178,7 +179,9 @@ def _fill_design(
     """Scatter kept rows into padded (shape_e, cap, d) tensors."""
     x = np.asarray(data.features[shard])
     d = x.shape[1]
-    feats = np.zeros((shape_e, cap, d), np.float64)
+    # in the rows' own type: the cast below gives the same values as a
+    # detour through float64, at half the bytes for float32 rows
+    feats = np.zeros((shape_e, cap, d), x.dtype)
     labels = np.zeros((shape_e, cap), np.float64)
     weights = np.zeros((shape_e, cap), np.float64)
     mask = np.zeros((shape_e, cap), np.float64)
@@ -681,24 +684,73 @@ def entity_shard_assignment(
 
 
 @dataclasses.dataclass(frozen=True)
+class RowExchangePlan:
+    """Static plan of the on-device row exchange between the CANONICAL
+    row partition (the first random effect's: labels, base offsets,
+    weights and every coordinate's scores live in it) and the partition
+    of another random effect, whose rows sit on other shards in another
+    order. Built once on the host from the two ``row_perm``s.
+
+    Shard p packs, for every destination q, the rows it holds that q
+    holds in the other order into a block of ``block_rows`` slots (the
+    largest (source, destination) count; the rest of a block is pad),
+    one ``all_to_all`` moves block (p, q) to shard q, and q reads every
+    row of its own order out of what it received by ONE gather through a
+    static inverse map (a gather costs a tenth of a scatter a row on the
+    chip: PERF.md section 6, PR 33). The way back runs the same blocks
+    in the other direction. Every index array is flat over the shards,
+    shard p's segment first, so a block split over the 'entity' mesh
+    axis hands each shard its own; -1 marks a pad (value 0).
+
+    send_to_owner:     (S * S * B,) canonical-local row of slot (q, j)
+    recv_at_owner:     (S * R_own,) slot p * B + j of what arrived, for
+                       each row of the owner's order
+    send_to_canonical: (S * S * B,) owner-local row of slot (p, j)
+    recv_at_canonical: (S * R_can,) slot q * B + j, for each canonical row
+    """
+
+    num_shards: int
+    block_rows: int
+    canonical_rows_per_shard: int
+    own_rows_per_shard: int
+    real_rows: int
+    send_to_owner: np.ndarray
+    recv_at_owner: np.ndarray
+    send_to_canonical: np.ndarray
+    recv_at_canonical: np.ndarray
+
+    @property
+    def canonical_padded_rows(self) -> int:
+        return self.num_shards * self.canonical_rows_per_shard
+
+    @property
+    def exchanged_rows(self) -> int:
+        """Slots one exchange moves, pads included, over all shards."""
+        return self.num_shards * self.num_shards * self.block_rows
+
+
+@dataclasses.dataclass(frozen=True)
 class EntityRowPartition:
     """Row-space permutation grouping batch rows by their entity's owner
     shard (entity-PARTITIONED rows — the device analog of the
     reference's ``RandomEffectIdPartitioner`` placement): shard p's rows
     sit in the contiguous block ``[p*R, (p+1)*R)``, padded with -1
     sentinel rows so every shard holds the same count. Applying the
-    permutation ONCE at setup keeps every per-row array of the descent
-    loop (labels, offsets, weights, scores, entity lanes) aligned with
-    the 'entity' mesh axis — the random-effect update then never
-    crosses shards.
+    permutation ONCE at setup keeps every per-row array of the
+    coordinate (features, entity lanes) aligned with the 'entity' mesh
+    axis — its update then solves and scores without crossing shards.
 
     row_perm: (padded_rows,) int64 permuted position -> original row
               (-1 = pad).
+    exchange: the :class:`RowExchangePlan` to and from the canonical
+              partition; None where this partition IS the canonical one
+              (its coordinate exchanges nothing).
     """
 
     num_shards: int
     rows_per_shard: int
     row_perm: np.ndarray
+    exchange: Optional[RowExchangePlan] = None
 
     @property
     def padded_rows(self) -> int:
@@ -708,11 +760,10 @@ class EntityRowPartition:
         """Permute one per-row array into the sharded order (pad rows
         carry ``fill``)."""
         column = np.asarray(column)
-        out = np.full(
-            (self.padded_rows,) + column.shape[1:], fill, column.dtype
-        )
-        real = self.row_perm >= 0
-        out[real] = column[self.row_perm[real]]
+        # one gather of whole rows, then the few pad rows overwritten: a
+        # masked assignment costs several times as much at 10^7 rows
+        out = np.take(column, np.maximum(self.row_perm, 0), axis=0)
+        out[self.row_perm < 0] = fill
         return out
 
     def restore(self, column: np.ndarray) -> np.ndarray:
@@ -725,56 +776,213 @@ class EntityRowPartition:
         return out
 
 
+def row_exchange_plan(
+    canonical: EntityRowPartition, own: EntityRowPartition
+) -> Optional[RowExchangePlan]:
+    """The exchange plan between ``canonical`` and ``own``, two partitions
+    of the same rows; None where they are the same partition."""
+    if canonical.num_shards != own.num_shards:
+        raise ValueError(
+            f"partitions of {canonical.num_shards} and {own.num_shards} "
+            "shards cannot exchange rows"
+        )
+    if np.array_equal(canonical.row_perm, own.row_perm):
+        return None
+    shards = canonical.num_shards
+    r_can, r_own = canonical.rows_per_shard, own.rows_per_shard
+    at_can = np.flatnonzero(canonical.row_perm >= 0)
+    at_own = np.flatnonzero(own.row_perm >= 0)
+    n = at_can.size
+    if at_own.size != n:
+        raise ValueError(
+            f"partitions hold {n} and {at_own.size} rows; an exchange "
+            "needs the same rows in both"
+        )
+    own_of_row = np.empty(n, np.int64)
+    own_of_row[own.row_perm[at_own]] = at_own
+    # in canonical order: the row's place in both partitions
+    own_pos = own_of_row[canonical.row_perm[at_can]]
+    src, dst = at_can // r_can, own_pos // r_own
+    pair = src * shards + dst
+    counts = np.bincount(pair, minlength=shards * shards)
+    block = max(int(counts.max(initial=0)), 1)
+    order = np.argsort(pair, kind="stable")
+    starts = np.concatenate([[0], np.cumsum(counts)])[:-1]
+    slot = np.empty(n, np.int64)
+    slot[order] = np.arange(n) - starts[pair[order]]
+    per_shard = shards * block
+    send_to_owner = np.full(shards * per_shard, -1, np.int32)
+    send_to_owner[src * per_shard + dst * block + slot] = at_can - src * r_can
+    recv_at_owner = np.full(own.padded_rows, -1, np.int32)
+    recv_at_owner[own_pos] = src * block + slot
+    send_to_canonical = np.full(shards * per_shard, -1, np.int32)
+    send_to_canonical[dst * per_shard + src * block + slot] = (
+        own_pos - dst * r_own
+    )
+    recv_at_canonical = np.full(canonical.padded_rows, -1, np.int32)
+    recv_at_canonical[at_can] = dst * block + slot
+    return RowExchangePlan(
+        num_shards=shards,
+        block_rows=block,
+        canonical_rows_per_shard=r_can,
+        own_rows_per_shard=r_own,
+        real_rows=int(n),
+        send_to_owner=send_to_owner,
+        recv_at_owner=recv_at_owner,
+        send_to_canonical=send_to_canonical,
+        recv_at_canonical=recv_at_canonical,
+    )
+
+
 def entity_partition_game_data(
-    data: GameData, random_effect: str, assignment: EntityShardAssignment
+    data: GameData,
+    random_effect: str,
+    assignment: EntityShardAssignment,
+    canonical: Optional[EntityRowPartition] = None,
+    feature_shards=None,
 ):
     """Permute a :class:`GameData` into the entity-partitioned row order
     of ``random_effect`` (rows grouped by their entity's owner shard,
     pad rows masked by zero weight): the ONE-time layout step of
-    entity-sharded GAME descent. Returns ``(permuted GameData,
-    EntityRowPartition)``. Dense and padded-ELL feature shards both
-    permute; other random effects' id columns ride along row-aligned
-    (but only ``random_effect`` is shard-local — a second entity-sharded
-    coordinate needs its own partition and therefore its own descent)."""
+    entity-sharded GAME descent, once a random effect. Returns
+    ``(permuted GameData, EntityRowPartition)``. Dense and padded-ELL
+    feature shards both permute (``feature_shards`` names the ones to
+    carry; all by default); every random effect's id column rides along
+    row-aligned.
+
+    Rows cannot be grouped by two entity types at once, so every
+    sharded random effect gets a partition of its own. The partition of
+    the FIRST one is the canonical row order: labels, base offsets,
+    weights, the fixed effect's batch and every coordinate's scores live
+    in it. A later random effect passes that partition as ``canonical``
+    and gets its own partition back with the static
+    :class:`RowExchangePlan` between the two attached
+    (``partition.exchange``; None where the two orders coincide): its
+    coordinate holds design, row features and entity lanes in ITS order
+    and moves residual offsets in and scores out through the plan, on
+    the device.
+
+    The whole step is one ``partition.entity_layout`` span carrying the
+    layout's counts: ``rows``, ``rows_per_shard``, ``padded_rows`` and,
+    with a plan, ``exchange_block_rows`` / ``exchange_real_rows``."""
     from photon_ml_tpu.ops.sparse import SparseFeatures, is_sparse, is_structured
 
-    part = entity_partition_rows(
-        data.entity_ids[random_effect], assignment
-    )
+    with obs.span(
+        "partition.entity_layout", cat="partition",
+        random_effect=random_effect, shards=assignment.num_shards,
+        entities=assignment.num_entities,
+    ) as sp:
+        part = entity_partition_rows(
+            data.entity_ids[random_effect], assignment
+        )
+        counted = dict(
+            rows=int(np.shape(data.labels)[0]),
+            rows_per_shard=part.rows_per_shard,
+            padded_rows=part.padded_rows,
+        )
+        if canonical is not None:
+            plan = row_exchange_plan(canonical, part)
+            part = dataclasses.replace(part, exchange=plan)
+            if plan is not None:
+                counted.update(
+                    exchange_block_rows=plan.block_rows,
+                    exchange_real_rows=plan.real_rows,
+                )
 
-    def permute_features(v):
-        if is_sparse(v):
-            ind = np.asarray(v.indices)
-            val = np.asarray(v.values)
-            out_i = np.full(
-                (part.padded_rows,) + ind.shape[1:], v.d, ind.dtype
-            )
-            out_v = np.zeros(
-                (part.padded_rows,) + val.shape[1:], val.dtype
-            )
-            real = part.row_perm >= 0
-            out_i[real] = ind[part.row_perm[real]]
-            out_v[real] = val[part.row_perm[real]]
-            return SparseFeatures(indices=out_i, values=out_v, d=v.d)
-        if is_structured(v):
-            raise ValueError(
-                "entity partitioning permutes dense or plain-ELL "
-                f"shards; got {type(v).__name__}"
-            )
-        return part.apply(v)
+        def permute_features(v):
+            if is_sparse(v):
+                return SparseFeatures(
+                    indices=part.apply(v.indices, fill=v.d),
+                    values=part.apply(v.values),
+                    d=v.d,
+                )
+            if is_structured(v):
+                raise ValueError(
+                    "entity partitioning permutes dense or plain-ELL "
+                    f"shards; got {type(v).__name__}"
+                )
+            return part.apply(v)
 
-    permuted = GameData(
-        features={
-            k: permute_features(v) for k, v in data.features.items()
-        },
-        labels=part.apply(data.labels),
-        offsets=part.apply(data.offsets),
-        weights=part.apply(data.weights),  # pad rows weight 0: masked out
-        entity_ids={
-            k: part.apply(v, fill=-1) for k, v in data.entity_ids.items()
-        },
-    )
+        permuted = GameData(
+            features={
+                k: permute_features(v)
+                for k, v in data.features.items()
+                if feature_shards is None or k in feature_shards
+            },
+            labels=part.apply(data.labels),
+            offsets=part.apply(data.offsets),
+            weights=part.apply(data.weights),  # pad rows weight 0: masked
+            entity_ids={
+                k: part.apply(v, fill=-1)
+                for k, v in data.entity_ids.items()
+            },
+        )
+        sp.set(**counted)
     return permuted, part
+
+
+class EntityShardLayout(NamedTuple):
+    """One sharded random effect's layout: the rows in its own order,
+    its entity -> shard assignment and its row partition (with the
+    exchange plan, for every random effect but the first)."""
+
+    data: GameData
+    assignment: EntityShardAssignment
+    partition: EntityRowPartition
+
+    def bucketed_design(
+        self, random_effect: str, shard: str, **design_options
+    ) -> BucketedRandomEffectDesign:
+        """:func:`build_bucketed_random_effect_design` over this
+        layout's rows, laid out on the HOST's device where the process
+        has one. The sharded coordinate regroups every bucket's lanes by
+        owner shard with numpy and puts each shard's block straight on
+        its chip: a design on the default device would sit whole on the
+        first chip only to be fetched back."""
+        try:
+            on_host = jax.default_device(jax.local_devices(backend="cpu")[0])
+        except RuntimeError:  # the process was given no host backend
+            on_host = contextlib.nullcontext()
+        with on_host:
+            return build_bucketed_random_effect_design(
+                self.data,
+                random_effect,
+                shard,
+                self.assignment.num_entities,
+                **design_options,
+            )
+
+
+def entity_shard_layouts(
+    data: GameData,
+    num_entities: Mapping[str, int],
+    num_shards: int,
+    feature_shards: Mapping[str, Collection[str]],
+) -> Dict[str, EntityShardLayout]:
+    """THE layout step of entity-sharded descent over any number of
+    random effects: one :func:`entity_partition_game_data` a random
+    effect of ``num_entities`` (random effect -> table rows), in its
+    order. The first one's partition is the canonical row order; its
+    ``data`` carries every feature shard (the fixed-effect batches and
+    whatever scores the whole model come from it). Every later one
+    carries the shards its coordinates read (``feature_shards``: random
+    effect -> shards) and the exchange plan."""
+    layouts: Dict[str, EntityShardLayout] = {}
+    canonical = None
+    for re_key, entities in num_entities.items():
+        assignment = entity_shard_assignment(entities, num_shards)
+        own, partition = entity_partition_game_data(
+            data,
+            re_key,
+            assignment,
+            canonical=canonical,
+            feature_shards=(
+                None if canonical is None else feature_shards[re_key]
+            ),
+        )
+        canonical = canonical or partition
+        layouts[re_key] = EntityShardLayout(own, assignment, partition)
+    return layouts
 
 
 def entity_partition_rows(

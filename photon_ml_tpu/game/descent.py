@@ -752,32 +752,39 @@ class CoordinateDescent:
         )
         history: List[CoordinateUpdateRecord] = []
         key = jax.random.PRNGKey(seed)
-        # Multi-process (multi-controller SPMD): every jit input must be
-        # a GLOBAL array. The data arrays arrive global from the caller
-        # (make_global_batch / make_global_re_design), but locally
-        # created state — the PRNG key and zero-initialized parameter
-        # tables — is a single-device process-local array that jit would
-        # reject; re-place it replicated over the data mesh.
-        if jax.process_count() > 1 and (
-            isinstance(self.labels, jax.Array)
-            and not self.labels.is_fully_addressable
-        ):
+        # Data over a mesh (an entity-sharded or batch-sharded descent, or
+        # multi-controller SPMD): the locally created state, the PRNG key
+        # and zero-initialized parameters, is a single-device array, while
+        # every pass gives both back REPLICATED over the data's mesh. Left
+        # so, the second pass sees other input types than the first and
+        # the whole fused pass is traced, lowered and compiled (or loaded)
+        # TWICE; multi-process, jit rejects a process-local input
+        # outright. Re-place it replicated over the data mesh.
+        mesh = getattr(getattr(self.labels, "sharding", None), "mesh", None)
+        if mesh is not None and mesh.size > 1:
             from jax.sharding import NamedSharding, PartitionSpec
 
-            rep = NamedSharding(self.labels.sharding.mesh, PartitionSpec())
+            rep = NamedSharding(mesh, PartitionSpec())
+            multi_process = jax.process_count() > 1
 
-            def _globalize(x):
-                if isinstance(x, jax.Array) and not x.is_fully_addressable:
-                    return x  # already global
-                return jax.device_put(np.asarray(x), rep)
+            def _on_the_mesh(x):
+                if isinstance(x, jax.Array) and (
+                    not x.is_fully_addressable
+                    if multi_process
+                    else len(x.sharding.device_set) > 1
+                ):
+                    return x  # already placed over the mesh
+                return jax.device_put(
+                    np.asarray(x) if multi_process else x, rep
+                )
 
             model = GameModel(
                 {
-                    n: jax.tree_util.tree_map(_globalize, p)
+                    n: jax.tree_util.tree_map(_on_the_mesh, p)
                     for n, p in model.params.items()
                 }
             )
-            key = _globalize(key)
+            key = _on_the_mesh(key)
         start_it = 0
         # divergence-guard casualties + caller-frozen coordinates (both
         # skip updates; both ride checkpoints)

@@ -49,7 +49,9 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "entities, buckets, bucket_caps, active_rows, active_slots, "
         "capped_entities, passive_rows) and counters (game.passes, "
         "game.updates, game.checkpoint.submit_ms, game.re.capped_entities, "
-        "game.re.passive_rows, game.table_write.inverse_gather, ...)",
+        "game.re.passive_rows, game.table_write.inverse_gather, "
+        "game.exchange.programs, ...) and the game.exchange.bytes_per_pass "
+        "gauge of an entity-sharded coordinate's row exchange",
     ),
     (
         "solver",
@@ -124,7 +126,9 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
     (
         "partition",
         r"partition\.[a-z_]+(\..+)?",
-        "multi-device partition layer: entity-shard layout spans, "
+        "multi-device partition layer: partition.entity_layout, one span a "
+        "sharded random effect (rows, rows_per_shard, padded_rows, "
+        "exchange_block_rows, exchange_real_rows), "
         "balanced-blocking stats, shard-skew drill events "
         "(docs/PARALLEL.md)",
     ),
