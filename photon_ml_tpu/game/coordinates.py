@@ -39,6 +39,8 @@ from photon_ml_tpu.core.types import LabeledBatch
 from photon_ml_tpu.game.data import (
     BucketedRandomEffectDesign,
     RandomEffectDesign,
+    gather_offsets_compact,
+    offsets_gather_maps,
 )
 from photon_ml_tpu.models.training import OptimizerType
 from photon_ml_tpu.ops.losses import loss_for_task
@@ -571,8 +573,11 @@ class RandomEffectUpdateSummary:
 
 
 def _make_multi_bucket_update(config: CoordinateConfig):
-    """ONE jitted call updating ALL buckets of a random effect: per bucket,
-    gather residual offsets and warm starts from the global table and
+    """ONE jitted call updating ALL buckets of a random effect: route the
+    residual offsets into every bucket's padded slots (one gather of the
+    held rows through the coordinate's static maps,
+    ``game.data.gather_offsets_compact``); per bucket, gather warm starts
+    from the global table and
     solve the bucket's entities in one vmapped call; then write every
     solution into the table at once, by a gather through the static
     entity -> lane map (``_lane_of_entity``): a table row reads its lane,
@@ -606,19 +611,27 @@ def _multi_bucket_update_body(config: CoordinateConfig):
 
     def update_all(
         table, reg_weights, full_offsets, entity_indices, lane_of_entity,
-        buckets, row_features, row_entities,
+        offsets_maps, buckets, row_features, row_entities,
     ):
         # runs while a coordinate's update is traced, never in a pass
         obs.registry().inc("game.table_write.inverse_gather")
+        obs.registry().inc("game.offsets_gather.compact")
+        # the residual offsets of every bucket: one index a held row and
+        # one a slot, where a gather a bucket paid one a PADDED slot
+        # (PERF.md section 6, PR 35)
+        with jax.named_scope("re_gather"), jax.named_scope("offsets"):
+            bucket_offsets = gather_offsets_compact(
+                full_offsets, offsets_maps, [b.mask for b in buckets]
+            )
         solved = []
         trackers = []
-        for eidx, bucket in zip(entity_indices, buckets):
+        for eidx, bucket, offsets in zip(
+            entity_indices, buckets, bucket_offsets
+        ):
             # every bucket warm-starts from the table as it came in: an
             # entity sits in at most one lane, so no bucket reads a row
             # that another wrote
             with jax.named_scope("re_gather"):
-                with jax.named_scope("offsets"):
-                    offsets = bucket.gather_offsets(full_offsets)
                 with jax.named_scope("warm_start"):
                     w0 = jnp.take(table, eidx, axis=0, mode="clip")
                 with jax.named_scope("reg_weight"):
@@ -675,6 +688,43 @@ def _lane_of_entity(entity_index, num_entities: int) -> np.ndarray:
     lane_of = np.full(num_entities, -1, np.int32)
     lane_of[entities] = position
     return lane_of
+
+
+def _offsets_gather_maps(shards):
+    """``game.data.offsets_gather_maps`` a shard of a coordinate (a plain
+    coordinate is one shard), each shard a list of its buckets' host
+    (row_index, mask), with the two numbers that say how far the compact
+    gather engages booked as gauges: the indices a pass gathers (held
+    rows and runs) beside the padded slots it fills."""
+    maps = [offsets_gather_maps(buckets) for buckets in shards]
+    reg = obs.registry()
+    reg.set_gauge(
+        "game.offsets_gather.gather_indices",
+        sum(perm.size + sum(s.size for s in starts) for perm, starts in maps),
+    )
+    reg.set_gauge(
+        "game.offsets_gather.padded_slots",
+        sum(np.size(m) for buckets in shards for _, m in buckets),
+    )
+    return maps
+
+
+def _design_offsets_maps(design: BucketedRandomEffectDesign):
+    """The offsets-gather maps of an unsharded bucketed design, on the
+    device. A bucket that is a global array over several processes
+    (``parallel.multihost.make_global_re_design``) is fetched replicated
+    first, so every process derives the same maps."""
+    from photon_ml_tpu.parallel.multihost import fetch_replicated
+
+    host = [
+        tuple(
+            np.asarray(fetch_replicated(a)) for a in (b.row_index, b.mask)
+        )
+        for b in design.buckets
+    ]
+    return jax.tree_util.tree_map(
+        jnp.asarray, _offsets_gather_maps([host])[0]
+    )
 
 
 def _score_rows_by_entity(table, feats, ents):
@@ -745,6 +795,9 @@ class RandomEffectCoordinate:
         self._lane_of_entity = jnp.asarray(
             _lane_of_entity(design.entity_index, design.num_entities)
         )
+        # static for the coordinate's life, like the lane map: the rows
+        # the offsets gather reads and where every slot's run starts
+        self._offsets_maps = _design_offsets_maps(design)
         # static per-bucket masks of real (non-sharding-pad) lanes
         self._valid_lanes = [
             np.asarray(ei) < design.num_entities
@@ -798,6 +851,7 @@ class RandomEffectCoordinate:
             self.full_offsets_base + partial_scores,
             self._entity_indices,
             self._lane_of_entity,
+            self._offsets_maps,
             tuple(self.design.buckets),
             self.row_features,
             self.row_entities,
@@ -822,6 +876,7 @@ class RandomEffectCoordinate:
             self.full_offsets_base,
             self._entity_indices,
             self._lane_of_entity,
+            self._offsets_maps,
             tuple(self.design.buckets),
             self.row_features,
             self.row_entities,
@@ -839,7 +894,8 @@ class RandomEffectCoordinate:
         SAME-OBJECT CONTRACT (see the fixed-effect counterpart): only
         the freshly-built per-entity weight vector may vary per call;
         the design buckets, row features, entity indices, the entity ->
-        lane map and offsets must be the SAME objects every time so
+        lane map, the offsets-gather maps and offsets must be the SAME
+        objects every time so
         run_grid broadcasts them instead of stacking n_combo copies of
         the dataset."""
         if not getattr(self, "_uniform_reg", True):
@@ -855,6 +911,7 @@ class RandomEffectCoordinate:
             self.full_offsets_base,
             self._entity_indices,
             self._lane_of_entity,
+            self._offsets_maps,
             tuple(self.design.buckets),
             self.row_features,
             self.row_entities,
@@ -869,6 +926,7 @@ class RandomEffectCoordinate:
             c.full_offsets_base,
             c._entity_indices,
             c._lane_of_entity,
+            c._offsets_maps,
             buckets,
             c.row_features,
             c.row_entities,
@@ -1047,6 +1105,7 @@ class EntityShardedRandomEffectCoordinate:
         g2s = assignment.global_to_stored
         buckets = []
         eidx_local = []
+        held_rows = []  # a bucket's regrouped (row_index, mask), host
         self._valid_lanes = []
         self._lane_entities = []
         for bucket, eidx in zip(design.buckets, design.entity_index):
@@ -1095,12 +1154,14 @@ class EntityShardedRandomEffectCoordinate:
                 ri_new - shard_of_lane[:, None] * r_rows,
                 -1,
             ).astype(np.int32)
+            mask_new = regroup(bucket.mask)
+            held_rows.append((ri_local, mask_new))
             buckets.append(
                 RandomEffectDesign(
                     features=place(regroup(bucket.features)),
                     labels=place(regroup(bucket.labels)),
                     weights=place(regroup(bucket.weights)),
-                    mask=place(regroup(bucket.mask)),
+                    mask=place(mask_new),
                     row_index=place(ri_local),
                 )
             )
@@ -1113,8 +1174,36 @@ class EntityShardedRandomEffectCoordinate:
             glob[real] = assignment.stored_to_global[new_stored[real]]
             self._lane_entities.append(glob.astype(np.int32))
         self._buckets = tuple(buckets)
+        # the offsets-gather maps a shard, from ITS lanes' block of every
+        # bucket; a shard's rows to gather padded (row 0, past every run)
+        # to the longest shard's
+        shard_maps = _offsets_gather_maps(
+            [
+                [
+                    tuple(np.split(a, n_shards)[p] for a in held)
+                    for held in held_rows
+                ]
+                for p in range(n_shards)
+            ]
+        )
+        longest = max(perm.size for perm, _ in shard_maps)
+        offsets_maps = (
+            place(
+                np.concatenate(
+                    [
+                        np.pad(perm, (0, longest - perm.size))
+                        for perm, _ in shard_maps
+                    ]
+                )
+            ),
+            tuple(
+                place(np.concatenate([starts[b] for _, starts in shard_maps]))
+                for b in range(len(buckets))
+            ),
+        )
         # (every bucket's lanes, their shard-local inverse: block p maps
-        # the rows of ITS table block to the concatenation of ITS lanes)
+        # the rows of ITS table block to the concatenation of ITS lanes,
+        # the offsets-gather maps)
         self._entity_indices = (
             tuple(place(li) for li in eidx_local),
             place(
@@ -1131,6 +1220,7 @@ class EntityShardedRandomEffectCoordinate:
                     ]
                 )
             ),
+            offsets_maps,
         )
 
         # per-row scoring inputs, shard-local entity rows

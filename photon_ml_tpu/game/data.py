@@ -139,9 +139,87 @@ class RandomEffectDesign:
     def gather_offsets(self, full_offsets: jax.Array) -> jax.Array:
         """(n,) -> (E, R): route each row's current residual offset to its
         active slot. The reference does this with an RDD join per pass
-        (``data/RandomEffectDataSet.scala:58-75``); here it is one gather."""
+        (``data/RandomEffectDataSet.scala:58-75``); here it is one gather.
+
+        The plain DEFINITION, an index a padded slot. The coordinates
+        deliver the same values through :func:`gather_offsets_compact`,
+        an index a held row."""
         safe = jnp.maximum(self.row_index, 0)
         return jnp.take(full_offsets, safe, axis=0) * self.mask
+
+
+def offsets_gather_maps(buckets):
+    """Static maps of :func:`gather_offsets_compact` over the buckets of
+    one random effect, from each bucket's host ``(row_index, mask)``:
+    ``(perm, starts)``, int32.
+
+    ``perm`` lists the rows to gather bucket by bucket, SLOT-major: slot
+    j of a bucket contributes the rows its lanes ``lo_j .. hi_j`` hold
+    there, ``lo_j`` / ``hi_j`` the first and last lane holding slot j.
+    Lanes in an order monotone in their row count (the bucketed builder's,
+    and a shard's block of it) make every such range exactly the lanes
+    that hold the slot, so ``perm`` has one entry a held row; a lane
+    inside the range that does not reach the slot costs one wasted index
+    (row 0, masked), which is all an unordered design pays. ``starts[b]``
+    is (R_b,): slot j's range, read as ``E_b`` consecutive entries of the
+    gathered vector padded by ``max_b E_b`` at both ends, starting at
+    ``starts[b][j]``, puts lane e's value at position e.
+
+    Raises where a lane's held slots are not a prefix of its slots (what
+    ``_fill_design`` builds) or a held slot names no row."""
+    buckets = [(np.asarray(ri), np.asarray(m) > 0) for ri, m in buckets]
+    pad = max(held.shape[0] for _, held in buckets)
+    perm, starts, position = [], [], pad
+    for b, (row_index, held) in enumerate(buckets):
+        holes = (held[:, 1:] & ~held[:, :-1]).any(axis=1)
+        if holes.any():
+            raise ValueError(
+                f"bucket {b}: the held slots of lane "
+                f"{int(np.flatnonzero(holes)[0])} are not a prefix of its "
+                "slots; the offsets gather needs every lane's rows in "
+                "slots 0 .. count - 1"
+            )
+        if np.any(row_index[held] < 0):
+            raise ValueError(
+                f"bucket {b}: a slot the mask holds has no row (row_index -1)"
+            )
+        lanes = held.shape[0]
+        held_t = held.T  # (R_b, E_b)
+        some = held_t.any(axis=1)
+        lo = np.where(some, held_t.argmax(axis=1), 0)
+        hi = np.where(some, lanes - held_t[:, ::-1].argmax(axis=1), 0)
+        lane = np.arange(lanes)
+        in_range = (lane >= lo[:, None]) & (lane < hi[:, None])
+        perm.append(np.maximum(row_index.T[in_range], 0))
+        first = position + np.cumsum(hi - lo) - (hi - lo)
+        starts.append((first - lo).astype(np.int32))
+        position += int(np.sum(hi - lo))
+    return np.concatenate(perm).astype(np.int32), tuple(starts)
+
+
+def gather_offsets_compact(full_offsets, maps, masks):
+    """``[bucket.gather_offsets(full_offsets) for bucket in buckets]``, the
+    same values slot for slot, with ``maps = offsets_gather_maps(...)`` of
+    those buckets and ``masks`` their (E_b, R_b) masks: ONE gather of an
+    index a held row, then every slot of every bucket filled by one
+    contiguous run of the gathered vector (what a run reads beyond the
+    lanes that hold its slot lands on masked slots). XLA's gather on the
+    TPU is paid by the index, not the byte, and a run by the run, not the
+    lane: about 7 ns an index and 1 to 2 us a run, at most a bucket's
+    depth of them (PERF.md section 6, PR 35); about half of a bucketed
+    design's padded slots hold no row."""
+    perm, starts = maps
+    pad = max(m.shape[0] for m in masks)
+    gathered = jnp.pad(jnp.take(full_offsets, perm, axis=0, mode="clip"),
+                       (pad, pad))
+    out = []
+    for start, mask in zip(starts, masks):
+        lanes = mask.shape[0]
+        runs = jax.vmap(
+            lambda at: jax.lax.dynamic_slice_in_dim(gathered, at, lanes)
+        )(start)  # (R_b, E_b)
+        out.append(runs.T * mask)
+    return out
 
 
 def _grouped_rows(eids: np.ndarray, seed: int):

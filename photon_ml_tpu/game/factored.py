@@ -36,10 +36,15 @@ import jax.numpy as jnp
 import numpy as np
 
 from photon_ml_tpu.core.types import _pytree_dataclass
-from photon_ml_tpu.game.coordinates import CoordinateConfig, _make_solve
+from photon_ml_tpu.game.coordinates import (
+    CoordinateConfig,
+    _design_offsets_maps,
+    _make_solve,
+)
 from photon_ml_tpu.game.data import (
     BucketedRandomEffectDesign,
     RandomEffectDesign,
+    gather_offsets_compact,
 )
 from photon_ml_tpu.models.training import OptimizerType
 from photon_ml_tpu.ops.losses import loss_for_task
@@ -175,6 +180,7 @@ class FactoredRandomEffectCoordinate:
                 num_entities=design.num_entities,
             )
         self.design = design
+        self._offsets_maps = _design_offsets_maps(design)
         self.row_features = row_features
         self.row_entities = row_entities
         self.full_offsets_base = full_offsets_base
@@ -230,9 +236,9 @@ class FactoredRandomEffectCoordinate:
     ) -> Tuple[FactoredParams, object]:
         design = self.design
         full_offsets = self.full_offsets_base + partial_scores
-        bucket_offsets = [
-            b.gather_offsets(full_offsets) for b in design.buckets
-        ]
+        bucket_offsets = gather_offsets_compact(
+            full_offsets, self._offsets_maps, [b.mask for b in design.buckets]
+        )
         gamma, b = params.gamma, params.projection
         lam_re = jnp.full(
             (design.num_entities,), self.config.reg_weight, gamma.dtype
@@ -290,6 +296,7 @@ class FactoredRandomEffectCoordinate:
         designs)."""
         return (
             tuple(self.design.buckets),
+            self._offsets_maps,
             self.row_features,
             self.row_entities,
             self.full_offsets_base,
@@ -301,6 +308,7 @@ class FactoredRandomEffectCoordinate:
         c = copy.copy(self)
         (
             buckets,
+            c._offsets_maps,
             c.row_features,
             c.row_entities,
             c.full_offsets_base,

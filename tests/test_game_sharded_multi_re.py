@@ -507,6 +507,60 @@ def test_exchange_counters():
         2 * plan.exchanged_rows * 8)
 
 
+# -- (d2) the offsets-gather maps a shard -------------------------------------
+
+
+@pytest.mark.parametrize("n_shards", [2, 4])
+def test_offsets_gather_maps_a_shard(n_shards):
+    """A shard gathers the residual offsets of ITS held rows through its
+    block of the maps; the shards hold unequal numbers of rows, so the
+    shorter ones' blocks are padded to the longest's. (That the descent
+    over these coordinates equals the unsharded one at both widths is
+    ``test_both_tables_sharded_match_the_unsharded_descent``.)"""
+    from photon_ml_tpu.game.data import gather_offsets_compact
+
+    _, coords, _, _ = build_sharded(n_shards)
+    for name in TABLES:
+        coord = coords[name]
+        perm, starts = coord._entity_indices[2]
+        blocks = lambda a: np.split(np.asarray(a), n_shards)
+        held = [
+            sum(int((blocks(b.mask)[p] > 0).sum()) for b in coord._buckets)
+            for p in range(n_shards)
+        ]
+        assert len(set(held)) > 1, held
+        assert perm.shape == (n_shards * max(held),)
+        assert [s.shape for s in starts] == [
+            (n_shards * b.rows_per_entity,) for b in coord._buckets]
+        rows = coord.partition.rows_per_shard
+        full = np.random.default_rng(5).normal(size=n_shards * rows)
+        for p in range(n_shards):
+            local = jnp.asarray(blocks(full)[p])
+            got = gather_offsets_compact(
+                local,
+                (blocks(perm)[p], tuple(blocks(s)[p] for s in starts)),
+                [jnp.asarray(blocks(b.mask)[p]) for b in coord._buckets],
+            )
+            for g, b in zip(got, coord._buckets):
+                shard = dataclasses.replace(
+                    b, **{f.name: jnp.asarray(blocks(getattr(b, f.name))[p])
+                          for f in dataclasses.fields(b)})
+                np.testing.assert_array_equal(
+                    np.asarray(g), np.asarray(shard.gather_offsets(local)))
+        # the maps ride the fused state beside the lanes, as one object
+        state = coord.fused_state()
+        assert state[2] is coord._entity_indices
+        restored = coord.with_fused_state(state)
+        assert restored._entity_indices[2][0] is perm
+        table = coord.initial_params() + 0.25
+        partial = jax.device_put(
+            jnp.asarray(full[:coord.full_offsets_base.shape[0]]),
+            coord.full_offsets_base.sharding)
+        for g, w in zip(jtu.tree_leaves(restored.update_step(table, partial)),
+                        jtu.tree_leaves(coord.update_step(table, partial))):
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
 # -- (e) one update body -----------------------------------------------------
 
 

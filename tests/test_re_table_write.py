@@ -206,7 +206,7 @@ def test_lowered_update_holds_no_scatter_into_the_table(num_buckets):
     table, partial = warm_table_and_scores(coord, jnp.float32)
     text = coord._update_all.lower(
         table, coord.reg_weights, coord.full_offsets_base + partial,
-        coord._entity_indices, coord._lane_of_entity,
+        coord._entity_indices, coord._lane_of_entity, coord._offsets_maps,
         tuple(coord.design.buckets), coord.row_features, coord.row_entities,
     ).as_text()
     table_type = f"tensor<{N_USERS}x{DIMS['per_user']}xf32>"
