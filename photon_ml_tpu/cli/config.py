@@ -346,9 +346,17 @@ class CoordinateSpec:
     # latent-matrix sub-config of the reference's triple-config string)
     latent_dim: Optional[int] = None
     num_inner_iterations: int = 1
+    # the latent-matrix sub-config's own optimizer ("re-config;latent-config;
+    # mf-config": the shared B is a GLM of its own): LBFGS | TRON; default:
+    # the coordinate's optimizer
+    latent_optimizer: Optional[str] = None
     latent_reg_weight: Optional[float] = None  # default: reg weight
     latent_max_iters: Optional[int] = None  # default: max_iters
     latent_tolerance: Optional[float] = None  # default: tolerance
+    # TRON's CG iterations an outer iteration of the latent-matrix solve
+    # (default: the solver's 20); under latent_tolerance 0 every CG runs
+    # exactly this many Hessian-vector passes
+    latent_max_cg: Optional[int] = None
     # fixed-effect coordinates on a SPARSE shard: densify the N hottest
     # columns into the MXU slab (-1 = auto), ops.sparse.to_hybrid applied
     # coordinate-locally (the row permutation never leaves the coordinate)
@@ -569,6 +577,12 @@ class GameDriverParams:
                     "effects need dense per-row features (EXCEPT a "
                     "random effect with projector INDEX_MAP, which "
                     "solves in each entity's compact column space)"
+                )
+            if spec.latent_optimizer is not None and spec.latent_dim is None:
+                raise ValueError(
+                    f"coordinate {name!r}: latent_optimizer is the "
+                    "factored coordinate's latent-matrix optimizer; set "
+                    "latent_dim to factor the random effect"
                 )
             if spec.hot_columns and (entityish or not uses_sparse):
                 raise ValueError(

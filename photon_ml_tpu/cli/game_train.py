@@ -370,6 +370,11 @@ def build_coordinates(
                     )
                 latent_cfg = dataclasses.replace(
                     cfg,
+                    optimizer=(
+                        OptimizerType[spec.latent_optimizer]
+                        if spec.latent_optimizer is not None
+                        else cfg.optimizer
+                    ),
                     reg_weight=(
                         spec.latent_reg_weight
                         if spec.latent_reg_weight is not None
@@ -385,6 +390,7 @@ def build_coordinates(
                         if spec.latent_tolerance is not None
                         else cfg.tolerance
                     ),
+                    tron_max_cg=spec.latent_max_cg,
                 )
                 coords[name] = FactoredRandomEffectCoordinate(
                     design=design,

@@ -81,12 +81,23 @@ class CoordinateConfig:
     # state; the fleet summaries (reason/iterations/final grad norm)
     # don't need it.
     track_states: bool = False
+    # TRON only: the inner CG's iteration budget an outer iteration
+    # (None: the solver's, 20). Under a tolerance of zero the budget is the
+    # rule (``solvers.tron``): every CG then runs exactly this many
+    # Hessian-vector passes unless it reaches the trust-region boundary,
+    # so a budgeted solve states it like ``max_iters``.
+    tron_max_cg: Optional[int] = None
 
     def solver_config(self) -> SolverConfig:
+        budget = (
+            {} if self.tron_max_cg is None
+            else {"tron_max_cg": self.tron_max_cg}
+        )
         return SolverConfig(
             max_iters=self.max_iters,
             tolerance=self.tolerance,
             track_states=self.track_states,
+            **budget,
         )
 
 

@@ -26,7 +26,7 @@ from photon_ml_tpu import obs
 from photon_ml_tpu.core.tasks import TaskType
 from photon_ml_tpu.resilience import faults as _faults
 from photon_ml_tpu.ops import metrics as metrics_mod
-from photon_ml_tpu.solvers.common import ConvergenceReason
+from photon_ml_tpu.solvers.common import ConvergenceReason, reason_histogram
 
 
 @dataclasses.dataclass
@@ -66,6 +66,15 @@ class CoordinateUpdateRecord:
     # succeeded, "frozen" when the retry also failed and the coordinate
     # was excluded from further training (docs/ROBUSTNESS.md)
     event: Optional[str] = None
+    # factored coordinates only: the reference's array of (random effect,
+    # latent matrix) trackers, one dict an inner iteration
+    # (``game.factored.FactoredUpdateSummary.history_decode``): "lanes"
+    # (count, solver_iterations, convergence_histogram over every lane of
+    # every bucket) and "projection" (the shared-B solve's iterations,
+    # cg_iterations, passes over the design, reason, grad_norm).
+    # ``solver_iterations`` / ``convergence_histogram`` above are the LAST
+    # inner iteration's lanes.
+    inner_iterations: Optional[List[dict]] = None
 
 
 def _coordinate_reg_term(coord, params) -> jax.Array:
@@ -98,6 +107,7 @@ def _history_record(
     seconds,
     validation_metric=None,
     event=None,
+    inner_iterations=None,
 ) -> CoordinateUpdateRecord:
     """THE record builder both the sequential drain and the grid sweep
     use — one place for the reason histogram / solver-iteration
@@ -111,13 +121,11 @@ def _history_record(
         seconds=seconds,
         validation_metric=validation_metric,
         event=event,
+        inner_iterations=inner_iterations,
         solver_iterations=(
             float(np.mean(iters_arr)) if iters_arr.size else 0.0
         ),
-        convergence_histogram={
-            ConvergenceReason(int(r)).name: int(c)
-            for r, c in zip(*np.unique(reasons, return_counts=True))
-        },
+        convergence_histogram=reason_histogram(reasons),
     )
 
 
@@ -138,6 +146,18 @@ def _record_update_metrics(rec: CoordinateUpdateRecord) -> None:
         reg.inc("resilience.rollbacks")
     elif rec.event == "frozen":
         reg.inc("resilience.frozen_coordinates")
+    if rec.inner_iterations is not None:
+        solves = [i["projection"] for i in rec.inner_iterations]
+        reg.inc("game.factored.updates")
+        reg.inc("game.factored.inner_iterations", len(solves))
+        reg.inc(
+            "game.factored.projection_passes",
+            sum(s["passes"] for s in solves),
+        )
+        reg.inc(
+            "game.factored.projection_cg_iterations",
+            sum(s["cg_iterations"] for s in solves),
+        )
 
 
 def _normalize_fuse_passes(fp):
@@ -872,7 +892,11 @@ class CoordinateDescent:
             for p in pending:
                 r = p["result"]
                 raw = getattr(r, "pending", None)
-                if raw is not None:
+                if hasattr(r, "history_fetch"):
+                    # a summary that brings its own tracker tree (the
+                    # factored coordinate's) and decodes it below
+                    fetch.append((p["objective"], r.history_fetch()))
+                elif raw is not None:
                     # lazy RandomEffectUpdateSummary: per-bucket device
                     # (reason, iterations, final grad norm); valid-lane
                     # masks and entity indices are host-side
@@ -918,7 +942,13 @@ class CoordinateDescent:
             for p, (obj, tr) in zip(pending, host):
                 result = p.pop("result")
                 raw = getattr(result, "pending", None)
-                if raw is not None:
+                inner_iterations = None
+                if hasattr(result, "history_decode"):
+                    (
+                        reason, iterations, grad_norms, entity_ids,
+                        inner_iterations,
+                    ) = result.history_decode(tr)
+                elif raw is not None:
                     valid = [v for _, _, _, v, _ in raw]
                     reason = np.concatenate(
                         [
@@ -962,6 +992,7 @@ class CoordinateDescent:
                     p["seconds"],
                     p["validation_metric"],
                     p.get("event"),
+                    inner_iterations,
                 )
                 history.append(rec)
                 _record_update_metrics(rec)

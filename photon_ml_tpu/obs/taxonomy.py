@@ -50,7 +50,10 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "capped_entities, passive_rows) and counters (game.passes, "
         "game.updates, game.checkpoint.submit_ms, game.re.capped_entities, "
         "game.re.passive_rows, game.table_write.inverse_gather, "
-        "game.offsets_gather.compact, game.exchange.programs, ...), the "
+        "game.offsets_gather.compact, game.exchange.programs, "
+        "game.factored.updates / .inner_iterations / .projection_passes / "
+        ".projection_cg_iterations of a factored coordinate's tracker, "
+        "...), the "
         "game.offsets_gather.gather_indices / .padded_slots gauges of a "
         "random-effect coordinate's residual-offset gather and the "
         "game.exchange.bytes_per_pass gauge of an entity-sharded "

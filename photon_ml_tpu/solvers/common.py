@@ -158,6 +158,15 @@ def mask_tape(tape, iterations, axis: int = -1) -> np.ndarray:
     return np.where(idx <= lim, arr, np.nan)
 
 
+def reason_histogram(reasons) -> dict:
+    """ConvergenceReason name -> count over an array of reason codes: the
+    form every history record's ``convergence_histogram`` takes."""
+    return {
+        ConvergenceReason(int(r)).name: int(c)
+        for r, c in zip(*np.unique(np.asarray(reasons), return_counts=True))
+    }
+
+
 def index_result(result: "SolverResult", i) -> "SolverResult":
     """Element ``i`` of a STACKED SolverResult — the decode reader for
     results whose leaves carry a leading batch/path axis (a vmapped

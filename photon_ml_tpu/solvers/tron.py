@@ -6,7 +6,8 @@ reference fixes at ``TRON.scala:97-98,230-237``):
 
   - trust-region acceptance thresholds (eta0, eta1, eta2) = (1e-4, .25, .75)
   - radius update factors (sigma1, sigma2, sigma3) = (.25, .5, 4)
-  - inner CG: <= 20 iterations, tolerance 0.1 * ||g||
+  - inner CG: <= 20 iterations, tolerance 0.1 * ||g|| (under a solver
+    tolerance of zero the residual test is off: the budget is the rule)
   - <= 5 consecutive improvement failures, then give up
   - defaults maxIter 15, tol 1e-5 (gradient-based)
 
@@ -52,6 +53,17 @@ _SIGMA1, _SIGMA2, _SIGMA3 = 0.25, 0.5, 4.0
 
 TRON_DEFAULT_CONFIG = SolverConfig(max_iters=15, tolerance=1e-5)
 
+# Under a tolerance of zero (the budget is the rule) the ratio test gets
+# the room the objective's own sum needs, as Newton's Armijo test has
+# (``newton._ARMIJO_ROUNDING_ULPS``): a step whose PREDICTED reduction is
+# under this many ulps of the value cannot be judged by evaluating the
+# value twice, so it is taken and the radius is left alone. Without it the
+# late outer iterations of a budgeted solve accept or reject on rounding,
+# a rejection shrinks the radius, and the next CG ends on the boundary
+# after one pass where it would have made its whole budget (61, 52 and 42
+# CG iterations for 80 in one descent: PERF.md section 6, PR 36).
+_RATIO_ROUNDING_ULPS = 1024.0
+
 
 class _CGState(NamedTuple):
     step: jax.Array  # current solution s
@@ -73,6 +85,8 @@ def _truncated_cg(
 
     Returns (s, r). Exits on residual < cg_tol_factor * ||grad||, on hitting
     the trust-region boundary (step clipped to the sphere), or on max_cg.
+    A ``cg_tol_factor`` of zero leaves the boundary and ``max_cg`` (and a
+    residual of exactly zero, where there is nothing left to solve).
     """
     cg_tol = cg_tol_factor * jnp.linalg.norm(grad)
 
@@ -179,6 +193,17 @@ def minimize_tron(
     all. Requires ``hvp_at_fn``; takes precedence over ``hvp_setup_fn``."""
     dtype = w0.dtype
     use_vgc = vgc_fn is not None and hvp_at_fn is not None
+    # A tolerance of zero asks for the whole budget, of the inner CG as of
+    # the outer loop (``check_convergence``): the CG's relative residual
+    # test is a comparison of two sums over the data, and where a residual
+    # falls near 0.1 ||g|| the order of the rows, or which rows of a capped
+    # entity were sampled, decides whether one more Hessian-vector pass is
+    # made (the factored coordinate's projection solve made 141, 148 and
+    # 146 passes a job on three seeds of one problem: PERF.md section 6,
+    # PR 36). With the test off a CG runs ``tron_max_cg`` iterations unless
+    # it reaches the trust-region boundary.
+    budget_is_the_rule = not config.tolerance > 0.0
+    cg_tol_factor = 0.0 if budget_is_the_rule else config.tron_cg_tol
     if use_vgc:
         v0, g0, c0 = vgc_fn(w0)
     else:
@@ -232,7 +257,7 @@ def minimize_tron(
             s.grad,
             s.delta,
             config.tron_max_cg,
-            config.tron_cg_tol,
+            cg_tol_factor,
         )
         snorm = jnp.linalg.norm(step)
         gs = jnp.vdot(s.grad, step)
@@ -256,6 +281,7 @@ def minimize_tron(
             s.iteration == 0, jnp.minimum(s.delta, snorm), s.delta
         )
         alpha_snorm = alpha_c * snorm
+        delta_in = delta
         delta = jnp.where(
             actred < _ETA0 * prered,
             jnp.minimum(jnp.maximum(alpha_snorm, _SIGMA1 * snorm), _SIGMA2 * delta),
@@ -271,6 +297,13 @@ def minimize_tron(
         )
 
         accept = actred > _ETA0 * prered
+        if budget_is_the_rule:
+            slack = _RATIO_ROUNDING_ULPS * jnp.finfo(dtype).eps * jnp.abs(
+                s.value
+            )
+            unresolved = prered <= slack
+            accept = accept | (unresolved & (actred >= -slack))
+            delta = jnp.where(unresolved, delta_in, delta)
         w_new = jnp.where(accept, w_try, s.w)
         v_new = jnp.where(accept, v_try, s.value)
         g_new = jnp.where(accept, g_try, s.grad)

@@ -23,6 +23,19 @@ order, each ``c`` a dict with ``name``, ``kind`` ("fixed" | "random"), ``x``
 (n, d), ``l2`` and, for a random effect, ``ids`` (n,), ``entities`` (table
 rows) and ``sample``.  Parameters are a dict name -> (d,) or (entities, d).
 
+A FACTORED random effect (kind "factored", ``FactoredRandomEffectCoordinate
+.scala:37-267``) keeps w_e = B gamma_e: parameters ``{"gamma": (entities, k),
+"projection": (d, k)}``, ``score(i) = (B^T x_i) . gamma[id_i]``, penalty
+``l2 / 2 |gamma|^2 + l2_projection / 2 |B|^2``, both leaves trained on the
+same rows and weights as a plain random effect.  ``factored_update`` is its
+update as the reference describes it: the per-entity problems on explicitly
+projected rows, then the B problem on the MATERIALISED Kronecker design
+x_i (x) gamma[id_i] of shape (n, d k) with vec(B) as its coefficient vector
+(``kroneckerProductFeaturesAndCoefficients``, ``:251-266``), each solved by
+plain Newton to convergence.  Departure: the program runs a budgeted NEWTON
+on the lanes and TRON on B; both problems are strictly convex under their
+L2, so the two agree where both are run to convergence, and only there.
+
 Departures from Photon-ML, as in the program: Newton with the explicit
 d x d Hessian where the reference runs TRON (on a 16-wide Hessian Newton is
 TRON's step solved exactly), and Armijo halving for the damping.  With
@@ -58,6 +71,10 @@ def score(coord, params, dtype):
     x = jnp.asarray(coord["x"], dtype)
     if coord["kind"] == "fixed":
         return jnp.sum(x * jnp.asarray(params, dtype), axis=1, dtype=dtype)
+    if coord["kind"] == "factored":
+        latent = (x @ jnp.asarray(params["projection"], dtype)).astype(dtype)
+        rows = jnp.asarray(params["gamma"], dtype)[np.asarray(coord["ids"])]
+        return jnp.sum(latent * rows, axis=1, dtype=dtype)
     rows = jnp.asarray(params, dtype)[np.asarray(coord["ids"])]
     return jnp.sum(x * rows, axis=1, dtype=dtype)
 
@@ -70,17 +87,26 @@ def objective(problem, params, dtype=jnp.float64):
                 for c in problem["coordinates"])
         value = jnp.sum(_loss(z, y), dtype=dtype)
         for c in problem["coordinates"]:
-            p = jnp.asarray(params[c["name"]], dtype)
-            value = value + jnp.asarray(0.5 * c["l2"], dtype) * jnp.sum(
-                p * p, dtype=dtype)
+            for weight, p in _penalised(c, params[c["name"]]):
+                p = jnp.asarray(p, dtype)
+                value = value + jnp.asarray(0.5 * weight, dtype) * jnp.sum(
+                    p * p, dtype=dtype)
         return value
+
+
+def _penalised(coord, params):
+    """[(l2 weight, array)] of a coordinate's parameters."""
+    if coord["kind"] == "factored":
+        return [(coord["l2"], params["gamma"]),
+                (coord["l2_projection"], params["projection"])]
+    return [(coord["l2"], params)]
 
 
 def train_weights(coord, n):
     """(n,) weight of each row in the objective this coordinate is trained
     on: 1, or the sample's weight, or 0 for a passive row."""
     w = np.ones(n)
-    if coord["kind"] == "random":
+    if coord["kind"] != "fixed":
         ids = np.asarray(coord["ids"])
         for entity, (rows, weights) in coord["sample"].items():
             w[ids == entity] = 0.0
@@ -99,8 +125,19 @@ def gradients(problem, params, dtype=jnp.float64):
         out = {}
         for c in problem["coordinates"]:
             x = jnp.asarray(c["x"], dtype)
-            p = jnp.asarray(params[c["name"]], dtype)
             r = (_d1(z, y) * jnp.asarray(train_weights(c, n), dtype))[:, None]
+            if c["kind"] == "factored":
+                ids = np.asarray(c["ids"])
+                gamma = jnp.asarray(params[c["name"]]["gamma"], dtype)
+                b = jnp.asarray(params[c["name"]]["projection"], dtype)
+                out[c["name"]] = {
+                    "gamma": jnp.zeros(gamma.shape, dtype).at[ids].add(
+                        (x @ b) * r) + jnp.asarray(c["l2"], dtype) * gamma,
+                    "projection": x.T @ (gamma[ids] * r) + jnp.asarray(
+                        c["l2_projection"], dtype) * b,
+                }
+                continue
+            p = jnp.asarray(params[c["name"]], dtype)
             if c["kind"] == "fixed":
                 g = jnp.sum(x * r, axis=0, dtype=dtype)
             else:
@@ -150,6 +187,52 @@ def newton(x, y, offsets, weights, w0, l2, iterations, dtype):
             break
         w, value, grad, z = trial, t_value, t_grad, t_z
     return w
+
+
+CONVERGED = 40  # damped Newton steps: far past quadratic convergence
+
+
+def kronecker_design(x, gamma_rows):
+    """(n, d k): row i is x_i (x) gamma_i, so that its dot with vec(B)
+    (row-major over (d, k)) is (B^T x_i) . gamma_i."""
+    n = x.shape[0]
+    return jnp.einsum("nd,nk->ndk", x, gamma_rows).reshape(n, -1)
+
+
+def factored_update(coord, labels, offsets, params, dtype=jnp.float64):
+    """One alternation of a factored coordinate from ``params``, against
+    the other coordinates' scores ``offsets``: every entity's gamma by
+    Newton to convergence on its rows projected through B (its active
+    sample where it has one), then vec(B) by Newton to convergence on the
+    materialised Kronecker design of every trained row.  Returns the new
+    ``{"gamma", "projection"}``."""
+    with jax.default_matmul_precision("highest"):
+        x = jnp.asarray(coord["x"], dtype)
+        y = jnp.asarray(labels, dtype)
+        offsets = jnp.asarray(offsets, dtype)
+        ids = np.asarray(coord["ids"])
+        b = jnp.asarray(params["projection"], dtype)
+        gamma = jnp.asarray(params["gamma"], dtype)
+        projected = x @ b
+        for entity, rows in enumerate(_rows_by_entity(ids,
+                                                      coord["entities"])):
+            if rows.size == 0:
+                continue
+            weights = np.ones(rows.size)
+            if entity in coord["sample"]:
+                rows, weights = coord["sample"][entity]
+                rows = np.asarray(rows)
+            gamma = gamma.at[entity].set(newton(
+                projected[rows], y[rows], offsets[rows],
+                jnp.asarray(weights, dtype), gamma[entity], coord["l2"],
+                CONVERGED, dtype))
+        weights = train_weights(coord, ids.size)
+        trained = np.flatnonzero(weights > 0)
+        vec_b = newton(
+            kronecker_design(x[trained], gamma[ids[trained]]), y[trained],
+            offsets[trained], jnp.asarray(weights[trained], dtype),
+            b.reshape(-1), coord["l2_projection"], CONVERGED, dtype)
+        return {"gamma": gamma, "projection": vec_b.reshape(b.shape)}
 
 
 def _rows_by_entity(ids, entities):

@@ -679,6 +679,35 @@ class TestFactoredGameDriver:
         with pytest.raises(ValueError, match="mutually exclusive"):
             run_game_training(params)
 
+    def test_latent_optimizer_is_the_projection_solves_own(
+            self, rng, game_fixture):
+        """"re-config;latent-config;mf-config": NEWTON on the lanes, the
+        latent matrix by TRON under its own budget; the record says what
+        that solve did; an optimizer the projection solve does not
+        implement, or the key without latent_dim, is refused."""
+        train, valid, gs, us, tmp = game_fixture
+        params = game_params(train, valid, gs, us, str(tmp / "factopt"))
+        params["coordinates"]["per-user"].update(
+            optimizer="NEWTON", max_iters=2, tolerance=0.0, latent_dim=2,
+            latent_optimizer="TRON", latent_max_iters=3,
+            latent_tolerance=0.0, latent_reg_weight=1.0,
+        )
+        run = run_game_training(params)
+        solves = [
+            it["projection"] for h in run.sweep[0]["history"]
+            if h.coordinate == "per-user" for it in h.inner_iterations
+        ]
+        assert solves and all(s["iterations"] == 3 for s in solves)
+        assert all(s["cg_iterations"] > 0 for s in solves)
+
+        params["output_dir"] = str(tmp / "factopt-bad")
+        params["coordinates"]["per-user"]["latent_optimizer"] = "NEWTON"
+        with pytest.raises(ValueError, match="projection solve implements"):
+            run_game_training(params)
+        del params["coordinates"]["per-user"]["latent_dim"]
+        with pytest.raises(ValueError, match="latent_optimizer"):
+            run_game_training(params)
+
     def test_factored_latent_round_trip_io(self, rng, tmp_path):
         """save -> load preserves gamma and projection exactly (through
         the raw-entity-id and feature-key mappings)."""

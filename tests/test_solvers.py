@@ -220,6 +220,46 @@ class TestTRON:
             values.append(float(res.value))
         assert np.ptp(values) < 1e-4  # all starts reach the same optimum
 
+    @pytest.mark.parametrize("dtype", [jnp.float64, jnp.float32])
+    def test_tolerance_zero_makes_the_budget_the_rule(self, rng, dtype):
+        """Under tolerance 0 neither the outer tests nor the CG's relative
+        residual test stop anything: the same problem in another order of
+        its rows (other rounding) makes the same number of Hessian-vector
+        passes, which a residual near 0.1 |g| would otherwise decide."""
+        n, d = 400, 12
+        x = rng.normal(size=(n, d))
+        y = (rng.uniform(size=n) < 0.5).astype(float)
+
+        def solve(order, tolerance):
+            xo, yo = jnp.asarray(x[order], dtype), jnp.asarray(y[order], dtype)
+
+            def vg(w):
+                z = xo @ w
+                p = jax.nn.sigmoid(z)
+                value = jnp.sum(jax.nn.softplus(z) - yo * z) + 5.0 * (w @ w)
+                return value, xo.T @ (p - yo) + 10.0 * w
+
+            def hvp(w, v):
+                p = jax.nn.sigmoid(xo @ w)
+                return xo.T @ (p * (1 - p) * (xo @ v)) + 10.0 * v
+
+            return minimize_tron(
+                vg, hvp, jnp.zeros(d, dtype),
+                SolverConfig(max_iters=3, tolerance=tolerance, tron_max_cg=6),
+            )
+
+        runs = [solve(rng.permutation(n), 0.0) for _ in range(3)]
+        assert [int(r.iterations) for r in runs] == [3, 3, 3]
+        # the regulariser keeps every step inside the region: all 6, thrice
+        assert [int(r.cg_iterations) for r in runs] == [18, 18, 18]
+        assert all(int(r.reason) == ConvergenceReason.MAX_ITERATIONS
+                   for r in runs)
+        loose = solve(np.arange(n), 1e-12)
+        assert int(loose.cg_iterations) < 18  # the residual test still stops
+        np.testing.assert_allclose(
+            np.asarray(runs[0].w), np.asarray(loose.w),
+            atol=1e-4 if dtype == jnp.float32 else 1e-6)
+
     def test_vmapped_tron(self, rng):
         d = 5
         mats = np.stack(
