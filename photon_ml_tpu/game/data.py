@@ -183,18 +183,36 @@ def offsets_gather_maps(buckets):
             raise ValueError(
                 f"bucket {b}: a slot the mask holds has no row (row_index -1)"
             )
-        lanes = held.shape[0]
-        held_t = held.T  # (R_b, E_b)
-        some = held_t.any(axis=1)
-        lo = np.where(some, held_t.argmax(axis=1), 0)
-        hi = np.where(some, lanes - held_t[:, ::-1].argmax(axis=1), 0)
-        lane = np.arange(lanes)
-        in_range = (lane >= lo[:, None]) & (lane < hi[:, None])
+        lo, hi, in_range = _slot_ranges(held)
         perm.append(np.maximum(row_index.T[in_range], 0))
         first = position + np.cumsum(hi - lo) - (hi - lo)
         starts.append((first - lo).astype(np.int32))
         position += int(np.sum(hi - lo))
     return np.concatenate(perm).astype(np.int32), tuple(starts)
+
+
+def _slot_ranges(held):
+    """``(lo, hi, in_range)`` of one bucket's (E_b, R_b) held mask: slot j's
+    range of lanes ``lo[j] .. hi[j] - 1``, the first and last lane holding
+    it, and ``in_range`` (R_b, E_b), whose entries in row-major order are
+    the bucket's entries of ``perm``."""
+    lanes = held.shape[0]
+    held_t = held.T  # (R_b, E_b)
+    some = held_t.any(axis=1)
+    lo = np.where(some, held_t.argmax(axis=1), 0)
+    hi = np.where(some, lanes - held_t[:, ::-1].argmax(axis=1), 0)
+    lane = np.arange(lanes)
+    return lo, hi, (lane >= lo[:, None]) & (lane < hi[:, None])
+
+
+def held_slot_values(arrays, masks):
+    """Each bucket's (E_b, R_b) host array of ``arrays`` read at the
+    entries of :func:`offsets_gather_maps`' ``perm``, in its order (a wasted
+    entry of an unordered design reads a slot the mask does not hold)."""
+    return np.concatenate([
+        np.asarray(a).T[_slot_ranges(np.asarray(m) > 0)[2]]
+        for a, m in zip(arrays, masks)
+    ])
 
 
 def gather_offsets_compact(full_offsets, maps, masks):
@@ -209,9 +227,24 @@ def gather_offsets_compact(full_offsets, maps, masks):
     depth of them (PERF.md section 6, PR 35); about half of a bucketed
     design's padded slots hold no row."""
     perm, starts = maps
+    return fill_offsets(gather_held_offsets(full_offsets, perm), starts,
+                        masks)
+
+
+def gather_held_offsets(full_offsets, perm):
+    """The gather of :func:`gather_offsets_compact`: the residual offset of
+    every entry of ``perm`` (one a held row of an ordered design), in
+    ``perm``'s order. A factored coordinate's shared-projection solve reads
+    this vector as it is; :func:`fill_offsets` spreads it over the padded
+    slots."""
+    return jnp.take(full_offsets, perm, axis=0, mode="clip")
+
+
+def fill_offsets(gathered, starts, masks):
+    """The fills of :func:`gather_offsets_compact`: every slot of every
+    bucket from one contiguous run of ``gathered``."""
     pad = max(m.shape[0] for m in masks)
-    gathered = jnp.pad(jnp.take(full_offsets, perm, axis=0, mode="clip"),
-                       (pad, pad))
+    gathered = jnp.pad(gathered, (pad, pad))
     out = []
     for start, mask in zip(starts, masks):
         lanes = mask.shape[0]

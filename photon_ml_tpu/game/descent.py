@@ -155,6 +155,10 @@ def _record_update_metrics(rec: CoordinateUpdateRecord) -> None:
             sum(s["passes"] for s in solves),
         )
         reg.inc(
+            "game.factored.projection_rows",
+            sum(s["passes"] * s["rows"] for s in solves),
+        )
+        reg.inc(
             "game.factored.projection_cg_iterations",
             sum(s["cg_iterations"] for s in solves),
         )

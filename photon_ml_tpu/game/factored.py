@@ -12,14 +12,16 @@ Training alternates (numInnerIterations x):
       products x (x) gamma_e (``kroneckerProductFeaturesAndCoefficients``
       :251-266) — here the Kronecker design is NEVER materialized: margins,
       gradients, and Hessian-vector products contract X, gamma, and B
-      directly by einsum, so phase (b) costs O(E R d k) FLOPs and
-      O(E R d) memory instead of the O(E R d k) memory a materialized
-      (E*R, d*k) matrix would need.
+      directly by einsum, so phase (b) costs O(h d k) FLOPs and O(h d)
+      memory, h the held rows, instead of the O(h d k) memory a
+      materialized (h, d*k) matrix would need.
 
 Accepts a :class:`BucketedRandomEffectDesign` (or a single global-cap
 design, wrapped as one bucket): phase (a) runs per bucket with
-gather/scatter against the global gamma table; phase (b) sums every
-bucket's contribution into one shared-B objective.
+gather/scatter against the global gamma table; phase (b) has no lanes and
+reads a compact copy of the held rows (:class:`HeldRowDesign`), built once
+at construction, not the padded buckets, of which about half the slots
+hold no row.
 
 ``MatrixFactorizationModel`` (``model/MatrixFactorizationModel.scala:30-134``)
 is the inference-side pairing: two latent tables scored by gathered dot.
@@ -29,7 +31,7 @@ from __future__ import annotations
 
 import copy
 import dataclasses
-from functools import lru_cache
+from functools import lru_cache, partial
 from typing import List, Optional, Tuple
 
 import jax
@@ -46,7 +48,9 @@ from photon_ml_tpu.game.coordinates import (
 from photon_ml_tpu.game.data import (
     BucketedRandomEffectDesign,
     RandomEffectDesign,
-    gather_offsets_compact,
+    fill_offsets,
+    gather_held_offsets,
+    held_slot_values,
 )
 from photon_ml_tpu.models.training import OptimizerType
 from photon_ml_tpu.ops.losses import loss_for_task
@@ -135,6 +139,7 @@ class FactoredUpdateSummary:
     tracker: FactoredUpdateTracker
     valid_lanes: list  # per bucket (E_b,) bool, host: not a sharding pad
     entity_index: list  # per bucket (E_b,) int, host: lane -> table row
+    held_rows: int  # held slots (mask > 0); a B pass reads H >= these
     _host: Optional[tuple] = None  # history_decode's result, once read
 
     def history_fetch(self):
@@ -172,6 +177,7 @@ class FactoredUpdateSummary:
                             host.projection_cg_iterations[i]
                         ),
                         "passes": int(host.projection_passes[i]),
+                        "rows": self.held_rows,
                         "reason": ConvergenceReason(
                             int(host.projection_reason[i])
                         ).name,
@@ -217,6 +223,92 @@ class FactoredUpdateSummary:
         return self._decoded()[4]
 
 
+HELD_ROWS_ALIGN = 1024
+
+
+@_pytree_dataclass
+class HeldRowDesign:
+    """The held rows of a bucketed design, one entry an entry of
+    ``offsets_gather_maps``' ``perm`` (a held slot of an ordered design),
+    in its order (so that the offsets gather's vector lines up with it as
+    it is), zero-padded to a multiple of ``HELD_ROWS_ALIGN`` entries. What
+    the shared projection's GLM reads.
+
+    The features lie as (d, H), the entries minor: lane-dense on the chip,
+    where (H, d) of d 64 lies padded to 128 lanes, and where the (n, R, k)
+    intermediates of an (n, R, d) form were laid out with R or k minor, 16
+    times their size (PERF.md section 6, PR 37).
+
+    ``weights`` is the slot's weight times its mask (the cap's
+    weight-preserving rescale kept; 0 on a wasted ``perm`` entry of an
+    unordered design and on padding); ``entity`` the gamma table row of
+    the entry's lane."""
+
+    features: jax.Array  # (d, H)
+    weights: jax.Array  # (H,)
+    labels: jax.Array  # (H,)
+    entity: jax.Array  # (H,) int32
+
+
+def _held_vector(a, held):
+    """A vector in ``perm``'s order, zero-padded to the held rows' H."""
+    return jnp.pad(a, (0, held.weights.shape[0] - a.shape[0]))
+
+
+@partial(jax.jit, static_argnums=2)
+def _held_features(row_features, perm, size):
+    """(d, H): the rows ``perm`` names, zero-padded to ``size``: a bucket's
+    slot holds its row's features. Built ``HELD_ROWS_ALIGN`` entries at a
+    time, so that the only temp is ``row_features`` made row-major for the
+    gather (2.16 GB for the music cell's, compiled for v5e: one gather and
+    transpose of them all took 4.10 GB, the (H, d) rows laid out padded to
+    128 lanes; PERF.md section 6, PR 37)."""
+    chunk = HELD_ROWS_ALIGN
+    # padding names no row: the fill gives it zeros
+    perm = jnp.pad(perm, (0, size - perm.shape[0]),
+                   constant_values=row_features.shape[0])
+
+    def put(i, out):
+        at = lax.dynamic_slice_in_dim(perm, i * chunk, chunk)
+        rows = jnp.take(row_features, at, axis=0, mode="fill", fill_value=0)
+        return lax.dynamic_update_slice_in_dim(out, rows.T, i * chunk, axis=1)
+
+    out = jnp.zeros((row_features.shape[1], size), row_features.dtype)
+    return lax.fori_loop(0, size // chunk, put, out)
+
+
+def _build_held_rows(design, perm, row_features):
+    """The :class:`HeldRowDesign` of the design, once, at construction:
+    the features gathered from ``row_features`` through ``perm`` (the
+    offsets maps', on the device), the (H,) vectors read on the host at
+    the same slots; and the count of held slots (mask > 0)."""
+    from photon_ml_tpu.parallel.multihost import fetch_replicated
+
+    def host(a):
+        return np.asarray(fetch_replicated(a))
+
+    buckets = design.buckets
+    masks = [host(b.mask) for b in buckets]
+    weights = held_slot_values(
+        [host(b.weights) * m for b, m in zip(buckets, masks)], masks)
+    labels = held_slot_values([host(b.labels) for b in buckets], masks)
+    entity = held_slot_values(
+        [np.broadcast_to(np.asarray(ei)[:, None], m.shape)
+         for ei, m in zip(design.entity_index, masks)], masks)
+    size = -(-weights.size // HELD_ROWS_ALIGN) * HELD_ROWS_ALIGN
+
+    def pad(v, dtype):
+        return jnp.asarray(np.pad(v, (0, size - v.size)).astype(dtype))
+
+    return HeldRowDesign(
+        features=_held_features(
+            row_features.astype(buckets[0].features.dtype), perm, size),
+        weights=pad(weights, buckets[0].weights.dtype),
+        labels=pad(labels, buckets[0].labels.dtype),
+        entity=pad(entity, np.int32),
+    ), int(sum(np.count_nonzero(m > 0) for m in masks))
+
+
 def _einsum(spec, a, b):
     """Every contraction of the design with B, V or gamma. At matmul
     precision HIGHEST: the k-wide products go to the MXU, whose default
@@ -227,42 +319,38 @@ def _einsum(spec, a, b):
     return jnp.einsum(spec, a, b, precision=lax.Precision.HIGHEST)
 
 
-def _latent_objective(loss, lam, shape, gammas, buckets_offsets, buckets):
+def _latent_objective(loss, lam, shape, held, gamma_rows, offsets):
     """``(value_and_grad(vecB), hvp(vecB, vecV))`` of the shared
-    projection's GLM over the buckets' weighted, masked slots, vec(B) the
-    (d * k,) coefficient vector of the virtual Kronecker features
-    x (x) gamma, which are never built: every term contracts the bucket's
-    features, its lanes' gammas and B (or V) directly."""
+    projection's GLM over the held rows (a :class:`HeldRowDesign`, each
+    row's gamma ``gamma_rows`` (H, k) and residual offset ``offsets``
+    (H,)), vec(B) the (d * k,) coefficient vector of the
+    virtual Kronecker features x (x) gamma, which are never built: every
+    term contracts the rows' features, their gammas and B (or V)
+    directly."""
     d, k = shape
 
-    def margins(B, bucket, gamma_b, offsets):
-        xb = _einsum("erd,dk->erk", bucket.features, B)
-        return _einsum("erk,ek->er", xb, gamma_b) + offsets
+    def margins(B, offsets):
+        xb = _einsum("dh,dk->hk", held.features, B)
+        return _einsum("hk,hk->h", xb, gamma_rows) + offsets
 
     def value_and_grad(vecB):
         B = vecB.reshape(d, k)
-        val = 0.5 * lam * jnp.vdot(B, B)
-        grad = lam * B
-        for bucket, gamma_b, offsets in zip(buckets, gammas, buckets_offsets):
-            w = bucket.weights * bucket.mask
-            z = margins(B, bucket, gamma_b, offsets)
-            val = val + jnp.sum(w * loss.value(z, bucket.labels))
-            c = w * loss.d1(z, bucket.labels)
-            cg = _einsum("er,ek->erk", c, gamma_b)
-            grad = grad + _einsum("erd,erk->dk", bucket.features, cg)
+        z = margins(B, offsets)
+        val = 0.5 * lam * jnp.vdot(B, B) + jnp.sum(
+            held.weights * loss.value(z, held.labels))
+        c = held.weights * loss.d1(z, held.labels)
+        cg = _einsum("h,hk->hk", c, gamma_rows)
+        grad = lam * B + _einsum("dh,hk->dk", held.features, cg)
         return val, grad.reshape(-1)
 
     def hvp(vecB, vecV):
         B = vecB.reshape(d, k)
         V = vecV.reshape(d, k)
-        out = lam * V
-        for bucket, gamma_b, offsets in zip(buckets, gammas, buckets_offsets):
-            w = bucket.weights * bucket.mask
-            z = margins(B, bucket, gamma_b, offsets)
-            dz = margins(V, bucket, gamma_b, jnp.zeros_like(offsets))
-            c2 = w * loss.d2(z, bucket.labels) * dz
-            cg = _einsum("er,ek->erk", c2, gamma_b)
-            out = out + _einsum("erd,erk->dk", bucket.features, cg)
+        z = margins(B, offsets)
+        dz = margins(V, jnp.zeros_like(offsets))
+        c2 = held.weights * loss.d2(z, held.labels) * dz
+        cg = _einsum("h,hk->hk", c2, gamma_rows)
+        out = lam * V + _einsum("dh,hk->dk", held.features, cg)
         return out.reshape(-1)
 
     return value_and_grad, hvp
@@ -273,18 +361,17 @@ _LATENT_OPTIMIZERS = (OptimizerType.LBFGS, OptimizerType.TRON)
 
 @lru_cache(maxsize=64)
 def _make_latent_solve(config: CoordinateConfig):
-    """jitted solve for the shared projection B over any number of bucket
-    designs. The objective treats vec(B) as the coefficient vector of a
-    GLM on the VIRTUAL Kronecker features x (x) gamma — contracted lazily:
+    """jitted solve for the shared projection B over the held rows. The
+    objective treats vec(B) as the coefficient vector of a GLM on the
+    VIRTUAL Kronecker features x (x) gamma — contracted lazily:
 
-      margin_er = einsum('erd,dk,ek->er', X_b, B, gamma_b)
-      grad_dk   = einsum('er,erd,ek->dk', c, X_b, gamma_b) + lambda B
-      (Hv)_dk   = same contraction with c2 * dmargin(V)
+      margin_h = einsum('dh,dk,hk->h', X, B, gamma_rows)
+      grad_dk  = einsum('h,dh,hk->dk', c, X, gamma_rows) + lambda B
+      (Hv)_dk  = same contraction with c2 * dmargin(V)
 
-    Bucket tensors arrive as positional args (pytrees of varying shapes),
-    so one compilation serves a whole training run. TRON and L-BFGS (and
-    OWL-QN under an L1 share) are what it implements; any other optimizer
-    is refused here, at build time."""
+    The held rows arrive as arguments, so one compilation serves a whole
+    training run. TRON and L-BFGS (and OWL-QN under an L1 share) are what
+    it implements; any other optimizer is refused here, at build time."""
     loss = loss_for_task(config.task)
     scfg = config.solver_config()
     use_owlqn = config.l1_ratio > 0.0
@@ -299,9 +386,9 @@ def _make_latent_solve(config: CoordinateConfig):
     l1 = config.reg_weight * config.l1_ratio
     lam = l2
 
-    def solve(b0, gammas, buckets_offsets, buckets):
+    def solve(b0, gamma_rows, offsets, held):
         value_and_grad, hvp = _latent_objective(
-            loss, lam, b0.shape, gammas, buckets_offsets, buckets
+            loss, lam, b0.shape, held, gamma_rows, offsets
         )
         if use_owlqn:
             return minimize_owlqn(value_and_grad, b0.reshape(-1), l1, scfg)
@@ -337,12 +424,15 @@ def _make_factored_update(
 ):
     """ONE jitted call for a whole factored update and its rescore: the
     eager ``update`` dispatches it, the fused coordinate-descent pass
-    inlines it. Every bucket's ``entity_index`` is an argument (a leaf of
-    ``fused_state``), so the program holds no lane map as a constant. Its
-    device time splits by ``jax.named_scope``: ``factored/offsets``,
-    ``/project``, ``/latent_solve``, ``/table_write``, ``/gamma_gather``,
-    ``/projection_solve``, ``/score``. Both regularization weights are
-    trace-time constants of the two inner solves."""
+    inlines it. Every bucket's ``entity_index`` and the held rows are
+    arguments (leaves of ``fused_state``), so the program holds no lane
+    map and no copy of the design as a constant. Its device time splits
+    by ``jax.named_scope``: ``factored/offsets``, ``/project``,
+    ``/latent_solve``, ``/table_write``, ``/gamma_gather`` (the lanes'
+    warm starts, and the held rows' gammas), ``/projection_solve``,
+    ``/score``.
+    Both regularization weights are trace-time constants of the two inner
+    solves."""
     return _make_factored_update_cached(
         dataclasses.replace(re_config, random_effect=None),
         dataclasses.replace(latent_config, random_effect=None),
@@ -362,16 +452,20 @@ def _make_factored_update_cached(
         return jax.named_scope("factored/" + name)
 
     def update_all(
-        params, full_offsets, entity_indices, offsets_maps, buckets,
+        params, full_offsets, entity_indices, offsets_maps, buckets, held,
         row_features, row_entities,
     ):
         gamma, b = params.gamma, params.projection
         # the residual offsets do not change inside an update: one compact
-        # gather serves every inner iteration and both solves
+        # gather, an index a held row, serves every inner iteration and both
+        # solves, the B solve's as it is and the lanes' through the fills
         with scope("offsets"):
-            bucket_offsets = gather_offsets_compact(
-                full_offsets, offsets_maps, [bk.mask for bk in buckets]
+            perm, starts = offsets_maps
+            gathered = gather_held_offsets(full_offsets, perm)
+            bucket_offsets = fill_offsets(
+                gathered, starts, [bk.mask for bk in buckets]
             )
+            held_offsets = _held_vector(gathered, held)
         lane_tapes = [[] for _ in buckets]
         projection_tape = []
         for _ in range(num_inner_iterations):
@@ -401,15 +495,12 @@ def _make_factored_update_cached(
                 )
                 with scope("table_write"):
                     gamma = gamma.at[eidx].set(result.w, mode="drop")
-            # (b) shared projection over ALL buckets, einsum-contracted
+            # (b) shared projection over the held rows, einsum-contracted
             with scope("gamma_gather"):
-                gammas = tuple(
-                    jnp.take(gamma, eidx, axis=0, mode="clip")
-                    for eidx in entity_indices
-                )
+                gamma_rows = jnp.take(gamma, held.entity, axis=0, mode="clip")
             with scope("projection_solve"):
                 latent_result = latent_solve(
-                    b, gammas, tuple(bucket_offsets), tuple(buckets)
+                    b, gamma_rows, held_offsets, held
                 )
                 b = latent_result.w.reshape(b.shape)
                 projection_tape.append(_projection_tracker(latent_result))
@@ -463,6 +554,11 @@ class FactoredRandomEffectCoordinate:
         self._offsets_maps = _design_offsets_maps(design)
         self._entity_indices = tuple(
             jnp.asarray(ei) for ei in design.entity_index
+        )
+        # the B solve's rows, and how many slots the mask holds among them
+        # (game.factored.projection_rows counts those)
+        self._held, self._held_rows = _build_held_rows(
+            design, self._offsets_maps[0], row_features
         )
         # static per-bucket masks of real (non-sharding-pad) lanes
         self._valid_lanes = [
@@ -544,6 +640,7 @@ class FactoredRandomEffectCoordinate:
             self._entity_indices,
             self._offsets_maps,
             tuple(self.design.buckets),
+            self._held,
             self.row_features,
             self.row_entities,
         )
@@ -555,6 +652,7 @@ class FactoredRandomEffectCoordinate:
             tracker=tracker,
             valid_lanes=self._valid_lanes,
             entity_index=self.design.entity_index,
+            held_rows=self._held_rows,
         )
 
     def fused_state(self):
@@ -563,6 +661,7 @@ class FactoredRandomEffectCoordinate:
             tuple(self.design.buckets),
             self._entity_indices,
             self._offsets_maps,
+            self._held,
             self.row_features,
             self.row_entities,
             self.full_offsets_base,
@@ -574,6 +673,7 @@ class FactoredRandomEffectCoordinate:
             buckets,
             c._entity_indices,
             c._offsets_maps,
+            c._held,
             c.row_features,
             c.row_entities,
             c.full_offsets_base,

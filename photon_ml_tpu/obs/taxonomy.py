@@ -52,7 +52,9 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "game.re.passive_rows, game.table_write.inverse_gather, "
         "game.offsets_gather.compact, game.exchange.programs, "
         "game.factored.updates / .inner_iterations / .projection_passes / "
-        ".projection_cg_iterations of a factored coordinate's tracker, "
+        ".projection_rows (passes x held slots, mask > 0) / "
+        ".projection_cg_iterations "
+        "of a factored coordinate's tracker, "
         "...), the "
         "game.offsets_gather.gather_indices / .padded_slots gauges of a "
         "random-effect coordinate's residual-offset gather and the "

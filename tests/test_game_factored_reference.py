@@ -7,6 +7,7 @@ Hessian-vector product at a random point; a three-coordinate descent fused
 against unfused; and what its tracker says of every lane and of the B solve.
 """
 
+import dataclasses
 import functools
 
 import jax
@@ -28,7 +29,7 @@ from photon_ml_tpu.game import (
     build_bucketed_random_effect_design,
 )
 from photon_ml_tpu.game import factored as factored_mod
-from photon_ml_tpu.game.data import gather_offsets_compact
+from photon_ml_tpu.game.data import gather_held_offsets, offsets_gather_maps
 from photon_ml_tpu.game.factored import (
     FactoredConfig,
     FactoredParams,
@@ -91,10 +92,12 @@ def song_design(dtype):
 
 
 def song_coordinate(dtype, inner, lane_iters, b_iters, tolerance,
-                    b_optimizer=OptimizerType.TRON):
-    """(the factored per-song coordinate, its reference description)."""
+                    b_optimizer=OptimizerType.TRON, design=None,
+                    b_tolerance=None):
+    """(the factored per-song coordinate, its reference description); the
+    B solve's tolerance is ``tolerance`` unless ``b_tolerance`` is given."""
     x, ids, _ = ratings()
-    design = song_design(dtype)
+    design = song_design(dtype) if design is None else design
     n = ids["songId"].size
     common = dict(task=TaskType.LOGISTIC_REGRESSION, tolerance=tolerance)
     coord = FactoredRandomEffectCoordinate(
@@ -110,7 +113,9 @@ def song_coordinate(dtype, inner, lane_iters, b_iters, tolerance,
             latent_dim=LATENT, num_inner_iterations=inner,
             latent_factor_config=CoordinateConfig(
                 shard="per_song", optimizer=b_optimizer,
-                reg_weight=L2_PROJECTION, max_iters=b_iters, **common),
+                reg_weight=L2_PROJECTION, max_iters=b_iters,
+                **dict(common, tolerance=(
+                    tolerance if b_tolerance is None else b_tolerance))),
         ),
         initial_projection=initial_projection(),
     )
@@ -144,22 +149,29 @@ def reference_update(dtype_name):
 
 
 # Both problems of an alternation are strictly convex under their L2, so
-# each has one minimiser and two converged solvers meet there.  The program
-# stops on its relative function-value test (|df| <= tol f0: at 1e-15 in
-# float64, 1e-7 in float32), which leaves a B about sqrt(tol) from the
-# minimiser.  Read against the float64 reference: the float64 program 3.1e-10
-# (gamma) and 1.0e-9 (B); the float32 program 1.1e-7 and 1.7e-5; the
-# reference itself in bfloat16 1.9e-2 and 4.2e-2.  The float32 limit sits
-# twelve times above its reading and two decades below bfloat16's.
+# each has one minimiser and two converged solvers meet there.  The lane
+# solves stop on their relative function-value test (|df| <= tol f0: at
+# 1e-15 in float64, 1e-7 in float32).  The B solve does not: in float32 a
+# decrease of 1e-7 f0 is the objective's own rounding, so that test stopped
+# B anywhere within about sqrt(1e-7) of the minimiser as the order of the
+# sum fell (1.7e-5 over the padded buckets, 2.0e-4 over the held rows,
+# PR 37).  It runs the cell's rule instead, tolerance 0 and a budget of
+# B_ITERATIONS outer iterations, which reaches its minimiser to the
+# gradient's rounding.  Read against the float64 reference: the float64
+# program 3.1e-10 (gamma) and 4.4e-11 (B); the float32 program 1.1e-7 and
+# 1.3e-7 (a budget of 20 or 40: 1.5e-7, 1.4e-7); the reference itself in
+# bfloat16 1.9e-2 and 4.2e-2.  The float32 limit sits three decades above
+# its reading and two below bfloat16's.
 TOLERANCE = {"float64": 1e-8, "float32": 2e-4}
 SOLVER_TOLERANCE = {"float64": 1e-15, "float32": 1e-7}
+B_ITERATIONS = 10
 
 
 @pytest.mark.parametrize("dtype_name", ["float64", "float32"])
 def test_converged_update_matches_the_kronecker_reference(dtype_name):
     dtype = jnp.dtype(dtype_name)
-    coord, _ = song_coordinate(dtype, 1, 60, 200,
-                               SOLVER_TOLERANCE[dtype_name])
+    coord, _ = song_coordinate(dtype, 1, 60, B_ITERATIONS,
+                               SOLVER_TOLERANCE[dtype_name], b_tolerance=0.0)
     params, summary = coord.update(
         coord.initial_params(), jnp.asarray(other_scores(), dtype))
     want = reference_update("float64")
@@ -170,8 +182,9 @@ def test_converged_update_matches_the_kronecker_reference(dtype_name):
     no_rows = np.bincount(ratings()[1]["songId"], minlength=N_SONGS) == 0
     assert np.all(np.asarray(params.gamma)[no_rows] == 0.0)
     assert params.gamma.shape == (N_SONGS, LATENT)
-    assert summary.inner_iterations[0]["projection"]["reason"] != (
-        ConvergenceReason.MAX_ITERATIONS.name)
+    solve = summary.inner_iterations[0]["projection"]
+    assert solve["reason"] == ConvergenceReason.MAX_ITERATIONS.name
+    assert solve["iterations"] == B_ITERATIONS
 
 
 def test_bfloat16_in_the_programs_place_fails_the_float32_tolerance():
@@ -225,17 +238,10 @@ def test_projection_gradient_and_hvp_against_the_kronecker_design():
     coord, described = song_coordinate(jnp.float64, 1, 1, 1, 0.0)
     point = random_point()
     offsets = other_scores()
-    design = coord.design
-    gammas = tuple(
-        jnp.take(jnp.asarray(point["gamma"]), jnp.asarray(ei), axis=0,
-                 mode="clip")
-        for ei in design.entity_index)
-    bucket_offsets = gather_offsets_compact(
-        jnp.asarray(offsets), coord._offsets_maps,
-        [b.mask for b in design.buckets])
     value_and_grad, hvp = factored_mod._latent_objective(
         loss_for_task(TaskType.LOGISTIC_REGRESSION), L2_PROJECTION,
-        point["projection"].shape, gammas, bucket_offsets, design.buckets)
+        point["projection"].shape, coord._held,
+        *held_inputs(coord, point["gamma"], offsets))
     vec_b = jnp.asarray(point["projection"]).reshape(-1)
     direction = jnp.asarray(
         np.random.default_rng(4).normal(size=vec_b.shape))
@@ -271,6 +277,17 @@ def test_projection_gradient_and_hvp_against_the_kronecker_design():
     )["per-song"]["projection"]
     np.testing.assert_allclose(np.asarray(got_grad).reshape(want_b.shape),
                                np.asarray(want_b), rtol=1e-10, atol=1e-11)
+
+
+def held_inputs(coord, gamma, offsets):
+    """(gamma a held row, residual offset a held row), in the coordinate's
+    held-row shape: what its update hands the B solve."""
+    return (
+        jnp.take(jnp.asarray(gamma), coord._held.entity, axis=0,
+                 mode="clip"),
+        factored_mod._held_vector(gather_held_offsets(
+            jnp.asarray(offsets), coord._offsets_maps[0]), coord._held),
+    )
 
 
 def descent(fuse, dtype=jnp.float64):
@@ -365,12 +382,8 @@ def test_tracker_counts_every_lane_and_the_projection_solve():
     assert np.all(np.isfinite(summary.grad_norms))
 
     alone = factored_mod._make_latent_solve(coord._latent_cfg)(
-        start.projection,
-        tuple(jnp.take(params.gamma, jnp.asarray(ei), axis=0, mode="clip")
-              for ei in design.entity_index),
-        tuple(gather_offsets_compact(
-            offsets, coord._offsets_maps, [b.mask for b in design.buckets])),
-        tuple(design.buckets),
+        start.projection, *held_inputs(coord, params.gamma, offsets),
+        coord._held,
     )
     solve = inner["projection"]
     assert solve["iterations"] == int(alone.iterations) == 3
@@ -383,9 +396,209 @@ def test_tracker_counts_every_lane_and_the_projection_solve():
                                np.asarray(alone.w), rtol=1e-12)
 
 
+def padded_objective(lam, shape, buckets, gammas, bucket_offsets):
+    """The B problem over the padded buckets, slot by slot, each lane's
+    gamma broadcast over its slots: the form the held rows replaced (PR
+    37), ``(value_and_grad(vecB), hvp(vecB, vecV))``."""
+    loss = loss_for_task(TaskType.LOGISTIC_REGRESSION)
+    d, k = shape
+
+    def terms(B, V):
+        out = []
+        for bucket, gamma_b, offsets in zip(buckets, gammas, bucket_offsets):
+            w = bucket.weights * bucket.mask
+            xb = jnp.einsum("erd,dk,ek->er", bucket.features, B, gamma_b,
+                            precision="highest")
+            dz = jnp.einsum("erd,dk,ek->er", bucket.features, V, gamma_b,
+                            precision="highest")
+            out.append((bucket, gamma_b, w, xb + offsets, dz))
+        return out
+
+    def contract(bucket, gamma_b, c):
+        return jnp.einsum("erd,er,ek->dk", bucket.features, c, gamma_b,
+                          precision="highest")
+
+    def value_and_grad(vecB):
+        B = vecB.reshape(d, k)
+        val, grad = 0.5 * lam * jnp.vdot(B, B), lam * B
+        for bucket, gamma_b, w, z, _ in terms(B, B):
+            val = val + jnp.sum(w * loss.value(z, bucket.labels))
+            grad = grad + contract(
+                bucket, gamma_b, w * loss.d1(z, bucket.labels))
+        return val, grad.reshape(-1)
+
+    def hvp(vecB, vecV):
+        B, V = vecB.reshape(d, k), vecV.reshape(d, k)
+        out = lam * V
+        for bucket, gamma_b, w, z, dz in terms(B, V):
+            out = out + contract(
+                bucket, gamma_b, w * loss.d2(z, bucket.labels) * dz)
+        return out.reshape(-1)
+
+    return value_and_grad, hvp
+
+
+def shuffled_lanes(design, seed):
+    """The design with every bucket's lanes in a random order: a slot's
+    range of lanes then holds lanes that do not reach it, so the offsets
+    maps' ``perm`` carries wasted entries (an unordered design)."""
+    rng = np.random.default_rng(seed)
+    buckets, index = [], []
+    for bucket, ei in zip(design.buckets, design.entity_index):
+        order = rng.permutation(bucket.num_entities)
+        buckets.append(jax.tree_util.tree_map(lambda a: a[order], bucket))
+        index.append(np.asarray(ei)[order])
+    return dataclasses.replace(design, buckets=buckets, entity_index=index)
+
+
+@pytest.mark.parametrize("ordered", [True, False])
+def test_held_rows_give_the_padded_buckets_objective(ordered):
+    """The B problem over the held rows against the same problem over the
+    padded buckets, float32: its value, gradient and Hessian-vector
+    product at a random point, on the song design with a capped (rescaled)
+    song, passive rows and lanes padded for sharding, its lanes as built
+    and shuffled (wasted ``perm`` entries, weight 0)."""
+    design = song_design(jnp.float32)
+    if not ordered:
+        design = shuffled_lanes(design, 7)
+    coord, _ = song_coordinate(jnp.float32, 1, 1, 1, 0.0, design=design)
+    held_slots = sum(int(np.count_nonzero(np.asarray(b.mask) > 0))
+                     for b in design.buckets)
+    perm = np.asarray(coord._offsets_maps[0])
+    assert coord._held_rows == held_slots
+    assert (perm.size > held_slots) == (not ordered)
+    held = coord._held
+    size = held.weights.shape[0]
+    assert held.features.shape == (DIMS["per_song"], size)
+    assert size % factored_mod.HELD_ROWS_ALIGN == 0
+    assert size - perm.size < factored_mod.HELD_ROWS_ALIGN
+    # the cap's rescale is kept: every slot's weight, once
+    np.testing.assert_allclose(
+        float(jnp.sum(held.weights)),
+        sum(float(jnp.sum(b.weights * b.mask)) for b in design.buckets),
+        rtol=1e-6)
+    # a held entry is the row perm names, of its lane's entity
+    real = np.asarray(held.weights)[:perm.size] > 0
+    assert real.sum() == held_slots
+    assert not np.any(np.asarray(held.weights)[perm.size:])
+    # a held row's gamma is its song's
+    songs = ratings()[1]["songId"]
+    table = np.arange(N_SONGS * LATENT, dtype=np.float32).reshape(
+        N_SONGS, LATENT)
+    gamma_rows = np.asarray(
+        held_inputs(coord, table, np.zeros(songs.size))[0])
+    np.testing.assert_array_equal(
+        gamma_rows[:perm.size][real], table[songs[perm[real]]])
+    # and its features are that row's
+    np.testing.assert_array_equal(
+        np.asarray(held.features)[:, :perm.size][:, real],
+        np.asarray(ratings()[0]["per_song"], np.float32)[perm[real]].T)
+
+    point = random_point()
+    offsets = jnp.asarray(other_scores(), jnp.float32)
+    gamma = jnp.asarray(point["gamma"], jnp.float32)
+    vec_b = jnp.asarray(point["projection"], jnp.float32).reshape(-1)
+    direction = jnp.asarray(
+        np.random.default_rng(4).normal(size=vec_b.shape), jnp.float32)
+    got_vg, got_hvp = factored_mod._latent_objective(
+        loss_for_task(TaskType.LOGISTIC_REGRESSION), L2_PROJECTION,
+        point["projection"].shape, held,
+        *held_inputs(coord, gamma, offsets))
+    want_vg, want_hvp = padded_objective(
+        L2_PROJECTION, point["projection"].shape, design.buckets,
+        [jnp.take(gamma, jnp.asarray(ei), axis=0, mode="clip")
+         for ei in design.entity_index],
+        [b.gather_offsets(offsets) for b in design.buckets])
+    (got_value, got_grad), (want_value, want_grad) = (
+        got_vg(vec_b), want_vg(vec_b))
+    assert abs(float(got_value) - float(want_value)) <= 1e-6 * abs(
+        float(want_value))
+    assert rel(got_grad, want_grad) <= 1e-6
+    assert rel(got_hvp(vec_b, direction),
+               want_hvp(vec_b, direction)) <= 1e-6
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+@pytest.mark.parametrize("sentinels", [0, 5])
+def test_held_rows_are_the_perm_entries_in_order(shuffle, sentinels):
+    """The held-row build against a plain loop on buckets of prefix-held
+    slots, each a row of its own, lanes in count order or shuffled, with
+    ``sentinels`` empty lanes a bucket: every ``perm`` entry (slot-major,
+    slot j over the lanes from the first to the last that hold it) carries
+    its row's features, weight x mask, label and lane's entity, in that
+    order, then zeros."""
+    from photon_ml_tpu.game.data import (
+        BucketedRandomEffectDesign,
+        RandomEffectDesign,
+    )
+
+    rng = np.random.default_rng(17)
+    n = 400
+    rows = jnp.asarray(rng.normal(size=(n, 5)), jnp.float32)
+    free = iter(rng.permutation(n))
+    buckets, index = [], []
+    for lanes, depth in ((23, 3), (9, 11), (4, 30)):
+        count = np.sort(rng.integers(0, depth + 1, size=lanes))
+        count = np.concatenate([count, np.zeros(sentinels, int)])
+        if shuffle:
+            count = rng.permutation(count)
+        mask = (np.arange(depth)[None, :] < count[:, None]).astype(np.float32)
+        row_index = np.full(mask.shape, -1, np.int32)
+        for lane, slot in zip(*np.nonzero(mask)):
+            row_index[lane, slot] = next(free)
+        buckets.append(RandomEffectDesign(
+            features=jnp.where(mask[..., None] > 0,
+                               rows[np.maximum(row_index, 0)], 0.0),
+            labels=jnp.asarray(rng.integers(0, 2, mask.shape), jnp.float32),
+            weights=jnp.asarray(rng.uniform(0.5, 2.0, mask.shape),
+                                jnp.float32),
+            mask=jnp.asarray(mask),
+            row_index=jnp.asarray(row_index)))
+        index.append(rng.permutation(100)[:mask.shape[0]].astype(np.int32))
+    design = BucketedRandomEffectDesign(
+        buckets=buckets, entity_index=index, num_entities=100)
+    perm, _ = offsets_gather_maps([(np.asarray(b.row_index), b.mask)
+                                   for b in buckets])
+    held, held_slots = factored_mod._build_held_rows(
+        design, jnp.asarray(perm), rows)
+
+    want = {k: [] for k in ("row", "weights", "labels", "entity")}
+    for bucket, eidx in zip(buckets, index):
+        mask, ri = np.asarray(bucket.mask), np.asarray(bucket.row_index)
+        for j in range(mask.shape[1]):
+            holders = [e for e in range(mask.shape[0]) if mask[e, j] > 0]
+            for e in range(holders[0], holders[-1] + 1) if holders else ():
+                want["row"].append(max(ri[e, j], 0))
+                want["weights"].append(
+                    float(bucket.weights[e, j]) * mask[e, j])
+                want["labels"].append(float(bucket.labels[e, j]))
+                want["entity"].append(eidx[e])
+    size = held.weights.shape[0]
+    entries = len(want["row"])
+    assert entries == perm.size
+    assert size % factored_mod.HELD_ROWS_ALIGN == 0
+    assert size - entries < factored_mod.HELD_ROWS_ALIGN
+    assert held_slots == sum(int(np.count_nonzero(b.mask)) for b in buckets)
+    assert (entries > held_slots) == shuffle
+
+    def padded(values, dtype):
+        return np.pad(np.asarray(values, dtype), (0, size - entries))
+
+    np.testing.assert_array_equal(
+        np.asarray(held.features),
+        np.pad(np.asarray(rows)[want["row"]], ((0, size - entries), (0, 0))).T)
+    np.testing.assert_array_equal(np.asarray(held.weights),
+                                  padded(want["weights"], np.float32))
+    np.testing.assert_array_equal(np.asarray(held.labels),
+                                  padded(want["labels"], np.float32))
+    np.testing.assert_array_equal(np.asarray(held.entity),
+                                  padded(want["entity"], np.int32))
+
+
 def test_counters_are_fed_once_an_update():
     names = ("game.factored.updates", "game.factored.inner_iterations",
              "game.factored.projection_passes",
+             "game.factored.projection_rows",
              "game.factored.projection_cg_iterations")
 
     def read():
@@ -401,6 +614,11 @@ def test_counters_are_fed_once_an_update():
     assert got["game.factored.updates"] == 2
     assert got["game.factored.inner_iterations"] == 4 == len(solves)
     assert got["game.factored.projection_passes"] == sum(
+        s["passes"] for s in solves)
+    held = sum(int(np.count_nonzero(np.asarray(b.mask) > 0))
+               for b in song_design(jnp.float64).buckets)
+    assert {s["rows"] for s in solves} == {held}
+    assert got["game.factored.projection_rows"] == held * sum(
         s["passes"] for s in solves)
     assert got["game.factored.projection_cg_iterations"] == sum(
         s["cg_iterations"] for s in solves) > 0
