@@ -1083,183 +1083,195 @@ class EntityShardedRandomEffectCoordinate:
         self.partition = partition
         self._dim = design.dim
 
-        ent_spec = lambda nd: NamedSharding(
-            mesh, P(ENTITY_AXIS, *([None] * (nd - 1)))
-        )
-
-        def place(x):
-            # straight to its shards: a host array staged through
-            # ``jnp.asarray`` would sit whole on the first device
-            return jax.device_put(x, ent_spec(np.ndim(x)))
-
-        # per-entity reg weights, stored shard-major (pad rows keep the
-        # config weight — no lane holds them)
-        self._uniform_reg = reg_weights is None
-        if reg_weights is None:
-            reg_stored = np.full(
-                (assignment.padded_rows,), config.reg_weight, np.float32
+        # the host regroup and the device placement: one span, the
+        # bytes put on the mesh counted as they go
+        with obs.span(
+            "partition.coordinate", cat="partition",
+            random_effect=config.random_effect, shards=n_shards, rows=n_pad,
+        ) as sp:
+            ent_spec = lambda nd: NamedSharding(
+                mesh, P(ENTITY_AXIS, *([None] * (nd - 1)))
             )
-        else:
-            reg_weights = np.asarray(reg_weights, np.float32)
-            if reg_weights.shape != (e_global,):
-                raise ValueError(
-                    f"reg_weights must be ({e_global},), got "
-                    f"{reg_weights.shape}"
+            placed_bytes = 0
+
+            def place(x):
+                # straight to its shards: a host array staged through
+                # ``jnp.asarray`` would sit whole on the first device
+                nonlocal placed_bytes
+                placed_bytes += sum(
+                    int(leaf.nbytes) for leaf in jax.tree.leaves(x)
                 )
-            reg_stored = assignment.table_from_global(reg_weights)
-        self.reg_weights = place(reg_stored)
+                return jax.device_put(x, ent_spec(np.ndim(x)))
 
-        # regroup every bucket's lanes by owner shard: shard p's lanes
-        # contiguous, padded to the max per-shard count; indices go
-        # shard-LOCAL (table rows within the block, sentinel b_rows;
-        # offset rows within the block, sentinel -1)
-        g2s = assignment.global_to_stored
-        buckets = []
-        eidx_local = []
-        held_rows = []  # a bucket's regrouped (row_index, mask), host
-        self._valid_lanes = []
-        self._lane_entities = []
-        for bucket, eidx in zip(design.buckets, design.entity_index):
-            eidx = np.asarray(eidx, np.int64)
-            stored = np.where(
-                eidx < e_global, g2s[np.minimum(eidx, e_global)],
-                assignment.padded_rows,
-            )
-            owner = assignment.shard_of_stored(
-                np.minimum(stored, assignment.padded_rows - 1)
-            )
-            owner = np.where(
-                stored < assignment.padded_rows, owner, 0
-            )  # sentinels balance onto shard 0's padding
-            counts = np.bincount(owner, minlength=n_shards)
-            l_b = max(int(counts.max()), 1)
-            order = np.argsort(owner, kind="stable")
-            starts = np.concatenate([[0], np.cumsum(counts)])[:-1]
-            slot = np.arange(eidx.size) - starts[owner[order]]
-            lane_of = owner[order] * l_b + slot  # new lane of old lane
-            new_lanes = n_shards * l_b
-            new_stored = np.full(new_lanes, assignment.padded_rows, np.int64)
-            new_stored[lane_of] = stored[order]
-            local = np.where(
-                new_stored < assignment.padded_rows,
-                new_stored - (np.arange(new_lanes) // l_b) * b_rows,
-                b_rows,
-            ).astype(np.int32)
-
-            old_lane = np.full(new_lanes, -1, np.int64)
-            old_lane[lane_of] = order
-
-            def regroup(x, fill=0.0):
-                # one gather of whole lanes, the pad lanes overwritten
-                out = np.take(
-                    np.asarray(x), np.maximum(old_lane, 0), axis=0
+            # per-entity reg weights, stored shard-major (pad rows keep the
+            # config weight — no lane holds them)
+            self._uniform_reg = reg_weights is None
+            if reg_weights is None:
+                reg_stored = np.full(
+                    (assignment.padded_rows,), config.reg_weight, np.float32
                 )
-                out[old_lane < 0] = fill
-                return out
+            else:
+                reg_weights = np.asarray(reg_weights, np.float32)
+                if reg_weights.shape != (e_global,):
+                    raise ValueError(
+                        f"reg_weights must be ({e_global},), got "
+                        f"{reg_weights.shape}"
+                    )
+                reg_stored = assignment.table_from_global(reg_weights)
+            self.reg_weights = place(reg_stored)
 
-            ri = np.asarray(bucket.row_index, np.int64)
-            shard_of_lane = np.arange(new_lanes) // l_b
-            ri_new = regroup(ri, fill=-1)
-            ri_local = np.where(
-                ri_new >= 0,
-                ri_new - shard_of_lane[:, None] * r_rows,
-                -1,
-            ).astype(np.int32)
-            mask_new = regroup(bucket.mask)
-            held_rows.append((ri_local, mask_new))
-            buckets.append(
-                RandomEffectDesign(
-                    features=place(regroup(bucket.features)),
-                    labels=place(regroup(bucket.labels)),
-                    weights=place(regroup(bucket.weights)),
-                    mask=place(mask_new),
-                    row_index=place(ri_local),
+            # regroup every bucket's lanes by owner shard: shard p's lanes
+            # contiguous, padded to the max per-shard count; indices go
+            # shard-LOCAL (table rows within the block, sentinel b_rows;
+            # offset rows within the block, sentinel -1)
+            g2s = assignment.global_to_stored
+            buckets = []
+            eidx_local = []
+            held_rows = []  # a bucket's regrouped (row_index, mask), host
+            self._valid_lanes = []
+            self._lane_entities = []
+            for bucket, eidx in zip(design.buckets, design.entity_index):
+                eidx = np.asarray(eidx, np.int64)
+                stored = np.where(
+                    eidx < e_global, g2s[np.minimum(eidx, e_global)],
+                    assignment.padded_rows,
                 )
-            )
-            eidx_local.append(local)
-            self._valid_lanes.append(
-                new_stored < assignment.padded_rows
-            )
-            glob = np.full(new_lanes, e_global, np.int64)
-            real = new_stored < assignment.padded_rows
-            glob[real] = assignment.stored_to_global[new_stored[real]]
-            self._lane_entities.append(glob.astype(np.int32))
-        self._buckets = tuple(buckets)
-        # the offsets-gather maps a shard, from ITS lanes' block of every
-        # bucket; a shard's rows to gather padded (row 0, past every run)
-        # to the longest shard's
-        shard_maps = _offsets_gather_maps(
-            [
+                owner = assignment.shard_of_stored(
+                    np.minimum(stored, assignment.padded_rows - 1)
+                )
+                owner = np.where(
+                    stored < assignment.padded_rows, owner, 0
+                )  # sentinels balance onto shard 0's padding
+                counts = np.bincount(owner, minlength=n_shards)
+                l_b = max(int(counts.max()), 1)
+                order = np.argsort(owner, kind="stable")
+                starts = np.concatenate([[0], np.cumsum(counts)])[:-1]
+                slot = np.arange(eidx.size) - starts[owner[order]]
+                lane_of = owner[order] * l_b + slot  # new lane of old lane
+                new_lanes = n_shards * l_b
+                new_stored = np.full(new_lanes, assignment.padded_rows, np.int64)
+                new_stored[lane_of] = stored[order]
+                local = np.where(
+                    new_stored < assignment.padded_rows,
+                    new_stored - (np.arange(new_lanes) // l_b) * b_rows,
+                    b_rows,
+                ).astype(np.int32)
+
+                old_lane = np.full(new_lanes, -1, np.int64)
+                old_lane[lane_of] = order
+
+                def regroup(x, fill=0.0):
+                    # one gather of whole lanes, the pad lanes overwritten
+                    out = np.take(
+                        np.asarray(x), np.maximum(old_lane, 0), axis=0
+                    )
+                    out[old_lane < 0] = fill
+                    return out
+
+                ri = np.asarray(bucket.row_index, np.int64)
+                shard_of_lane = np.arange(new_lanes) // l_b
+                ri_new = regroup(ri, fill=-1)
+                ri_local = np.where(
+                    ri_new >= 0,
+                    ri_new - shard_of_lane[:, None] * r_rows,
+                    -1,
+                ).astype(np.int32)
+                mask_new = regroup(bucket.mask)
+                held_rows.append((ri_local, mask_new))
+                buckets.append(
+                    RandomEffectDesign(
+                        features=place(regroup(bucket.features)),
+                        labels=place(regroup(bucket.labels)),
+                        weights=place(regroup(bucket.weights)),
+                        mask=place(mask_new),
+                        row_index=place(ri_local),
+                    )
+                )
+                eidx_local.append(local)
+                self._valid_lanes.append(
+                    new_stored < assignment.padded_rows
+                )
+                glob = np.full(new_lanes, e_global, np.int64)
+                real = new_stored < assignment.padded_rows
+                glob[real] = assignment.stored_to_global[new_stored[real]]
+                self._lane_entities.append(glob.astype(np.int32))
+            self._buckets = tuple(buckets)
+            # the offsets-gather maps a shard, from ITS lanes' block of every
+            # bucket; a shard's rows to gather padded (row 0, past every run)
+            # to the longest shard's
+            shard_maps = _offsets_gather_maps(
                 [
-                    tuple(np.split(a, n_shards)[p] for a in held)
-                    for held in held_rows
+                    [
+                        tuple(np.split(a, n_shards)[p] for a in held)
+                        for held in held_rows
+                    ]
+                    for p in range(n_shards)
                 ]
-                for p in range(n_shards)
-            ]
-        )
-        longest = max(perm.size for perm, _ in shard_maps)
-        offsets_maps = (
-            place(
-                np.concatenate(
-                    [
-                        np.pad(perm, (0, longest - perm.size))
-                        for perm, _ in shard_maps
-                    ]
-                )
-            ),
-            tuple(
-                place(np.concatenate([starts[b] for _, starts in shard_maps]))
-                for b in range(len(buckets))
-            ),
-        )
-        # (every bucket's lanes, their shard-local inverse: block p maps
-        # the rows of ITS table block to the concatenation of ITS lanes,
-        # the offsets-gather maps)
-        self._entity_indices = (
-            tuple(place(li) for li in eidx_local),
-            place(
-                np.concatenate(
-                    [
-                        _lane_of_entity(
-                            [
-                                li.reshape(n_shards, -1)[p]
-                                for li in eidx_local
-                            ],
-                            b_rows,
-                        )
-                        for p in range(n_shards)
-                    ]
-                )
-            ),
-            offsets_maps,
-        )
+            )
+            longest = max(perm.size for perm, _ in shard_maps)
+            offsets_maps = (
+                place(
+                    np.concatenate(
+                        [
+                            np.pad(perm, (0, longest - perm.size))
+                            for perm, _ in shard_maps
+                        ]
+                    )
+                ),
+                tuple(
+                    place(np.concatenate([starts[b] for _, starts in shard_maps]))
+                    for b in range(len(buckets))
+                ),
+            )
+            # (every bucket's lanes, their shard-local inverse: block p maps
+            # the rows of ITS table block to the concatenation of ITS lanes,
+            # the offsets-gather maps)
+            self._entity_indices = (
+                tuple(place(li) for li in eidx_local),
+                place(
+                    np.concatenate(
+                        [
+                            _lane_of_entity(
+                                [
+                                    li.reshape(n_shards, -1)[p]
+                                    for li in eidx_local
+                                ],
+                                b_rows,
+                            )
+                            for p in range(n_shards)
+                        ]
+                    )
+                ),
+                offsets_maps,
+            )
 
-        # per-row scoring inputs, shard-local entity rows
-        re_ids = np.asarray(row_entities, np.int64)
-        known = re_ids >= 0
-        ents_local = np.full(re_ids.shape, -1, np.int32)
-        shard_of_row = np.arange(n_pad) // r_rows
-        ents_local[known] = (
-            g2s[re_ids[known]] - shard_of_row[known] * b_rows
-        ).astype(np.int32)
-        self.row_features = place(row_features)
-        self.row_entities_local = place(ents_local)
-        self.full_offsets_base = place(full_offsets_base)
-        # the exchange plan's four index arrays, a shard its segment; ()
-        # where the own order is the canonical one
-        self._exchange = (
-            ()
-            if plan is None
-            else tuple(
-                place(a)
-                for a in (
-                    plan.send_to_owner,
-                    plan.recv_at_owner,
-                    plan.send_to_canonical,
-                    plan.recv_at_canonical,
+            # per-row scoring inputs, shard-local entity rows
+            re_ids = np.asarray(row_entities, np.int64)
+            known = re_ids >= 0
+            ents_local = np.full(re_ids.shape, -1, np.int32)
+            shard_of_row = np.arange(n_pad) // r_rows
+            ents_local[known] = (
+                g2s[re_ids[known]] - shard_of_row[known] * b_rows
+            ).astype(np.int32)
+            self.row_features = place(row_features)
+            self.row_entities_local = place(ents_local)
+            self.full_offsets_base = place(full_offsets_base)
+            # the exchange plan's four index arrays, a shard its segment; ()
+            # where the own order is the canonical one
+            self._exchange = (
+                ()
+                if plan is None
+                else tuple(
+                    place(a)
+                    for a in (
+                        plan.send_to_owner,
+                        plan.recv_at_owner,
+                        plan.send_to_canonical,
+                        plan.recv_at_canonical,
+                    )
                 )
             )
-        )
+            sp.set(bytes_placed=placed_bytes)
 
         body = _multi_bucket_update_body(
             dataclasses.replace(config, reg_weight=0.0)

@@ -11,7 +11,8 @@ ONE instrument threaded through every layer (docs/OBSERVABILITY.md):
 - :mod:`.metrics` — named counters/gauges/histograms; JSON snapshots
   (``metrics.json``) and Prometheus text exposition (``cli/serve.py``).
 - :mod:`.compile_events` — ``jax.monitoring`` backend-compile counter
-  (promoted from ``serving/stats.py``), feeding ``xla.compiles``.
+  (promoted from ``serving/stats.py``), feeding ``xla.compiles``, and
+  the compile-path spans ``xla.trace`` / ``xla.lower`` / ``xla.compile``.
 
 Drivers enable all of it in one place::
 
@@ -139,7 +140,6 @@ from photon_ml_tpu.obs.trace import (
 from photon_ml_tpu.obs.xla_cost import (
     CostBook,
     CostRecord,
-    annotate_span,
     cost_book,
     count_collectives,
     set_cost_book,
@@ -167,7 +167,6 @@ __all__ = [
     "xla_cache_hits",
     "CostBook",
     "CostRecord",
-    "annotate_span",
     "cost_book",
     "count_collectives",
     "set_cost_book",

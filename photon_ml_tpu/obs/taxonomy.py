@@ -75,7 +75,10 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
     (
         "xla",
         r"xla\.[a-z_]+(\..+)?",
-        "compile listener + cost book (xla.compiles, xla.cost.*)",
+        "compile listener + cost book: the xla.compiles / xla.cache_hits "
+        "counters, the compile-path spans xla.trace > xla.lower > "
+        "xla.compile (fun; on xla.compile cache_hit, retrieval_s: the "
+        "persistent-cache load where it hit), xla.cost.*",
     ),
     (
         "hbm",
@@ -136,7 +139,9 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         r"partition\.[a-z_]+(\..+)?",
         "multi-device partition layer: partition.entity_layout, one span a "
         "sharded random effect (rows, rows_per_shard, padded_rows, "
-        "exchange_block_rows, exchange_real_rows), "
+        "exchange_block_rows, exchange_real_rows), partition.coordinate, "
+        "one span a sharded coordinate's host regroup and placement "
+        "(random_effect, shards, rows, bytes_placed), "
         "balanced-blocking stats, shard-skew drill events "
         "(docs/PARALLEL.md)",
     ),

@@ -17,10 +17,9 @@ a first-class instrument:
   inlined in ``bench.py``). Records key by executable name + shape
   bucket, land in the metrics registry as ``xla.cost.*`` gauges, and
   emit a ``xla.cost_record`` instant event on the active tracer.
-- :func:`annotate_span` turns a record + a measured window into live
-  hardware attribution on a span: ``flops``, ``achieved_tflops``,
-  ``mfu``, ``bytes_per_s`` — how TRON/L-BFGS solves, GAME coordinate
-  passes, and serving score buckets surface live MFU in the trace.
+- :meth:`CostRecord.achieved` turns a record + a measured window into
+  hardware attribution: ``flops``, ``achieved_tflops``, ``mfu``,
+  ``bytes_per_s`` (``bench.py``'s MFU).
 
 Every analysis is best-effort: backends without a cost/memory analysis
 (or exotic executables) degrade to the caller-supplied analytic
@@ -53,7 +52,6 @@ __all__ = [
     "CostBook",
     "cost_book",
     "set_cost_book",
-    "annotate_span",
 ]
 
 
@@ -373,21 +371,3 @@ def set_cost_book(book: CostBook) -> CostBook:
     prev = _default
     _default = book
     return prev
-
-
-def annotate_span(
-    sp,
-    record: Optional[CostRecord],
-    seconds: float,
-    passes: float = 1.0,
-    peaks: Optional[DevicePeaks] = None,
-) -> None:
-    """Attach hardware attribution (``flops``/``achieved_tflops``/
-    ``mfu``/``bytes_per_s``) to a live span from a cost-book record and a
-    measured window. No-ops on a missing record, a non-positive window,
-    or the disabled-mode null span — callers never need to guard."""
-    if record is None or seconds is None or seconds <= 0:
-        return
-    attrs = record.achieved(seconds, passes=passes, peaks=peaks)
-    if attrs:
-        sp.set(**attrs)

@@ -450,6 +450,30 @@ def test_layout_spans_carry_the_counts():
     assert song["exchange_real_rows"] == data.num_rows
 
 
+def test_coordinate_spans_carry_the_bytes_placed():
+    """One partition.coordinate span a sharded coordinate, after the
+    layouts: its entity, width, partition rows and every byte it put on
+    the mesh."""
+    _, coords, lay, _ = build_sharded(4)
+    records = obs.recent_spans()
+    spans = [s for s in records if s[0] == "partition.coordinate"]
+    assert [s[6]["random_effect"] for s in spans] == ["userId", "songId"]
+    layouts_end = max(
+        s[2] for s in records if s[0] == "partition.entity_layout")
+    for span, name in zip(spans, ("per-user", "per-song")):
+        coord = coords[name]
+        placed = (
+            coord.reg_weights, coord._buckets, coord._entity_indices,
+            coord.row_features, coord.row_entities_local,
+            coord.full_offsets_base, coord._exchange,
+        )
+        assert layouts_end <= span[1]
+        assert span[6]["shards"] == 4
+        assert span[6]["rows"] == lay[name][2].padded_rows
+        assert span[6]["bytes_placed"] == sum(
+            leaf.nbytes for leaf in jtu.tree_leaves(placed))
+
+
 # -- (d) the compiled programs' collectives ----------------------------------
 
 

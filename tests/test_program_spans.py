@@ -571,6 +571,112 @@ class TestDeviceScopes:
 
 
 # ---------------------------------------------------------------------------
+# compile-path spans from the compile listener
+# ---------------------------------------------------------------------------
+
+COMPILE_PATH = ("xla.trace", "xla.lower", "xla.compile")
+
+
+class TestCompileSpans:
+    @pytest.mark.parametrize("installs", [1, 2])
+    def test_a_fresh_jit_leaves_one_record_of_each_step(self, installs):
+        """Installing the listener again records nothing twice; the
+        records nest under the span that asked for the compile, on the
+        ring's clock."""
+        for _ in range(installs):
+            obs.install_compile_listener()
+        x = jnp.ones((4,), jnp.float32)
+        fresh = jax.jit(lambda v: jax.lax.mul(v, v))
+        flight.reset_spans()  # x's own eager compiles
+        with obs.span("game.outer") as outer:
+            time.sleep(0.002)
+            fresh(x).block_until_ready()
+            time.sleep(0.002)
+        (o,) = _named("game.outer")
+        for name in COMPILE_PATH:
+            (r,) = _named(name)
+            assert r[PARENT_ID] == outer.span_id
+            assert o[START] < r[START] <= r[END] < o[END]
+            assert r[THREAD] == o[THREAD]
+        (trace,), (compiled,) = _named("xla.trace"), _named("xla.compile")
+        assert trace[ATTRS] == {"fun": "<lambda>"}
+        assert compiled[ATTRS]["fun"] == _named("xla.lower")[0][ATTRS]["fun"]
+        assert compiled[ATTRS]["cache_hit"] is False
+        assert compiled[ATTRS]["retrieval_s"] == 0.0
+
+    def test_a_second_call_leaves_none(self):
+        obs.install_compile_listener()
+        x = jnp.ones((4,), jnp.float32)
+        fresh = jax.jit(lambda v: jax.lax.mul(v, v))
+        fresh(x).block_until_ready()
+        assert all(_named(name) for name in COMPILE_PATH)
+        flight.reset_spans()
+        with obs.span("game.outer"):
+            fresh(x).block_until_ready()
+        assert [r[NAME] for r in obs.recent_spans()] == ["game.outer"]
+
+    def test_nested_traces_are_counted_once(self):
+        """An inner jit traces inside the outer one's trace: two nested
+        xla.trace records, whose union is the outer one's window."""
+        from chipbench import setup_spans
+
+        obs.install_compile_listener()
+
+        def doubled(v):  # lax primitives: no jitted jnp function inside
+            return jax.lax.add(v, v)
+
+        inner = jax.jit(doubled)
+        outer = jax.jit(lambda v: jax.lax.mul(inner(v), v))
+        x = jnp.ones((4,), jnp.float32)
+        flight.reset_spans()  # x's own eager compiles
+        outer(x).block_until_ready()
+        traces = _named("xla.trace")
+        assert [r[ATTRS]["fun"] for r in traces] == ["doubled", "<lambda>"]
+        (i, o) = traces
+        assert o[START] < i[START] <= i[END] < o[END]
+        later = time.perf_counter() + 1.0
+        run = _Run([("job", later, later + 1.0)], {})
+        assert setup_spans.setup_seconds(run, ("xla.trace",)) == (
+            pytest.approx(o[END] - o[START], rel=1e-9)
+        )
+
+    def test_the_cache_load_is_the_compile_of_a_hit(self, tmp_path):
+        """With a persistent cache and jax's in-memory caches cleared, the
+        same function's second compile is a load: cache_hit, and the
+        cache's retrieval time."""
+        from jax._src import compilation_cache
+
+        obs.install_compile_listener()
+        keys = {
+            "jax_compilation_cache_dir": str(tmp_path),
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            "jax_persistent_cache_min_entry_size_bytes": 0,
+        }
+        before = {k: getattr(jax.config, k) for k in keys}
+        x = jnp.ones((4,), jnp.float32)
+
+        def tripled(v):
+            return jax.lax.mul(v, jnp.float32(3.0))
+
+        flight.reset_spans()  # x's own eager compiles
+        try:
+            for k, v in keys.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+            for _ in range(2):
+                jax.clear_caches()
+                jax.jit(tripled)(x).block_until_ready()
+        finally:
+            for k, v in before.items():
+                jax.config.update(k, v)
+            compilation_cache.reset_cache()
+        first, second = (r[ATTRS] for r in _named("xla.compile"))
+        assert first["cache_hit"] is False and first["retrieval_s"] == 0.0
+        assert second["cache_hit"] is True and second["retrieval_s"] > 0
+        assert first["fun"] == second["fun"] == "jit(tripled)"
+
+
+# ---------------------------------------------------------------------------
 # the per-layer readers over a hand-made ring
 # ---------------------------------------------------------------------------
 
@@ -680,6 +786,34 @@ SERVE_WANT = {
 }
 
 
+def _setup_ring(t0, coordinate=True):
+    """Set-up's records on two threads before a window whose first job
+    starts at t0 + 100, and a compile of the window after it."""
+    def put(name, a, b, thread=1):
+        flight.note_span((name, t0 + a, t0 + b, 0, 0, thread, {}))
+
+    put("xla.trace", 1.5, 2.0)  # an inner jit, traced inside the outer
+    put("xla.trace", 1.0, 3.0)
+    put("xla.trace", 2.0, 2.5, thread=2)
+    put("xla.lower", 3.0, 3.25)
+    put("xla.compile", 3.25, 4.25)
+    put("partition.entity_layout", 5.0, 7.0)
+    if coordinate:
+        put("partition.coordinate", 7.0, 8.0)
+    for name in ("xla.trace", "xla.lower", "xla.compile"):
+        put(name, 100.5, 101.0)  # ends in the window: not set-up's
+    return _Run([("job", t0 + 100.0, t0 + 101.0)], {"jobs": 1})
+
+
+SETUP_WANT = {
+    # thread 1's union (1.0 .. 3.0) and thread 2's 0.5
+    "setup.trace_s": 2.5,
+    "setup.lower_s": 0.25,
+    "setup.compile_s": 1.0,
+    "shard.layout_s": 3.0,
+}
+
+
 class TestLayerMetricReaders:
     @pytest.mark.parametrize("metric", sorted(TRAIN_WANT))
     def test_training_reader_over_a_hand_made_ring(self, metric):
@@ -718,6 +852,34 @@ class TestLayerMetricReaders:
         run = _training_ring(t0)
         assert obs.spans_dropped() > 0
         assert _read("dispatch.ms_per_job", run) == pytest.approx(1010.0)
+
+    @pytest.mark.parametrize("metric", sorted(SETUP_WANT))
+    def test_setup_reader_over_a_hand_made_ring(self, metric):
+        run = _setup_ring(time.perf_counter())
+        assert _read(metric, run) == pytest.approx(
+            SETUP_WANT[metric], rel=1e-9
+        )
+
+    @pytest.mark.parametrize("metric", sorted(SETUP_WANT))
+    def test_setup_reader_gives_none_on_drops_or_without_the_span(
+        self, metric
+    ):
+        """A ring that dropped any record no longer vouches for set-up; a
+        checkout without the span (partition.coordinate: the layout alone
+        would be a partial sum) reads nothing."""
+        flight.reset_spans(capacity=8)
+        run = _setup_ring(time.perf_counter())
+        assert obs.spans_dropped() > 0
+        assert _read(metric, run) is None
+        flight.reset_spans()
+        run = _setup_ring(time.perf_counter(), coordinate=False)
+        got = _read(metric, run)
+        if metric == "shard.layout_s":
+            assert got is None
+        else:
+            assert got == pytest.approx(SETUP_WANT[metric], rel=1e-9)
+        flight.reset_spans()
+        assert _read(metric, run) is None
 
     def test_glm_job_without_fetches_reads_zero_and_no_root_reads_none(self):
         t0 = time.perf_counter()
