@@ -255,6 +255,44 @@ def fill_offsets(gathered, starts, masks):
     return out
 
 
+def spread_lanes(lanes, starts, masks, size):
+    """The inverse of :func:`fill_offsets`: every bucket's per-lane values
+    ``lanes[b]`` (E_b, k) laid over the entries of
+    :func:`offsets_gather_maps`' ``perm``, in its order, as (size, k),
+    ``size`` at least the entries, zeros past them. An entry holds its
+    lane's value: what a gather of the lanes' table rows by each entry's
+    entity reads, with no table in between.
+
+    Slot j of bucket b is one run of entries, lanes ``lo_j .. hi_j - 1``
+    (the first and last lane holding it, read off the mask); it is
+    written by one lanes-wide window at ``starts[b][j]``, selected to that
+    lane range, so that windows that overlap leave every entry its own
+    lane's value. A wasted entry of an unordered design takes its lane's
+    value too. Built (k, .), entries minor, like the held rows' features:
+    a run then writes k contiguous rows of the bucket's width."""
+    pad = max(m.shape[0] for m in masks)
+    k = lanes[0].shape[1]
+    out = jnp.zeros((k, size + 2 * pad), lanes[0].dtype)
+    for values, start, mask in zip(lanes, starts, masks):
+        held = mask > 0  # (E_b, R_b)
+        width = held.shape[0]
+        some = held.any(axis=0)
+        lo = jnp.where(some, jnp.argmax(held, axis=0), 0)
+        hi = jnp.where(some, width - jnp.argmax(held[::-1], axis=0), 0)
+        lane = jnp.arange(width)
+        columns = values.T  # (k, E_b)
+
+        def put(j, out):
+            at = start[j]
+            window = jax.lax.dynamic_slice_in_dim(out, at, width, axis=1)
+            own = (lane >= lo[j]) & (lane < hi[j])
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, jnp.where(own, columns, window), at, axis=1)
+
+        out = jax.lax.fori_loop(0, start.shape[0], put, out)
+    return jax.lax.slice_in_dim(out, pad, pad + size, axis=1).T
+
+
 def _grouped_rows(eids: np.ndarray, seed: int):
     """Vectorized per-entity grouping with a uniform random shuffle inside
     each entity (the reservoir-sample analog; no Python per-entity loop).

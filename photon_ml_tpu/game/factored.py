@@ -17,11 +17,13 @@ Training alternates (numInnerIterations x):
       materialized (h, d*k) matrix would need.
 
 Accepts a :class:`BucketedRandomEffectDesign` (or a single global-cap
-design, wrapped as one bucket): phase (a) runs per bucket with
-gather/scatter against the global gamma table; phase (b) has no lanes and
-reads a compact copy of the held rows (:class:`HeldRowDesign`), built once
-at construction, not the padded buckets, of which about half the slots
-hold no row.
+design, wrapped as one bucket): phase (a) runs per bucket, on lanes;
+phase (b) has no lanes and reads a compact copy of the held rows
+(:class:`HeldRowDesign`), built once at construction, not the padded
+buckets, of which about half the slots hold no row, each held row's gamma
+laid from its lane by runs. Inside an update gamma stays in the lanes:
+the global table is read once, for the first warm starts, and written
+once, at the end.
 
 ``MatrixFactorizationModel`` (``model/MatrixFactorizationModel.scala:30-134``)
 is the inference-side pairing: two latent tables scored by gathered dot.
@@ -39,10 +41,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from photon_ml_tpu import obs
 from photon_ml_tpu.core.types import _pytree_dataclass
 from photon_ml_tpu.game.coordinates import (
     CoordinateConfig,
     _design_offsets_maps,
+    _lane_of_entity,
     _make_solve,
 )
 from photon_ml_tpu.game.data import (
@@ -51,6 +55,7 @@ from photon_ml_tpu.game.data import (
     fill_offsets,
     gather_held_offsets,
     held_slot_values,
+    spread_lanes,
 )
 from photon_ml_tpu.models.training import OptimizerType
 from photon_ml_tpu.ops.losses import loss_for_task
@@ -241,13 +246,12 @@ class HeldRowDesign:
 
     ``weights`` is the slot's weight times its mask (the cap's
     weight-preserving rescale kept; 0 on a wasted ``perm`` entry of an
-    unordered design and on padding); ``entity`` the gamma table row of
-    the entry's lane."""
+    unordered design and on padding). An entry's gamma is its lane's,
+    laid by ``game.data.spread_lanes``."""
 
     features: jax.Array  # (d, H)
     weights: jax.Array  # (H,)
     labels: jax.Array  # (H,)
-    entity: jax.Array  # (H,) int32
 
 
 def _held_vector(a, held):
@@ -292,9 +296,6 @@ def _build_held_rows(design, perm, row_features):
     weights = held_slot_values(
         [host(b.weights) * m for b, m in zip(buckets, masks)], masks)
     labels = held_slot_values([host(b.labels) for b in buckets], masks)
-    entity = held_slot_values(
-        [np.broadcast_to(np.asarray(ei)[:, None], m.shape)
-         for ei, m in zip(design.entity_index, masks)], masks)
     size = -(-weights.size // HELD_ROWS_ALIGN) * HELD_ROWS_ALIGN
 
     def pad(v, dtype):
@@ -305,7 +306,6 @@ def _build_held_rows(design, perm, row_features):
             row_features.astype(buckets[0].features.dtype), perm, size),
         weights=pad(weights, buckets[0].weights.dtype),
         labels=pad(labels, buckets[0].labels.dtype),
-        entity=pad(entity, np.int32),
     ), int(sum(np.count_nonzero(m > 0) for m in masks))
 
 
@@ -424,13 +424,15 @@ def _make_factored_update(
 ):
     """ONE jitted call for a whole factored update and its rescore: the
     eager ``update`` dispatches it, the fused coordinate-descent pass
-    inlines it. Every bucket's ``entity_index`` and the held rows are
-    arguments (leaves of ``fused_state``), so the program holds no lane
-    map and no copy of the design as a constant. Its device time splits
-    by ``jax.named_scope``: ``factored/offsets``, ``/project``,
-    ``/latent_solve``, ``/table_write``, ``/gamma_gather`` (the lanes'
-    warm starts, and the held rows' gammas), ``/projection_solve``,
-    ``/score``.
+    inlines it. Every bucket's ``entity_index``, the entity -> lane map and
+    the held rows are arguments (leaves of ``fused_state``), so the program
+    holds no lane map and no copy of the design as a constant. Its device
+    time splits by ``jax.named_scope``: ``factored/offsets``,
+    ``/gamma_gather`` (the first warm starts, from the table),
+    ``/project``, ``/latent_solve``, ``/gamma_spread`` (the lanes'
+    solutions laid over the held rows by runs, ``game.data.spread_lanes``),
+    ``/projection_solve``, ``/table_write`` (once, after the last inner
+    iteration: a gather through the entity -> lane map), ``/score``.
     Both regularization weights are trace-time constants of the two inner
     solves."""
     return _make_factored_update_cached(
@@ -452,37 +454,50 @@ def _make_factored_update_cached(
         return jax.named_scope("factored/" + name)
 
     def update_all(
-        params, full_offsets, entity_indices, offsets_maps, buckets, held,
-        row_features, row_entities,
+        params, full_offsets, entity_indices, lane_of_entity, offsets_maps,
+        buckets, held, row_features, row_entities,
     ):
         gamma, b = params.gamma, params.projection
+        perm, starts = offsets_maps
+        masks = [bk.mask for bk in buckets]
+        # runs while an update is traced, never in a pass
+        obs.registry().inc(
+            "game.factored.gamma_spread_runs",
+            num_inner_iterations * sum(s.shape[0] for s in starts),
+        )
+        obs.registry().inc("game.factored.table_write.inverse_gather")
         # the residual offsets do not change inside an update: one compact
         # gather, an index a held row, serves every inner iteration and both
         # solves, the B solve's as it is and the lanes' through the fills
         with scope("offsets"):
-            perm, starts = offsets_maps
             gathered = gather_held_offsets(full_offsets, perm)
-            bucket_offsets = fill_offsets(
-                gathered, starts, [bk.mask for bk in buckets]
-            )
+            bucket_offsets = fill_offsets(gathered, starts, masks)
             held_offsets = _held_vector(gathered, held)
+        # inside an update gamma lives in the lanes: the table is read once,
+        # for the first warm starts, and written once, at the end; an entity
+        # sits in at most one lane, so a lane's last solution is its row
+        with scope("gamma_gather"):
+            solved = [
+                jnp.take(gamma, eidx, axis=0, mode="clip")
+                for eidx in entity_indices
+            ]
         lane_tapes = [[] for _ in buckets]
         projection_tape = []
         for _ in range(num_inner_iterations):
             # (a) latent-space per-entity solves, bucket by bucket
-            for tape, eidx, bucket, offsets in zip(
-                lane_tapes, entity_indices, buckets, bucket_offsets
+            for i, (tape, bucket, offsets) in enumerate(
+                zip(lane_tapes, buckets, bucket_offsets)
             ):
-                with scope("gamma_gather"):
-                    g0 = jnp.take(gamma, eidx, axis=0, mode="clip")
                 with scope("project"):
                     latent_feats = _einsum(
                         "erd,dk->erk", bucket.features, b
                     )
                 with scope("latent_solve"):
                     result = re_solve(
-                        g0,
-                        jnp.full((eidx.shape[0],), reg_weight, gamma.dtype),
+                        solved[i],
+                        jnp.full(
+                            (solved[i].shape[0],), reg_weight, gamma.dtype
+                        ),
                         latent_feats,
                         bucket.labels,
                         offsets,
@@ -493,17 +508,28 @@ def _make_factored_update_cached(
                     (result.reason, result.iterations,
                      final_grad_norm(result))
                 )
-                with scope("table_write"):
-                    gamma = gamma.at[eidx].set(result.w, mode="drop")
-            # (b) shared projection over the held rows, einsum-contracted
-            with scope("gamma_gather"):
-                gamma_rows = jnp.take(gamma, held.entity, axis=0, mode="clip")
+                solved[i] = result.w
+            # (b) shared projection over the held rows, einsum-contracted;
+            # a held row's gamma is its lane's, laid by runs
+            with scope("gamma_spread"):
+                gamma_rows = spread_lanes(
+                    solved, starts, masks, held.weights.shape[0]
+                )
             with scope("projection_solve"):
                 latent_result = latent_solve(
                     b, gamma_rows, held_offsets, held
                 )
                 b = latent_result.w.reshape(b.shape)
                 projection_tape.append(_projection_tracker(latent_result))
+        # the lanes are a fixed permutation of the table rows they hold: one
+        # gather through the entity -> lane map, where a scatter a bucket
+        # cost about ten times as much a row (PERF.md section 6)
+        with scope("table_write"):
+            written = jnp.take(
+                jnp.concatenate(solved), jnp.maximum(lane_of_entity, 0),
+                axis=0, mode="clip",
+            )
+            gamma = jnp.where(lane_of_entity[:, None] >= 0, written, gamma)
         new_params = FactoredParams(gamma=gamma, projection=b)
         with scope("score"):
             scores = _score_rows(new_params, row_features, row_entities)
@@ -554,6 +580,9 @@ class FactoredRandomEffectCoordinate:
         self._offsets_maps = _design_offsets_maps(design)
         self._entity_indices = tuple(
             jnp.asarray(ei) for ei in design.entity_index
+        )
+        self._lane_of_entity = jnp.asarray(
+            _lane_of_entity(design.entity_index, design.num_entities)
         )
         # the B solve's rows, and how many slots the mask holds among them
         # (game.factored.projection_rows counts those)
@@ -638,6 +667,7 @@ class FactoredRandomEffectCoordinate:
             params,
             self.full_offsets_base + partial_scores,
             self._entity_indices,
+            self._lane_of_entity,
             self._offsets_maps,
             tuple(self.design.buckets),
             self._held,
@@ -660,6 +690,7 @@ class FactoredRandomEffectCoordinate:
         return (
             tuple(self.design.buckets),
             self._entity_indices,
+            self._lane_of_entity,
             self._offsets_maps,
             self._held,
             self.row_features,
@@ -672,6 +703,7 @@ class FactoredRandomEffectCoordinate:
         (
             buckets,
             c._entity_indices,
+            c._lane_of_entity,
             c._offsets_maps,
             c._held,
             c.row_features,

@@ -55,6 +55,8 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         ".projection_rows (passes x held slots, mask > 0) / "
         ".projection_cg_iterations "
         "of a factored coordinate's tracker, "
+        "game.factored.gamma_spread_runs and "
+        "game.factored.table_write.inverse_gather of its traced update, "
         "...), the "
         "game.offsets_gather.gather_indices / .padded_slots gauges of a "
         "random-effect coordinate's residual-offset gather and the "

@@ -7,6 +7,7 @@ Hessian-vector product at a random point; a three-coordinate descent fused
 against unfused; and what its tracker says of every lane and of the B solve.
 """
 
+import copy
 import dataclasses
 import functools
 
@@ -29,7 +30,14 @@ from photon_ml_tpu.game import (
     build_bucketed_random_effect_design,
 )
 from photon_ml_tpu.game import factored as factored_mod
-from photon_ml_tpu.game.data import gather_held_offsets, offsets_gather_maps
+from photon_ml_tpu.game.coordinates import _make_solve
+from photon_ml_tpu.game.data import (
+    fill_offsets,
+    gather_held_offsets,
+    held_slot_values,
+    offsets_gather_maps,
+    spread_lanes,
+)
 from photon_ml_tpu.game.factored import (
     FactoredConfig,
     FactoredParams,
@@ -38,7 +46,7 @@ from photon_ml_tpu.game.factored import (
 from photon_ml_tpu.models.training import OptimizerType
 from photon_ml_tpu.ops import metrics as metrics_mod
 from photon_ml_tpu.ops.losses import loss_for_task
-from photon_ml_tpu.solvers.common import ConvergenceReason
+from photon_ml_tpu.solvers.common import ConvergenceReason, final_grad_norm
 
 CAP = multi.CAP
 N_USERS, N_SONGS = 8, 40
@@ -279,12 +287,25 @@ def test_projection_gradient_and_hvp_against_the_kronecker_design():
                                np.asarray(want_b), rtol=1e-10, atol=1e-11)
 
 
+def held_entity(design, size):
+    """(size,) int32, host: the gamma table row of every held-row entry's
+    lane, in ``perm``'s order (a wasted entry of an unordered design names
+    its lane's entity; a sharding pad lane names ``num_entities``), 0 past
+    the entries."""
+    masks = [np.asarray(b.mask) for b in design.buckets]
+    entity = held_slot_values(
+        [np.broadcast_to(np.asarray(ei)[:, None], m.shape)
+         for ei, m in zip(design.entity_index, masks)], masks)
+    return np.pad(entity, (0, size - entity.size)).astype(np.int32)
+
+
 def held_inputs(coord, gamma, offsets):
     """(gamma a held row, residual offset a held row), in the coordinate's
-    held-row shape: what its update hands the B solve."""
+    held-row shape: what its update hands the B solve, read here from the
+    table by each entry's entity."""
+    entity = held_entity(coord.design, coord._held.weights.shape[0])
     return (
-        jnp.take(jnp.asarray(gamma), coord._held.entity, axis=0,
-                 mode="clip"),
+        jnp.take(jnp.asarray(gamma), entity, axis=0, mode="clip"),
         factored_mod._held_vector(gather_held_offsets(
             jnp.asarray(offsets), coord._offsets_maps[0]), coord._held),
     )
@@ -518,30 +539,32 @@ def test_held_rows_give_the_padded_buckets_objective(ordered):
                want_hvp(vec_b, direction)) <= 1e-6
 
 
-@pytest.mark.parametrize("shuffle", [False, True])
-@pytest.mark.parametrize("sentinels", [0, 5])
-def test_held_rows_are_the_perm_entries_in_order(shuffle, sentinels):
-    """The held-row build against a plain loop on buckets of prefix-held
-    slots, each a row of its own, lanes in count order or shuffled, with
-    ``sentinels`` empty lanes a bucket: every ``perm`` entry (slot-major,
-    slot j over the lanes from the first to the last that hold it) carries
-    its row's features, weight x mask, label and lane's entity, in that
-    order, then zeros."""
+def prefix_held_design(shapes, shuffle, sentinels, pads=0, seed=17):
+    """(rows, design) of buckets of prefix-held slots, each slot a row of
+    its own, lanes in count order or shuffled: ``shapes`` the (lanes,
+    depth) of each bucket before ``sentinels`` empty lanes and ``pads``
+    sharding pad lanes (entity index == ``num_entities``) a bucket."""
     from photon_ml_tpu.game.data import (
         BucketedRandomEffectDesign,
         RandomEffectDesign,
     )
 
-    rng = np.random.default_rng(17)
-    n = 400
+    rng = np.random.default_rng(seed)
+    num_entities = 100
+    n = sum(lanes * depth for lanes, depth in shapes)
     rows = jnp.asarray(rng.normal(size=(n, 5)), jnp.float32)
     free = iter(rng.permutation(n))
+    entities = iter(rng.permutation(num_entities))  # one lane an entity
     buckets, index = [], []
-    for lanes, depth in ((23, 3), (9, 11), (4, 30)):
+    for lanes, depth in shapes:
         count = np.sort(rng.integers(0, depth + 1, size=lanes))
-        count = np.concatenate([count, np.zeros(sentinels, int)])
+        count = np.concatenate([count, np.zeros(sentinels + pads, int)])
+        entity = np.concatenate([
+            [next(entities) for _ in range(lanes + sentinels)],
+            np.full(pads, num_entities)]).astype(np.int32)
         if shuffle:
-            count = rng.permutation(count)
+            order = rng.permutation(count.size)
+            count, entity = count[order], entity[order]
         mask = (np.arange(depth)[None, :] < count[:, None]).astype(np.float32)
         row_index = np.full(mask.shape, -1, np.int32)
         for lane, slot in zip(*np.nonzero(mask)):
@@ -554,11 +577,25 @@ def test_held_rows_are_the_perm_entries_in_order(shuffle, sentinels):
                                 jnp.float32),
             mask=jnp.asarray(mask),
             row_index=jnp.asarray(row_index)))
-        index.append(rng.permutation(100)[:mask.shape[0]].astype(np.int32))
-    design = BucketedRandomEffectDesign(
-        buckets=buckets, entity_index=index, num_entities=100)
-    perm, _ = offsets_gather_maps([(np.asarray(b.row_index), b.mask)
-                                   for b in buckets])
+        index.append(entity)
+    return rows, BucketedRandomEffectDesign(
+        buckets=buckets, entity_index=index, num_entities=num_entities)
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+@pytest.mark.parametrize("sentinels", [0, 5])
+def test_held_rows_are_the_perm_entries_in_order(shuffle, sentinels):
+    """The held-row build against a plain loop on buckets of prefix-held
+    slots, each a row of its own, lanes in count order or shuffled, with
+    ``sentinels`` empty lanes a bucket: every ``perm`` entry (slot-major,
+    slot j over the lanes from the first to the last that hold it) carries
+    its row's features, weight x mask, label and, spread from the lanes,
+    its lane's entity, in that order, then zeros."""
+    rows, design = prefix_held_design(((23, 3), (9, 11), (4, 30)), shuffle,
+                                      sentinels)
+    buckets, index = design.buckets, design.entity_index
+    perm, starts = offsets_gather_maps(
+        [(np.asarray(b.row_index), b.mask) for b in buckets])
     held, held_slots = factored_mod._build_held_rows(
         design, jnp.asarray(perm), rows)
 
@@ -591,8 +628,209 @@ def test_held_rows_are_the_perm_entries_in_order(shuffle, sentinels):
                                   padded(want["weights"], np.float32))
     np.testing.assert_array_equal(np.asarray(held.labels),
                                   padded(want["labels"], np.float32))
-    np.testing.assert_array_equal(np.asarray(held.entity),
-                                  padded(want["entity"], np.int32))
+    spread = spread_lanes(
+        [jnp.asarray(ei, jnp.float32)[:, None] for ei in index],
+        tuple(jnp.asarray(s) for s in starts), [b.mask for b in buckets],
+        size)
+    assert spread.shape == (size, 1)
+    np.testing.assert_array_equal(np.asarray(spread)[:, 0],
+                                  padded(want["entity"], np.float32))
+
+
+@pytest.mark.parametrize("shapes, shuffle, sentinels, pads", [
+    (((23, 3), (9, 11), (4, 30)), False, 0, 0),
+    (((23, 3), (9, 11), (4, 30)), True, 0, 0),
+    (((23, 3), (9, 11), (4, 30)), True, 5, 3),
+    (((40, 2), (7, 6), (3, 9), (2, 40)), False, 0, 4),
+    (((40, 2), (7, 6), (3, 9), (2, 40)), True, 2, 4),
+    (((1, 1), (60, 5)), True, 0, 0),
+])
+def test_spread_lanes_is_the_gather_of_the_scattered_table(
+        shapes, shuffle, sentinels, pads):
+    """The lanes' values spread over the held rows by runs, against the
+    table round trip they replace: every bucket's lanes scattered into the
+    (E, k) table, then gathered a held row by its lane's entity. Bit for
+    bit at every entry whose lane holds an entity (the held rows, and the
+    wasted entries of an unordered design), zeros past the entries, and
+    finite on the wasted entries of sharding pad lanes, which weigh 0 and
+    which the scatter drops; buckets in count order or shuffled, with
+    empty lanes and pad lanes, of several shapes."""
+    rows, design = prefix_held_design(shapes, shuffle, sentinels, pads)
+    perm, starts = offsets_gather_maps(
+        [(np.asarray(b.row_index), b.mask) for b in design.buckets])
+    assert (perm.size > sum(int(np.count_nonzero(b.mask))
+                            for b in design.buckets)) == shuffle
+    size = -(-perm.size // 64) * 64 + 64
+    rng = np.random.default_rng(3)
+    lanes = [jnp.asarray(rng.normal(size=(b.num_entities, 3)), jnp.float32)
+             for b in design.buckets]
+    table = jnp.asarray(rng.normal(size=(design.num_entities, 3)),
+                        jnp.float32)
+    for eidx, w in zip(design.entity_index, lanes):
+        table = table.at[jnp.asarray(eidx)].set(w, mode="drop")
+    entity = held_entity(design, size)
+    want = np.asarray(jnp.take(table, entity, axis=0, mode="clip"))
+
+    got = np.asarray(spread_lanes(
+        lanes, tuple(jnp.asarray(s) for s in starts),
+        [b.mask for b in design.buckets], size))
+    assert got.shape == (size, 3)
+    real = np.arange(size) < perm.size
+    of_entity = real & (entity < design.num_entities)
+    assert (of_entity.sum() < perm.size) == (shuffle and pads > 0)
+    np.testing.assert_array_equal(got[of_entity], want[of_entity])
+    assert np.all(np.isfinite(got))
+    assert not np.any(got[~real])
+
+
+def round_trip_update(coord):
+    """The factored update as it stood before gamma stayed in the lanes,
+    jitted with the coordinate's update signature: every inner iteration
+    gathers each bucket's warm start from the table, scatters the lanes'
+    solutions back into it a bucket at a time, and gathers the B solve's
+    gamma rows from it a held row at a time. The oracle of the update that
+    replaced it."""
+    re_solve = _make_solve(coord.config, batched=True)
+    latent_solve = factored_mod._make_latent_solve(coord._latent_cfg)
+    reg_weight = coord.config.reg_weight
+    entity = held_entity(coord.design, coord._held.weights.shape[0])
+
+    def update_all(params, full_offsets, entity_indices, lane_of_entity,
+                   offsets_maps, buckets, held, row_features, row_entities):
+        gamma, b = params.gamma, params.projection
+        perm, starts = offsets_maps
+        gathered = gather_held_offsets(full_offsets, perm)
+        bucket_offsets = fill_offsets(gathered, starts,
+                                      [bk.mask for bk in buckets])
+        held_offsets = factored_mod._held_vector(gathered, held)
+        lane_tapes = [[] for _ in buckets]
+        projection_tape = []
+        for _ in range(coord.factored.num_inner_iterations):
+            for tape, eidx, bucket, offsets in zip(
+                    lane_tapes, entity_indices, buckets, bucket_offsets):
+                g0 = jnp.take(gamma, eidx, axis=0, mode="clip")
+                result = re_solve(
+                    g0, jnp.full((eidx.shape[0],), reg_weight, gamma.dtype),
+                    factored_mod._einsum("erd,dk->erk", bucket.features, b),
+                    bucket.labels, offsets, bucket.weights, bucket.mask)
+                tape.append((result.reason, result.iterations,
+                             final_grad_norm(result)))
+                gamma = gamma.at[eidx].set(result.w, mode="drop")
+            gamma_rows = jnp.take(gamma, entity, axis=0, mode="clip")
+            latent_result = latent_solve(b, gamma_rows, held_offsets, held)
+            b = latent_result.w.reshape(b.shape)
+            projection_tape.append(
+                factored_mod._projection_tracker(latent_result))
+        new_params = FactoredParams(gamma=gamma, projection=b)
+        scores = factored_mod._score_rows(new_params, row_features,
+                                          row_entities)
+        tracker = factored_mod.FactoredUpdateTracker(
+            tuple(tuple(jnp.stack(f) for f in zip(*tape))
+                  for tape in lane_tapes),
+            *(jnp.stack(f) for f in zip(*projection_tape)))
+        return new_params, tracker, scores
+
+    return jax.jit(update_all)
+
+
+def assert_updates_equal(coord, got, want):
+    """Two (params, tracker, scores) of one update bit for bit: the table,
+    B, every real lane's record of every inner iteration, the B solves'
+    records and the rescored rows. A sharding pad lane's record is left
+    out: no history reads it, and its warm start differs (the table's
+    last row, clipped, against its own last solution)."""
+    (params, tracker, scores), (want_params, want_tracker, want_scores) = (
+        jax.device_get(got), jax.device_get(want))
+    np.testing.assert_array_equal(params.gamma, want_params.gamma)
+    np.testing.assert_array_equal(params.projection, want_params.projection)
+    np.testing.assert_array_equal(scores, want_scores)
+    valid = coord._valid_lanes
+    assert not all(v.all() for v in valid)  # there are pad lanes to skip
+    for lanes, want_lanes, v in zip(tracker.lanes, want_tracker.lanes,
+                                    valid):
+        for field, want_field in zip(lanes, want_lanes):
+            np.testing.assert_array_equal(np.asarray(field)[:, v],
+                                          np.asarray(want_field)[:, v])
+    for name in ("projection_iterations", "projection_cg_iterations",
+                 "projection_passes", "projection_reason",
+                 "projection_grad_norm"):
+        np.testing.assert_array_equal(getattr(tracker, name),
+                                      getattr(want_tracker, name))
+
+
+@pytest.mark.parametrize("shuffle", [False, True])
+@pytest.mark.parametrize("fuse", [False, True])
+def test_update_against_the_table_round_trip(fuse, shuffle):
+    """Two updates of two inner iterations (the second from a table the
+    first wrote), each once through the coordinate and once through the
+    table round trip it replaced, unfused (the coordinate's own dispatch)
+    and fused (inside an outer jit, its state as arguments, as the fused
+    pass runs it), lanes as built and shuffled: bit-equal."""
+    design = song_design(jnp.float64)
+    if shuffle:
+        design = shuffled_lanes(design, 7)
+    coord, _ = song_coordinate(jnp.float64, 2, 2, 3, 0.0, design=design)
+    oracle = copy.copy(coord)
+    oracle._update_all = round_trip_update(coord)
+
+    def update(c, params, offsets):
+        if not fuse:
+            return c.update_step(params, offsets)
+        return jax.jit(
+            lambda state, p, o: c.with_fused_state(state).update_step(p, o)
+        )(c.fused_state(), params, offsets)
+
+    offsets = jnp.asarray(other_scores())
+    params = want_params = coord.initial_params()
+    for _ in range(2):
+        got, want = update(coord, params, offsets), update(
+            oracle, want_params, offsets)
+        assert_updates_equal(coord, got, want)
+        params, want_params = got[0], want[0]
+    assert np.any(np.asarray(params.gamma) != 0.0)
+
+
+def test_fused_descent_against_the_table_round_trip():
+    """The three-coordinate descent, fused, with the factored update as it
+    is and as the table round trip: the same model and history, bit for
+    bit."""
+    cd, other = descent(True), descent(True)
+    song = other.coordinates["per-song"]
+    song._update_all = round_trip_update(song)
+    model, history = cd.run(num_iterations=2)
+    want_model, want_history = other.run(num_iterations=2)
+    for name in ("fixed", "per-user"):
+        np.testing.assert_array_equal(model.params[name],
+                                      want_model.params[name])
+    for leaf in ("gamma", "projection"):
+        np.testing.assert_array_equal(
+            getattr(model.params["per-song"], leaf),
+            getattr(want_model.params["per-song"], leaf))
+    for h, o in zip(history, want_history):
+        assert (h.coordinate, h.objective, h.solver_iterations,
+                h.convergence_histogram, h.inner_iterations) == (
+            o.coordinate, o.objective, o.solver_iterations,
+            o.convergence_histogram, o.inner_iterations)
+
+
+def test_spread_and_table_write_counters_fire_once_a_traced_update():
+    """``game.factored.gamma_spread_runs`` books the runs a traced update
+    spreads (every slot of every bucket, every inner iteration) and
+    ``game.factored.table_write.inverse_gather`` one a traced update; a
+    second update of the same shapes traces nothing."""
+    runs, writes = ("game.factored.gamma_spread_runs",
+                    "game.factored.table_write.inverse_gather")
+    assert obs.taxonomy.matches(runs) and obs.taxonomy.matches(writes)
+    factored_mod._make_factored_update_cached.cache_clear()
+    coord, _ = song_coordinate(jnp.float64, 2, 2, 3, 0.0)
+    reg = obs.registry()
+    before = reg.counter(runs).value, reg.counter(writes).value
+    offsets = jnp.asarray(other_scores())
+    params, _ = coord.update(coord.initial_params(), offsets)
+    coord.update(params, offsets)
+    slots = sum(b.mask.shape[1] for b in coord.design.buckets)
+    assert reg.counter(runs).value - before[0] == 2 * slots
+    assert reg.counter(writes).value - before[1] == 1
 
 
 def test_counters_are_fed_once_an_update():
