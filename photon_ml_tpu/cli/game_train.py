@@ -53,13 +53,14 @@ from photon_ml_tpu.game.factored import (
     FactoredRandomEffectCoordinate,
 )
 from photon_ml_tpu.game.projected import (
+    IndexMapRandomEffectCoordinate,
     ProjectedRandomEffectCoordinate,
     build_index_map_columns,
     parse_projector_spec,
     project_design_and_rows,
 )
 from photon_ml_tpu.game.projectors import build_random_projection
-from photon_ml_tpu.game.scoring import score_game_data
+from photon_ml_tpu.game.scoring import CompactReTable, score_game_data
 
 from photon_ml_tpu.io.models import save_game_model
 from photon_ml_tpu.io.vocab import FeatureVocabulary
@@ -264,13 +265,14 @@ def build_coordinates(
             )
             own = data if es_layout is None else es_layout.data
             if sparse_ops.is_sparse(own.features[spec.shard]):
-                # wide-sparse random effect: INDEX_MAP projection straight
-                # from the ELL (config.validate() guarantees the projector)
+                # wide-sparse random effect: INDEX_MAP in ragged compact
+                # columns straight from the ELL (config.validate()
+                # guarantees the projector)
                 cache_key = f"{name}\x00sparse_projected"
                 if design_cache is not None and cache_key in design_cache:
                     coords[name] = design_cache[cache_key].with_config(cfg)
                 else:
-                    coord = ProjectedRandomEffectCoordinate.from_sparse_shard(
+                    coord = IndexMapRandomEffectCoordinate.from_sparse_shard(
                         data,
                         spec.random_effect,
                         spec.shard,
@@ -481,14 +483,19 @@ def build_coordinates(
 def materialize_original_space(model: GameModel, coords: Dict) -> GameModel:
     """Back-project any projected coordinate's table so the model is in
     original feature space (``RandomEffectModelInProjectedSpace.scala:31-97``
-    — persistence and scoring never see projected coefficients), and
+    — persistence and scoring never see projected coefficients; an
+    INDEX_MAP coordinate over a sparse shard gives per-entity (column,
+    value) lists, a ``CompactReTable``, never an (E, d) table), and
     bridge entity-SHARDED tables from their stored (shard-major, padded)
     layout back to the global entity order (docs/PARALLEL.md)."""
     from photon_ml_tpu.game import EntityShardedRandomEffectCoordinate
 
     def bridge(n, p):
         c = coords.get(n)
-        if isinstance(c, ProjectedRandomEffectCoordinate):
+        if isinstance(
+            c, (ProjectedRandomEffectCoordinate,
+                IndexMapRandomEffectCoordinate)
+        ):
             return c.back_project(p)
         if isinstance(c, EntityShardedRandomEffectCoordinate):
             return jnp.asarray(c.global_table(p))
@@ -1039,7 +1046,9 @@ def _run_game_training(
                     )
 
                     plain_coord = not isinstance(
-                        coord, ProjectedRandomEffectCoordinate
+                        coord,
+                        (ProjectedRandomEffectCoordinate,
+                         IndexMapRandomEffectCoordinate),
                     ) and not hasattr(coord, "factored")
                     if (
                         p is not None
@@ -1278,8 +1287,10 @@ def _run_game_training(
                 else os.path.join(params.output_dir, "all", str(idx))
             )
             save_params = {
-                # FactoredParams pass through whole (latent wire format)
-                n: p if hasattr(p, "gamma") else np.asarray(p)
+                # FactoredParams pass through whole (latent wire format),
+                # per-entity lists as they are
+                n: p if hasattr(p, "gamma") or isinstance(
+                    p, CompactReTable) else np.asarray(p)
                 for n, p in entry["model"].params.items()
             }
             save_shards = shards_by_coord

@@ -31,6 +31,7 @@ from photon_ml_tpu.game.data import (
     RandomEffectDesign,
     RowExchangePlan,
     build_bucketed_random_effect_design,
+    build_index_map_design,
     build_random_effect_design,
     entity_partition_game_data,
     entity_partition_rows,
@@ -52,6 +53,7 @@ from photon_ml_tpu.game.factored import (
     MatrixFactorizationModel,
 )
 from photon_ml_tpu.game.projected import (
+    IndexMapRandomEffectCoordinate,
     ProjectedRandomEffectCoordinate,
     build_index_map_columns,
     parse_projector_spec,
@@ -62,6 +64,7 @@ __all__ = [
     "FactoredParams",
     "FactoredRandomEffectCoordinate",
     "MatrixFactorizationModel",
+    "IndexMapRandomEffectCoordinate",
     "ProjectedRandomEffectCoordinate",
     "build_index_map_columns",
     "parse_projector_spec",
@@ -70,6 +73,7 @@ __all__ = [
     "BucketedRandomEffectDesign",
     "build_random_effect_design",
     "build_bucketed_random_effect_design",
+    "build_index_map_design",
     "CoordinateConfig",
     "EntityRowPartition",
     "EntityShardAssignment",

@@ -37,6 +37,7 @@ from photon_ml_tpu import obs
 from photon_ml_tpu.core.tasks import TaskType
 from photon_ml_tpu.core.types import LabeledBatch
 from photon_ml_tpu.game.data import (
+    COMPACT_BLOCK,
     BucketedRandomEffectDesign,
     RandomEffectDesign,
     gather_offsets_compact,
@@ -745,6 +746,202 @@ def _score_rows_by_entity(table, feats, ents):
     safe = jnp.maximum(ents, 0)
     per_row = jnp.einsum("nd,nd->n", feats, table[safe])
     return jnp.where(ents >= 0, per_row, 0.0)
+
+
+def _split_columns(columns, width: int):
+    """A local id as (block, lane of the block): the block as a one-hot
+    over the width's blocks, exact in any float type."""
+    blocks = width // COMPACT_BLOCK
+    pick = columns[..., None] // COMPACT_BLOCK == jnp.arange(
+        blocks, dtype=columns.dtype)
+    lane = columns[..., None] % COMPACT_BLOCK == jnp.arange(
+        COMPACT_BLOCK, dtype=columns.dtype)
+    return pick, lane
+
+
+def _lane_matvec(w, columns, values):
+    """One lane's margins over its compact ELL rows: (s, R) local ids and
+    values against its (k,) coefficients -> (R,). The gather w[id] is read
+    as two one-hot contractions: every block's coefficient at the id's
+    lane of a block, by a matrix product over the 128 lanes (the MXU's, at
+    HIGHEST precision: the one-hot is exact, so each pick is w's value to
+    the bit), then the id's block selected. XLA's gather costs about 7 ns
+    an index on the chip (PERF.md section 5), the products a fraction of
+    that. The other order (the id's block of 128 coefficients by the
+    product, then its lane selected) compiled for TPU v5e reads 0.85 of
+    the margins' size wrong on the chip (PERF.md section 6)."""
+    with jax.named_scope("sparse_re/matvec"):
+        width = w.shape[0]
+        pick, lane = _split_columns(columns, width)
+        column = jnp.einsum(
+            "srl,bl->srb", lane.astype(w.dtype),
+            w.reshape(width // COMPACT_BLOCK, COMPACT_BLOCK),
+            precision=jax.lax.Precision.HIGHEST)
+        return jnp.sum(
+            values * jnp.sum(jnp.where(pick, column, 0.0), axis=-1), axis=0)
+
+
+def _lane_rmatvec(a, columns, values, width: int):
+    """The transpose: per-row (R,) weights -> the lane's (k,) vector, the
+    segment sum of its stored entries by local id, read as the transposed
+    one-hot contraction (each entry spread over its block's 128 lanes,
+    then summed into its block by the MXU), no scatter."""
+    with jax.named_scope("sparse_re/rmatvec"):
+        pick, lane = _split_columns(columns, width)
+        spread = jnp.where(lane, (values * a[None, :])[..., None], 0.0)
+        return jnp.einsum(
+            "srb,srl->bl", pick.astype(a.dtype), spread,
+            precision=jax.lax.Precision.HIGHEST).reshape(-1)
+
+
+def _compact_lane_objective(loss, lam, columns, values, labels, offsets,
+                            weights):
+    """(value_and_grad, hvp, hvp_at, value_grad_curvature) of one lane's
+    L2 GLM in its compact column space: ``GLMObjective``'s algebra with
+    the margins a gather of the lane's vector at its rows' stored entries
+    and the gradient and Hessian-vector product a segment sum back into
+    it, ``weights`` already masked."""
+
+    def vgc(w):
+        z = _lane_matvec(w, columns, values) + offsets
+        val = jnp.sum(weights * loss.value(z, labels)) + 0.5 * lam * jnp.vdot(
+            w, w)
+        grad = _lane_rmatvec(
+            weights * loss.d1(z, labels), columns, values, w.shape[0]
+        ) + lam * w
+        return val, grad, weights * loss.d2(z, labels)
+
+    def hvp_at(curvature, v):
+        dz = _lane_matvec(v, columns, values)
+        return _lane_rmatvec(
+            curvature * dz, columns, values, v.shape[0]) + lam * v
+
+    def value_and_grad(w):
+        val, grad, _ = vgc(w)
+        return val, grad
+
+    def hvp(w, v):
+        return hvp_at(vgc(w)[2], v)
+
+    return value_and_grad, hvp, hvp_at, vgc
+
+
+_COMPACT_OPTIMIZERS = (OptimizerType.TRON, OptimizerType.LBFGS)
+
+
+@lru_cache(maxsize=64)
+def _make_compact_solve(config: CoordinateConfig):
+    """solve(w0 (E_b, k_b), reg_weight (E_b,), columns, values, labels,
+    offsets, weights, mask) of every lane of one compact ELL bucket
+    (``game.data.CompactEllBucket``), vmapped: TRON with its
+    Hessian-vector product (or L-BFGS; OWL-QN under an L1 share), never a
+    (k, k) Hessian, so NEWTON is refused here, at build time. Un-jitted:
+    it runs inside the coordinate's update."""
+    loss = loss_for_task(config.task)
+    scfg = config.solver_config()
+    use_owlqn = config.l1_ratio > 0.0
+    if not use_owlqn and config.optimizer not in _COMPACT_OPTIMIZERS:
+        raise ValueError(
+            f"a random effect over a sparse shard (INDEX_MAP, compact "
+            f"columns) solves by {[o.name for o in _COMPACT_OPTIMIZERS]} "
+            f"(OWL-QN under an L1 share), not {config.optimizer.name}: its "
+            "lanes' Hessians would be (k, k) at the width of their union"
+        )
+    if config.optimizer == OptimizerType.TRON and not (
+        loss.twice_differentiable
+    ):
+        raise ValueError(
+            f"{config.task} is first-order only; TRON needs a "
+            "twice-differentiable loss (use LBFGS)"
+        )
+
+    def solve_one(w0, reg_weight, columns, values, labels, offsets, weights,
+                  mask):
+        l1 = reg_weight * config.l1_ratio
+        lam = reg_weight * (1.0 - config.l1_ratio)
+        vg, hvp, hvp_at, vgc = _compact_lane_objective(
+            loss, lam, columns, values, labels, offsets, weights * mask)
+        if use_owlqn:
+            return minimize_owlqn(vg, w0, l1, scfg)
+        if config.optimizer == OptimizerType.TRON:
+            return minimize_tron(vg, hvp, w0, scfg, hvp_at_fn=hvp_at,
+                                 vgc_fn=vgc)
+        return minimize_lbfgs(vg, w0, scfg)
+
+    return jax.vmap(solve_one)
+
+
+def _score_compact_rows(table, row_slots, row_values):
+    """Every row's score from the flat ragged table: a gather at its
+    stored entries' flat positions ((s, n)), times their values, summed
+    (an entry outside its entity's union carries value 0)."""
+    with jax.named_scope("sparse_re/score"):
+        return jnp.sum(
+            row_values * jnp.take(table, row_slots, axis=0, mode="clip"),
+            axis=0)
+
+
+def _lane_passes(result) -> jax.Array:
+    """(E_b,) int32: the passes over its compact rows a lane's solve made
+    (TRON: its outer iterations + 1 value/gradient, and its CG
+    iterations, one Hessian-vector product each; L-BFGS: its counted
+    evaluations)."""
+    iterations = result.iterations.astype(jnp.int32)
+    if result.cg_iterations is not None:
+        return iterations + 1 + result.cg_iterations.astype(jnp.int32)
+    if result.evals is not None:
+        return result.evals.astype(jnp.int32)
+    return iterations + 1
+
+
+def _make_index_map_update(config: CoordinateConfig):
+    """ONE jitted update of every bucket of an INDEX_MAP random effect over
+    a sparse shard and its rescore (the eager ``update`` dispatches it,
+    the fused pass inlines it): the residual offsets routed into the
+    buckets by the compact gather (as a plain random effect's), each
+    bucket's lanes read as one (E_b, k_b) block of the flat table (its
+    static slice), solved by :func:`_make_compact_solve`, and the table
+    written back as the blocks end to end, with no gather and no scatter;
+    then every row rescored by :func:`_score_compact_rows`. ``widths`` is
+    static. Cache key zeroes reg_weight (a traced scalar, as the fixed
+    effect's, so that a grid sweep reuses one compile)."""
+    return _make_index_map_update_cached(
+        dataclasses.replace(config, reg_weight=0.0))
+
+
+@lru_cache(maxsize=64)
+def _make_index_map_update_cached(config: CoordinateConfig):
+    solve = _make_compact_solve(config)
+    from photon_ml_tpu.solvers.common import final_grad_norm
+
+    def update_all(table, reg_weight, full_offsets, offsets_maps, buckets,
+                   row_slots, row_values, *, widths):
+        # runs while a coordinate's update is traced, never in a pass
+        obs.registry().inc("game.offsets_gather.compact")
+        with jax.named_scope("re_gather"), jax.named_scope("offsets"):
+            bucket_offsets = gather_offsets_compact(
+                full_offsets, offsets_maps, [b.mask for b in buckets]
+            )
+        solved, trackers, at = [], [], 0
+        for bucket, offsets, width in zip(buckets, bucket_offsets, widths):
+            lanes = bucket.columns.shape[0]
+            w0 = jax.lax.slice_in_dim(
+                table, at, at + lanes * width).reshape(lanes, width)
+            at += lanes * width
+            with jax.named_scope("sparse_re/solve"):
+                result = solve(
+                    w0, jnp.full((lanes,), reg_weight, w0.dtype),
+                    bucket.columns, bucket.values, bucket.labels, offsets,
+                    bucket.weights, bucket.mask,
+                )
+            solved.append(result.w.reshape(-1))
+            trackers.append((result.reason, result.iterations,
+                             final_grad_norm(result), _lane_passes(result)))
+        table = jnp.concatenate(solved)
+        scores = _score_compact_rows(table, row_slots, row_values)
+        return table, tuple(trackers), scores
+
+    return jax.jit(update_all, static_argnames=("widths",))
 
 
 class RandomEffectCoordinate:

@@ -595,22 +595,27 @@ def build_bucketed_random_effect_design(
     return design
 
 
-def _build_bucketed_design(
-    data, random_effect, shard, num_entities, *, num_buckets, active_cap,
-    entity_multiple, seed, dtype, feature_ratio, min_support,
-):
-    """(design, its host-side counts: entities with rows, active and
-    passive rows, entities over the cap) of
-    :func:`build_bucketed_random_effect_design`."""
-    from photon_ml_tpu.ops.sparse import is_structured
+class _BucketPlan(NamedTuple):
+    """Which rows a bucketed design holds, and where: every kept (active)
+    row's bucket, lane and slot, its weight rescale under the cap; every
+    bucket's depth and lane -> entity map (sentinel ``num_entities`` on the
+    pads of ``entity_multiple``); the host-side counts of the
+    ``game.design`` span."""
 
-    if is_structured(data.features[shard]):
-        raise ValueError(
-            f"random effect {random_effect!r}: per-entity designs gather "
-            f"dense rows; shard {shard!r} is sparse (sparse shards serve "
-            "fixed-effect coordinates only)"
-        )
-    eids = np.asarray(data.entity_ids[random_effect])
+    rows: np.ndarray
+    buckets: np.ndarray
+    lanes: np.ndarray
+    slots: np.ndarray
+    rescale: np.ndarray
+    caps: list
+    entity_index: list
+    counted: dict
+
+
+def _bucket_plan(eids, num_entities, *, num_buckets, active_cap,
+                 entity_multiple, seed) -> _BucketPlan:
+    """The grouping, reservoir sample, size split and lane placement that
+    every bucketed design shares (dense rows or compact sparse ones)."""
     if active_cap is not None and active_cap <= 0:
         raise ValueError(f"active_cap must be positive, got {active_cap}")
     if entity_multiple <= 0:
@@ -620,19 +625,13 @@ def _build_bucketed_design(
     if uniq.size == 0:
         # no rows with a known entity: one all-masked bucket so callers
         # (initial_params, update) keep working, like the global builder
-        empty_idx = np.asarray([], np.int64)
-        return BucketedRandomEffectDesign(
-            buckets=[
-                _fill_design(
-                    data, shard, empty_idx, empty_idx, empty_idx,
-                    np.asarray([]), entity_multiple, 1, dtype,
-                )
-            ],
-            entity_index=[
-                np.full(entity_multiple, num_entities, np.int32)
-            ],
-            num_entities=num_entities,
-        ), dict(entities=0, active_rows=0, passive_rows=0, capped_entities=0)
+        empty = np.asarray([], np.int64)
+        return _BucketPlan(
+            empty, empty, empty, empty, np.asarray([]), [1],
+            [np.full(entity_multiple, num_entities, np.int32)],
+            dict(entities=0, active_rows=0, passive_rows=0,
+                 capped_entities=0),
+        )
 
     # per-entity active cap under the bucket policy. The split sees what a
     # bucket will hold: an entity over the cap pads like one AT the cap, so
@@ -648,16 +647,19 @@ def _build_bucketed_design(
     bucket_of_entity = np.full(num_entities, -1, np.int64)
     local_of_entity = np.zeros(num_entities, np.int64)
     bucket_caps = []
-    bucket_entities = []
+    entity_index = []
     for b, split in enumerate(splits):
         ents = uniq[split]
         cmax = int(counts[split].max())
         cap_b = min(cmax, active_cap) if active_cap is not None else cmax
         bucket_caps.append(cap_b)
-        bucket_entities.append(ents)
         cap_of_entity[ents] = np.minimum(counts[split], cap_b)
         bucket_of_entity[ents] = b
         local_of_entity[ents] = np.arange(ents.size)
+        e_pad = -(-ents.size // entity_multiple) * entity_multiple
+        idx = np.full(e_pad, num_entities, np.int64)
+        idx[: ents.size] = ents
+        entity_index.append(np.asarray(idx, np.int32))
 
     keep = slot < cap_of_entity[sorted_ids]
     full_count = np.zeros(num_entities, np.int64)
@@ -670,21 +672,54 @@ def _build_bucketed_design(
 
     rows = order[keep]
     ents = sorted_ids[keep]
-    slots = slot[keep]
+    return _BucketPlan(
+        rows=rows,
+        buckets=bucket_of_entity[ents],
+        lanes=local_of_entity[ents],
+        slots=slot[keep],
+        rescale=rescale_of_entity[ents],
+        caps=bucket_caps,
+        entity_index=entity_index,
+        counted=dict(
+            entities=int(uniq.size),
+            active_rows=int(rows.size),
+            passive_rows=int(order.size - rows.size),
+            capped_entities=int(np.sum(counts > active_counts)),
+        ),
+    )
 
+
+def _build_bucketed_design(
+    data, random_effect, shard, num_entities, *, num_buckets, active_cap,
+    entity_multiple, seed, dtype, feature_ratio, min_support,
+):
+    """(design, its host-side counts: entities with rows, active and
+    passive rows, entities over the cap) of
+    :func:`build_bucketed_random_effect_design`."""
+    from photon_ml_tpu.ops.sparse import is_structured
+
+    if is_structured(data.features[shard]):
+        raise ValueError(
+            f"random effect {random_effect!r}: per-entity designs gather "
+            f"dense rows; shard {shard!r} is sparse (a sparse shard's "
+            "random effect is built by build_index_map_design)"
+        )
+    plan = _bucket_plan(
+        np.asarray(data.entity_ids[random_effect]), num_entities,
+        num_buckets=num_buckets, active_cap=active_cap,
+        entity_multiple=entity_multiple, seed=seed,
+    )
     buckets = []
-    entity_index = []
-    for b, (cap_b, ents_b) in enumerate(zip(bucket_caps, bucket_entities)):
-        sel = bucket_of_entity[ents] == b
-        e_pad = -(-ents_b.size // entity_multiple) * entity_multiple
+    for b, (cap_b, idx) in enumerate(zip(plan.caps, plan.entity_index)):
+        sel = plan.buckets == b
         bucket = _fill_design(
             data,
             shard,
-            rows[sel],
-            local_of_entity[ents[sel]],
-            slots[sel],
-            rescale_of_entity[ents[sel]],
-            e_pad,
+            plan.rows[sel],
+            plan.lanes[sel],
+            plan.slots[sel],
+            plan.rescale[sel],
+            idx.size,
             cap_b,
             dtype,
         )
@@ -692,17 +727,305 @@ def _build_bucketed_design(
         if feature_ratio is not None:
             bucket = select_features_by_pearson(bucket, feature_ratio)
         buckets.append(bucket)
-        idx = np.full(e_pad, num_entities, np.int64)
-        idx[: ents_b.size] = ents_b
-        entity_index.append(np.asarray(idx, np.int32))
 
     return BucketedRandomEffectDesign(
-        buckets=buckets, entity_index=entity_index, num_entities=num_entities
-    ), dict(
-        entities=int(uniq.size),
-        active_rows=int(rows.size),
-        passive_rows=int(order.size - rows.size),
-        capped_entities=int(np.sum(counts > active_counts)),
+        buckets=buckets, entity_index=plan.entity_index,
+        num_entities=num_entities,
+    ), plan.counted
+
+
+# a bucket's compact width is a whole number of these blocks of columns:
+# the lanes' products read a local id as (block, lane of the block)
+# (``game.coordinates._lane_matvec``)
+COMPACT_BLOCK = 128
+
+
+@_pytree_dataclass
+class CompactEllBucket:
+    """One bucket of a random effect over a SPARSE shard, each lane in its
+    own entity's compact column space (INDEX_MAP,
+    ``IndexMapProjectorRDD.scala:113-120``): the rows' stored entries as
+    local column ids into the lane's coefficient vector of the bucket's
+    width ``k_b``, never a dense k axis.
+
+    columns: (E_b, s, R_b) int32 local ids in [0, k_b) (0 on a pad)
+    values:  (E_b, s, R_b) their values (0 on a pad), s the shard's slots
+    labels / weights / mask / row_index: (E_b, R_b) as
+    :class:`RandomEffectDesign`'s.
+
+    The slots lie ahead of the rows, so that the long axis is minor on the
+    chip (an (R, s) pair of axes with s = 8 minor lies padded to 128 lanes,
+    16 times its size)."""
+
+    columns: jax.Array
+    values: jax.Array
+    labels: jax.Array
+    weights: jax.Array
+    mask: jax.Array
+    row_index: jax.Array
+
+    @property
+    def num_entities(self) -> int:
+        return self.columns.shape[0]
+
+    @property
+    def rows_per_entity(self) -> int:
+        return self.columns.shape[2]
+
+
+@dataclasses.dataclass
+class IndexMapDesign:
+    """A random effect over a sparse shard, bucketed as
+    :func:`build_bucketed_random_effect_design` buckets its entities, with
+    the rows in each lane's compact columns (:class:`CompactEllBucket`) and
+    the coefficients in ONE flat ragged table laid out by ``index_map``
+    (``game.projectors.RaggedIndexMap``): bucket b's lanes are an
+    (E_b, k_b) block of it, k_b the largest union of the bucket's lanes.
+
+    ``row_slots`` / ``row_values`` (s, n) are every row's stored entries as
+    positions of the flat table (active and passive rows alike; an entry
+    outside its entity's union, or of a row whose entity is unknown, has
+    value 0): scoring a row is a gather of the table at its slots."""
+
+    buckets: list  # List[CompactEllBucket]
+    entity_index: list  # List[np.ndarray (E_b,) int32]
+    index_map: object  # game.projectors.RaggedIndexMap
+    row_slots: jax.Array
+    row_values: jax.Array
+    num_entities: int
+
+    @property
+    def num_buckets(self) -> int:
+        return len(self.buckets)
+
+    @property
+    def active_slots(self) -> int:
+        """Padded (entity, row) slots across buckets."""
+        return sum(b.num_entities * b.rows_per_entity for b in self.buckets)
+
+
+def build_index_map_design(
+    data: GameData,
+    random_effect: str,
+    shard: str,
+    num_entities: int,
+    num_buckets: int = 4,
+    active_cap: Optional[int] = None,
+    entity_multiple: int = 1,
+    seed: int = 0,
+    dtype=jnp.float32,
+    feature_ratio: Optional[float] = None,
+    min_support: int = 0,
+) -> IndexMapDesign:
+    """The bucketed design of a random effect over a padded-ELL shard
+    (``ops.sparse.SparseFeatures``), in compact column space: the same
+    entities, buckets, reservoir sample and weights as
+    :func:`build_bucketed_random_effect_design` gives a dense shard
+    (``_bucket_plan``), each lane's columns the union of the columns its
+    ACTIVE rows store (``RandomEffectCoordinateInProjectedSpace.scala:26-120``;
+    a column only passive rows touch has no data gradient and stays 0
+    under L2). A bucket's width is its lanes' largest union rounded up to
+    a whole number of ``COMPACT_BLOCK`` columns.
+
+    ``min_support`` drops a lane's column stored in fewer of its active
+    rows, ``feature_ratio`` keeps a lane's top ceil(ratio * rows) columns
+    by |Pearson correlation| with the label, as the dense builders'
+    filters (``filter_features_by_support``, ``select_features_by_pearson``)
+    zero them; a dropped column leaves the union.
+
+    Host work O(nnz log nnz), once a run; nothing of size (n, k), (E_b,
+    R_b, k_b) or (E, d) is built. One ``game.design`` span, inside it one
+    ``game.index_map`` span for the unions, widths and compact ids."""
+    from photon_ml_tpu.ops import sparse as sparse_ops
+
+    sf = data.features[shard]
+    if not sparse_ops.is_sparse(sf):
+        raise ValueError(
+            f"random effect {random_effect!r}: an INDEX_MAP design over a "
+            f"sparse shard needs a SparseFeatures shard; {shard!r} is not"
+        )
+    with obs.span(
+        "game.design", cat="data", random_effect=random_effect
+    ) as sp:
+        plan = _bucket_plan(
+            np.asarray(data.entity_ids[random_effect]), num_entities,
+            num_buckets=num_buckets, active_cap=active_cap,
+            entity_multiple=entity_multiple, seed=seed,
+        )
+        with obs.span(
+            "game.index_map", cat="data", random_effect=random_effect
+        ) as isp:
+            design, attrs = _fill_index_map_design(
+                data, random_effect, sf, plan, num_entities, dtype,
+                feature_ratio=feature_ratio, min_support=min_support,
+            )
+            isp.set(**attrs)
+        sp.set(
+            buckets=design.num_buckets,
+            active_slots=design.active_slots,
+            bucket_caps=[b.rows_per_entity for b in design.buckets],
+            **plan.counted,
+        )
+    obs.registry().inc(
+        "game.re.capped_entities", plan.counted["capped_entities"])
+    obs.registry().inc("game.re.passive_rows", plan.counted["passive_rows"])
+    return design
+
+
+def _pearson_keep(pair_lane, pair_inv, x, y, lane_rows, lane_y, lane_yy,
+                  ratio, present_pairs):
+    """``select_features_by_pearson`` over (lane, column) pairs: the
+    per-pair moments of the entries ``x`` (each entry's pair ``pair_inv``,
+    its row's label ``y``), the lanes' active row counts and label moments;
+    a lane keeps its ceil(ratio * rows) best pairs by |correlation|
+    (columns ascending on ties, an intercept-like constant column first),
+    among ``present_pairs``."""
+    m = pair_lane.size
+    s1 = np.bincount(pair_inv, weights=x, minlength=m)
+    s2 = np.bincount(pair_inv, weights=x * x, minlength=m)
+    sxy = np.bincount(pair_inv, weights=x * y, minlength=m)
+    s1, s2, sxy = (np.where(present_pairs, a, 0.0) for a in (s1, s2, sxy))
+    n = lane_rows[pair_lane].astype(np.float64)
+    ly, lyy = lane_y[pair_lane], lane_yy[pair_lane]
+    numerator = n * sxy - s1 * ly
+    feat_var = np.abs(n * s2 - s1 * s1)
+    label_var = np.maximum(n * lyy - ly * ly, 0.0)
+    denominator = np.sqrt(feat_var) * np.sqrt(label_var)
+    label_const = label_var < 1e-9 * np.maximum(n * lyy, 1.0)
+    score = np.where(label_const, 0.0, numerator / (denominator + 1e-12))
+    present = s2 > 0.0
+    constant = present & (feat_var < 1e-9 * np.maximum(n * s2, 1.0))
+    # pairs run lane by lane, columns ascending: the first constant pair
+    # of each lane is its intercept
+    first = np.r_[True, pair_lane[1:] != pair_lane[:-1]]
+    run = np.cumsum(constant)
+    before = np.maximum.accumulate(np.where(first, run - constant, 0))
+    first_const = constant & (run - before == 1)
+    score = np.where(constant, 0.0, score)
+    score = np.where(first_const, 1.0, score)
+    score = np.where(present, np.abs(score), -np.inf)
+    order = np.lexsort((np.arange(m), -score, pair_lane))
+    lane_start = np.searchsorted(pair_lane[order], pair_lane[order], "left")
+    rank = np.empty(m, np.int64)
+    rank[order] = np.arange(m) - lane_start
+    keep_count = np.ceil(ratio * lane_rows).astype(np.int64)
+    return rank < keep_count[pair_lane]
+
+
+def _fill_index_map_design(data, random_effect, sf, plan, num_entities,
+                           dtype, *, feature_ratio, min_support):
+    """(:class:`IndexMapDesign`, the ``game.index_map`` span's attributes)
+    of :func:`build_index_map_design`, for the rows ``plan`` places."""
+    from photon_ml_tpu.game.projectors import RaggedIndexMap
+
+    if feature_ratio is not None and feature_ratio <= 0:
+        raise ValueError(
+            f"feature ratio must be positive, got {feature_ratio}")
+    ind = np.asarray(sf.indices)
+    val = np.asarray(sf.values).astype(np.dtype(jnp.dtype(dtype)))
+    d, width = sf.d, ind.shape[1]
+    lanes = [int(e.size) for e in plan.entity_index]
+    total = int(sum(lanes))
+    lane_base = np.concatenate([[0], np.cumsum(lanes)])[:-1]
+    # every stored entry of the active rows, by global lane (the buckets'
+    # lanes end to end)
+    glane = lane_base[plan.buckets] + plan.lanes
+    active_ind = ind[plan.rows]
+    stored = active_ind < d
+    entry_row, entry_slot = np.nonzero(stored)
+    entry_lane = glane[entry_row]
+    entry_col = active_ind[stored].astype(np.int64)
+    entry_val = val[plan.rows][stored]
+    pairs, pair_inv = np.unique(
+        entry_lane.astype(np.int64) * d + entry_col, return_inverse=True)
+    pair_lane = pairs // d
+    keep = np.ones(pairs.size, bool)
+    if min_support > 0:
+        support = np.bincount(pair_inv, weights=entry_val != 0,
+                              minlength=pairs.size)
+        keep &= support >= min_support
+    if feature_ratio is not None:
+        row_label = np.asarray(data.labels, np.float64)[plan.rows]
+        lane_rows = np.bincount(glane, minlength=total)
+        lane_y = np.bincount(glane, weights=row_label, minlength=total)
+        lane_yy = np.bincount(glane, weights=row_label ** 2, minlength=total)
+        keep &= _pearson_keep(
+            pair_lane, pair_inv, entry_val.astype(np.float64),
+            row_label[entry_row], lane_rows, lane_y, lane_yy,
+            feature_ratio, keep)
+    # the kept pairs, lane by lane and columns ascending: a pair's local
+    # id is its rank inside its lane
+    kept = np.flatnonzero(keep)
+    kept_lane = pair_lane[kept]
+    union = np.bincount(kept_lane, minlength=total)
+    first_of_lane = np.concatenate([[0], np.cumsum(union)])[:-1]
+    local = np.arange(kept.size) - first_of_lane[kept_lane]
+    index_map = RaggedIndexMap.from_unions(
+        union=union, lanes=lanes, entity_index=plan.entity_index,
+        pair_lane=kept_lane, pair_column=pairs[kept] % d,
+        pair_local=local, num_entities=num_entities, original_dim=d,
+    )
+    entry_local = np.full(pairs.size, -1, np.int64)
+    entry_local[kept] = local
+    entry_local = entry_local[pair_inv]
+    entry_kept = entry_local >= 0
+
+    buckets, stored_by_bucket, rows_by_bucket = [], [], []
+    for b, (cap_b, idx) in enumerate(zip(plan.caps, plan.entity_index)):
+        shape = (idx.size, cap_b)
+        labels = np.zeros(shape, np.float64)
+        weights = np.zeros(shape, np.float64)
+        mask = np.zeros(shape, np.float64)
+        row_index = np.full(shape, -1, np.int64)
+        sel = plan.buckets == b
+        at = (plan.lanes[sel], plan.slots[sel])
+        rows = plan.rows[sel]
+        labels[at] = data.labels[rows]
+        weights[at] = data.weights[rows] * plan.rescale[sel]
+        mask[at] = 1.0
+        row_index[at] = rows
+        columns = np.zeros((idx.size, width, cap_b), np.int32)
+        values = np.zeros((idx.size, width, cap_b), val.dtype)
+        mine = entry_kept & sel[entry_row]
+        r = entry_row[mine]
+        spot = (plan.lanes[r], entry_slot[mine], plan.slots[r])
+        columns[spot] = entry_local[mine]
+        values[spot] = entry_val[mine]
+        stored_by_bucket.append(int(np.count_nonzero(mine)))
+        rows_by_bucket.append(int(rows.size))
+        buckets.append(CompactEllBucket(
+            columns=jnp.asarray(columns),
+            values=jnp.asarray(values, dtype),
+            labels=jnp.asarray(labels, dtype),
+            weights=jnp.asarray(weights, dtype),
+            mask=jnp.asarray(mask, dtype),
+            row_index=jnp.asarray(row_index, jnp.int32),
+        ))
+
+    # every row's entries, active and passive, as flat table positions
+    row_slots, row_values = index_map.row_positions(
+        np.asarray(data.entity_ids[random_effect]), ind, val)
+    design = IndexMapDesign(
+        buckets=buckets,
+        entity_index=plan.entity_index,
+        index_map=index_map,
+        row_slots=jnp.asarray(row_slots.T),
+        row_values=jnp.asarray(row_values.T, dtype),
+        num_entities=num_entities,
+    )
+    padded_slots = sum(
+        int(np.prod(b.columns.shape)) for b in buckets)
+    return design, dict(
+        entities=plan.counted["entities"],
+        union_columns=int(kept.size),
+        padded_columns=index_map.size,
+        widths=list(index_map.widths),
+        lanes=list(index_map.lanes),
+        stored_slots=int(np.count_nonzero(entry_kept)),
+        padded_slots=padded_slots,
+        stored_by_bucket=stored_by_bucket,
+        rows_by_bucket=rows_by_bucket,
+        row_slots_stored=int(np.count_nonzero(row_values)),
     )
 
 

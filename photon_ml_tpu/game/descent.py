@@ -66,14 +66,16 @@ class CoordinateUpdateRecord:
     # succeeded, "frozen" when the retry also failed and the coordinate
     # was excluded from further training (docs/ROBUSTNESS.md)
     event: Optional[str] = None
-    # factored coordinates only: the reference's array of (random effect,
+    # factored coordinates: the reference's array of (random effect,
     # latent matrix) trackers, one dict an inner iteration
     # (``game.factored.FactoredUpdateSummary.history_decode``): "lanes"
     # (count, solver_iterations, convergence_histogram over every lane of
     # every bucket) and "projection" (the shared-B solve's iterations,
     # cg_iterations, passes over the design, reason, grad_norm).
     # ``solver_iterations`` / ``convergence_histogram`` above are the LAST
-    # inner iteration's lanes.
+    # inner iteration's lanes. INDEX_MAP random effects over a sparse
+    # shard: one dict, "lanes" and "sparse_re" (each bucket's passes over
+    # its compact rows: ``game.projected.IndexMapUpdateSummary``).
     inner_iterations: Optional[List[dict]] = None
 
 
@@ -146,8 +148,14 @@ def _record_update_metrics(rec: CoordinateUpdateRecord) -> None:
         reg.inc("resilience.rollbacks")
     elif rec.event == "frozen":
         reg.inc("resilience.frozen_coordinates")
-    if rec.inner_iterations is not None:
-        solves = [i["projection"] for i in rec.inner_iterations]
+    inner = rec.inner_iterations or []
+    passes = [sum(i["sparse_re"]["passes"]) for i in inner
+              if "sparse_re" in i]
+    if passes:
+        reg.inc("game.sparse_re.updates")
+        reg.inc("game.sparse_re.passes", sum(passes))
+    solves = [i["projection"] for i in inner if "projection" in i]
+    if solves:
         reg.inc("game.factored.updates")
         reg.inc("game.factored.inner_iterations", len(solves))
         reg.inc(

@@ -7,11 +7,18 @@ Gaussian RANDOM projection, per-entity INDEX_MAP compaction, or IDENTITY),
 and coefficients are projected back to the original feature space at model
 extraction so on-disk models never know projection existed.
 
-TPU-first shape: projection is applied ONCE to the padded bucketed design
-at build time (a matmul or per-entity gather — not a per-row RDD map), the
-inner :class:`RandomEffectCoordinate` is reused unchanged on the projected
-tensors, and back-projection of the (E, k) table is a single matmul /
-scatter.
+TPU-first shape, over a DENSE shard: projection is applied ONCE to the
+padded bucketed design at build time (a matmul or per-entity gather — not
+a per-row RDD map), the inner :class:`RandomEffectCoordinate` is reused
+unchanged on the projected tensors, and back-projection of the (E, k)
+table is a single matmul / scatter.
+
+Over a SPARSE shard (:class:`IndexMapRandomEffectCoordinate`), the regime
+INDEX_MAP exists for (a wide bag, small per-entity unions), nothing is
+dense: each bucket's lanes solve in their own compact columns at the
+bucket's width (``game.data.build_index_map_design``), the coefficients
+are one flat ragged table (``game.projectors.RaggedIndexMap``), and
+back-projection gives per-entity (column, value) lists.
 """
 
 from __future__ import annotations
@@ -26,17 +33,24 @@ import numpy as np
 from photon_ml_tpu.game.coordinates import (
     CoordinateConfig,
     RandomEffectCoordinate,
+    RandomEffectUpdateSummary,
+    _design_offsets_maps,
+    _make_index_map_update,
+    _score_compact_rows,
 )
 from photon_ml_tpu.game.data import (
     BucketedRandomEffectDesign,
     GameData,
+    IndexMapDesign,
     RandomEffectDesign,
+    build_index_map_design,
 )
 from photon_ml_tpu.game.projectors import (
     IndexMapProjection,
     RandomProjection,
     build_random_projection,
 )
+from photon_ml_tpu.solvers.common import reason_histogram
 
 
 def parse_projector_spec(spec: str) -> Tuple[str, Optional[int]]:
@@ -65,84 +79,21 @@ def build_index_map_columns(
     num_entities: int,
 ) -> IndexMapProjection:
     """Per-entity union of ACTIVE feature indices over all of the entity's
-    rows (``IndexMapProjectorRDD.scala:113-120``), indexed by global entity
-    id — usable against any bucketing of the same entities.
-
-    O(nnz) in time and memory: works on the nonzero coordinates directly
-    (never a dense (E, d) presence matrix, which would defeat INDEX_MAP's
-    purpose in the wide-feature regime it exists for). Accepts dense OR
-    padded-ELL (``SparseFeatures``) shards — the sparse case is the whole
-    point of INDEX_MAP (wide shards whose per-entity active unions are
-    small, ``RandomEffectCoordinateInProjectedSpace.scala:26-120``)."""
+    rows of a DENSE shard (``IndexMapProjectorRDD.scala:113-120``),
+    indexed by global entity id — usable against any bucketing of the
+    same entities. O(nnz): works on the nonzero coordinates, never a
+    dense (E, d) presence matrix. A sparse shard's random effect is
+    :class:`IndexMapRandomEffectCoordinate`'s, in ragged widths."""
     from photon_ml_tpu.game.projectors import columns_from_active_pairs
-    from photon_ml_tpu.ops import sparse as sparse_ops
 
-    x = data.features[shard]
+    x = np.asarray(data.features[shard])
     eids = np.asarray(data.entity_ids[random_effect])
-    if sparse_ops.is_sparse(x):
-        ind = np.asarray(x.indices)
-        d = x.d
-        keep = (ind < d) & (eids[:, None] >= 0)
-        rows = np.broadcast_to(
-            np.arange(ind.shape[0])[:, None], ind.shape
-        )[keep]
-        ent = eids[rows]
-        feat_cols = ind[keep]
-    else:
-        x = np.asarray(x)
-        d = x.shape[1]
-        rows, feat_cols = np.nonzero(x)
-        ent = eids[rows]
-        known = ent >= 0
-        ent, feat_cols = ent[known], feat_cols[known]
-    cols = columns_from_active_pairs(ent, feat_cols, d, num_entities)
+    rows, feat_cols = np.nonzero(x)
+    ent = eids[rows]
+    known = ent >= 0
+    cols = columns_from_active_pairs(
+        ent[known], feat_cols[known], x.shape[1], num_entities)
     return IndexMapProjection(columns=jnp.asarray(cols, jnp.int32))
-
-
-def project_sparse_rows(
-    sf,
-    entities: np.ndarray,
-    projection: IndexMapProjection,
-    dtype=np.float32,
-) -> np.ndarray:
-    """Project padded-ELL rows into each row's OWN entity's compact column
-    space: (n, nnz) ELL -> dense (n, k) where k = max per-entity active
-    columns. The sparse analog of
-    ``IndexMapProjection.project_row_features`` — entries whose (entity,
-    column) pair is outside the entity's active union are dropped (score
-    0), exactly the reference's projected-space scoring semantics.
-    Host-side, O(nnz log nnz), once per run."""
-    from photon_ml_tpu.ops import sparse as sparse_ops
-
-    if not sparse_ops.is_sparse(sf):
-        raise ValueError("project_sparse_rows takes a SparseFeatures shard")
-    cols_np = np.asarray(projection.columns)
-    e_count, k = cols_np.shape
-    d = sf.d
-    valid = cols_np >= 0
-    ent_of = np.broadcast_to(
-        np.arange(e_count)[:, None], cols_np.shape
-    )[valid]
-    slot_of = np.broadcast_to(np.arange(k)[None, :], cols_np.shape)[valid]
-    pair = ent_of.astype(np.int64) * d + cols_np[valid]
-    order = np.argsort(pair, kind="stable")
-    pair = pair[order]
-    slot_sorted = slot_of[order]
-
-    ind = np.asarray(sf.indices)
-    val = np.asarray(sf.values)
-    n = ind.shape[0]
-    ents = np.asarray(entities).astype(np.int64)
-    entry_ok = (ind < d) & (ents[:, None] >= 0)
-    rows_e = np.broadcast_to(np.arange(n)[:, None], ind.shape)[entry_ok]
-    epair = ents[rows_e] * d + ind[entry_ok].astype(np.int64)
-    evals = val[entry_ok]
-    loc = np.searchsorted(pair, epair)
-    loc = np.clip(loc, 0, max(pair.size - 1, 0))
-    hit = pair[loc] == epair if pair.size else np.zeros(epair.shape, bool)
-    out = np.zeros((n, k), dtype)
-    np.add.at(out, (rows_e[hit], slot_sorted[loc[hit]]), evals[hit])
-    return out
 
 
 def _project_design_bucket(
@@ -243,86 +194,6 @@ class ProjectedRandomEffectCoordinate:
             reg_weights=reg_weights,
         )
 
-    @classmethod
-    def from_sparse_shard(
-        cls,
-        data,  # GameData with a SparseFeatures shard
-        random_effect: str,
-        shard: str,
-        num_entities: int,
-        config: CoordinateConfig,
-        num_buckets: int = 4,
-        active_cap: Optional[int] = None,
-        entity_multiple: int = 1,
-        seed: int = 0,
-        dtype=None,
-        reg_weights: Optional[jax.Array] = None,
-        feature_ratio: Optional[float] = None,
-        min_support: int = 0,
-    ) -> "ProjectedRandomEffectCoordinate":
-        """Wide-sparse random effects: build an INDEX_MAP-projected
-        coordinate STRAIGHT from a padded-ELL shard, never materializing
-        the (E, rows, d) original-space design — the regime of
-        ``RandomEffectCoordinateInProjectedSpace.scala:26-120`` +
-        ``IndexMapProjectorRDD.scala:113-120``, where d is huge but each
-        entity touches few columns.
-
-        Pipeline (host-side, once per run): per-entity active-column
-        union -> project every row into its own entity's compact space
-        (dense (n, k), k = max union size) -> reuse the standard bucketed
-        builder/capping/scoring machinery on that dense view. Training,
-        scoring, reservoir caps, Pearson filters and checkpointing all
-        work unchanged; ``back_project`` scatters the (E, k) table to
-        original d-space for persistence."""
-        import dataclasses as _dc
-        import jax.numpy as jnp_
-
-        from photon_ml_tpu.game.data import (
-            build_bucketed_random_effect_design,
-        )
-
-        dtype = dtype or jnp_.float32
-        projector = build_index_map_columns(
-            data, random_effect, shard, num_entities
-        )
-        proj_rows_np = project_sparse_rows(
-            data.features[shard],
-            np.asarray(data.entity_ids[random_effect]),
-            projector,
-            dtype=np.dtype(jnp.dtype(dtype)),
-        )
-        proj_data = _dc.replace(
-            data, features={**data.features, shard: proj_rows_np}
-        )
-        design = build_bucketed_random_effect_design(
-            proj_data,
-            random_effect,
-            shard,
-            num_entities,
-            num_buckets=num_buckets,
-            active_cap=active_cap,
-            entity_multiple=entity_multiple,
-            seed=seed,
-            dtype=dtype,
-            feature_ratio=feature_ratio,
-            min_support=min_support,
-        )
-        proj_rows = jnp_.asarray(proj_rows_np, dtype)
-        row_entities = jnp_.asarray(
-            np.asarray(data.entity_ids[random_effect]), jnp_.int32
-        )
-        return cls(
-            design=design,
-            row_features=proj_rows,
-            row_entities=row_entities,
-            full_offsets_base=jnp_.asarray(data.offsets, dtype),
-            config=config,
-            projector=projector,
-            original_dim=data.features[shard].d,
-            reg_weights=reg_weights,
-            prebuilt=(design, proj_rows),
-        )
-
     def with_config(self, config: CoordinateConfig) -> "ProjectedRandomEffectCoordinate":
         """Same projected design/rows under a different optimization
         config — the grid-sweep reuse hook (designs and projections are
@@ -402,3 +273,223 @@ class ProjectedRandomEffectCoordinate:
         return self.projector.project_coefficients_back(
             table, self.original_dim
         )
+
+
+@dataclasses.dataclass
+class IndexMapUpdateSummary(RandomEffectUpdateSummary):
+    """The lazy tracker view of one :class:`IndexMapRandomEffectCoordinate`
+    update: ``RandomEffectUpdateSummary``'s per-lane fields, plus each
+    bucket's passes over its compact rows, fetched in the descent's one
+    batched history transfer (``history_fetch`` / ``history_decode``) and
+    handed to the update's record as ``inner_iterations``, one entry:
+    ``{"lanes": {...}, "sparse_re": {"passes": [a bucket's passes, the
+    most of any of its real lanes: its batched solve ran that many]}}``
+    (``game.sparse_re.passes`` books their sum at ``materialize()``)."""
+
+    def history_fetch(self):
+        return tuple(
+            (reason, iters, gnorm, passes)
+            for reason, iters, gnorm, _, _, passes in self.pending
+        )
+
+    def history_decode(self, host):
+        valid = [v for _, _, _, v, _, _ in self.pending]
+        entity_ids = np.concatenate(
+            [np.asarray(e)[v] for _, _, _, v, e, _ in self.pending])
+
+        def lanes_of(field):
+            return np.concatenate(
+                [np.asarray(bucket[field])[v]
+                 for bucket, v in zip(host, valid)])
+
+        reason, iterations, grad_norms = (lanes_of(i) for i in range(3))
+        passes = [
+            int(np.asarray(bucket[3])[v].max(initial=0))
+            for bucket, v in zip(host, valid)
+        ]
+        inner = [{
+            "lanes": {
+                "count": int(iterations.size),
+                "solver_iterations": (
+                    float(np.mean(iterations)) if iterations.size else 0.0),
+                "convergence_histogram": reason_histogram(reason),
+            },
+            "sparse_re": {"passes": passes},
+        }]
+        return reason, iterations, grad_norms, entity_ids, inner
+
+    def _materialize(self):
+        if self.pending is not None:
+            import jax as _jax
+
+            (self._reason, self._iterations, self._grad_norms,
+             self._entity_ids, _) = self.history_decode(
+                _jax.device_get(self.history_fetch()))
+            self.pending = None
+
+
+class IndexMapRandomEffectCoordinate:
+    """A random effect over a SPARSE shard through INDEX_MAP
+    (``RandomEffectCoordinateInProjectedSpace.scala:26-120``): every
+    entity solves in the compact space of the columns its active rows
+    store, each bucket of entities at its own width, the whole coefficient
+    set one flat ragged table (``game.projectors.RaggedIndexMap``).
+
+    Drop-in member of a CoordinateDescent ``coordinates`` dict: its
+    parameters are the flat (T,) table; :meth:`back_project` gives the
+    per-entity (column, value) lists persistence and scoring read
+    (``RandomEffectModelInProjectedSpace.toRandomEffectModel``), never an
+    (E, d) table."""
+
+    def __init__(
+        self,
+        design: IndexMapDesign,
+        full_offsets_base: jax.Array,
+        config: CoordinateConfig,
+    ):
+        if config.random_effect is None:
+            raise ValueError("config lacks random_effect; wrong coordinate")
+        self.design = design
+        self.config = config
+        self.full_offsets_base = full_offsets_base
+        # traced, like the fixed effect's (``FixedEffectCoordinate``)
+        self._reg_weight = config.reg_weight
+        self._widths = design.index_map.widths
+        self._update_all = _make_index_map_update(config)
+        self._offsets_maps = _design_offsets_maps(design)
+        self._valid_lanes = [
+            np.asarray(ei) < design.num_entities
+            for ei in design.entity_index
+        ]
+        self._score = jax.jit(_score_compact_rows)
+
+    @classmethod
+    def from_sparse_shard(
+        cls,
+        data: GameData,
+        random_effect: str,
+        shard: str,
+        num_entities: int,
+        config: CoordinateConfig,
+        num_buckets: int = 4,
+        active_cap: Optional[int] = None,
+        entity_multiple: int = 1,
+        seed: int = 0,
+        dtype=None,
+        feature_ratio: Optional[float] = None,
+        min_support: int = 0,
+    ) -> "IndexMapRandomEffectCoordinate":
+        """Build the coordinate straight from a padded-ELL shard
+        (``game.data.build_index_map_design``): host-side, once a run,
+        O(nnz log nnz)."""
+        dtype = dtype or jnp.float32
+        design = build_index_map_design(
+            data, random_effect, shard, num_entities,
+            num_buckets=num_buckets, active_cap=active_cap,
+            entity_multiple=entity_multiple, seed=seed, dtype=dtype,
+            feature_ratio=feature_ratio, min_support=min_support,
+        )
+        return cls(
+            design=design,
+            full_offsets_base=jnp.asarray(data.offsets, dtype),
+            config=config,
+        )
+
+    def with_config(self, config: CoordinateConfig) -> "IndexMapRandomEffectCoordinate":
+        """The same design under another optimization config (the grid
+        sweep's reuse)."""
+        return IndexMapRandomEffectCoordinate(
+            design=self.design,
+            full_offsets_base=self.full_offsets_base,
+            config=config,
+        )
+
+    @property
+    def num_entities(self) -> int:
+        return self.design.num_entities
+
+    @property
+    def dim(self) -> int:
+        """The shard's original width (the space of the saved model)."""
+        return self.design.index_map.original_dim
+
+    def initial_params(self) -> jax.Array:
+        return jnp.zeros(
+            (self.design.index_map.size,),
+            self.design.buckets[0].values.dtype,
+        )
+
+    def update(self, table, partial_scores, key=None):
+        table, summary, _ = self.update_and_score(table, partial_scores, key)
+        return table, summary
+
+    def update_and_score(self, table, partial_scores, key=None):
+        table, trackers, scores = self.update_step(
+            table, partial_scores, key)
+        return table, self.wrap_tracker(trackers), scores
+
+    def update_step(self, table, partial_scores, key=None):
+        """Trace-safe update + rescore (the fused pass's unit)."""
+        return self._update_all(
+            table,
+            jnp.asarray(self._reg_weight, table.dtype),
+            self.full_offsets_base + partial_scores,
+            self._offsets_maps,
+            tuple(self.design.buckets),
+            self.design.row_slots,
+            self.design.row_values,
+            widths=self._widths,
+        )
+
+    def wrap_tracker(self, trackers: tuple) -> IndexMapUpdateSummary:
+        return IndexMapUpdateSummary(pending=[
+            (reason, iters, gnorm, valid, np.asarray(ei), passes)
+            for (reason, iters, gnorm, passes), valid, ei in zip(
+                trackers, self._valid_lanes, self.design.entity_index)
+        ])
+
+    def fused_state(self):
+        """See ``FixedEffectCoordinate.fused_state``."""
+        return (
+            jnp.asarray(self._reg_weight, jnp.result_type(float)),
+            self.full_offsets_base,
+            self._offsets_maps,
+            tuple(self.design.buckets),
+            self.design.row_slots,
+            self.design.row_values,
+        )
+
+    def with_fused_state(self, state):
+        import copy
+
+        c = copy.copy(self)
+        (
+            c._reg_weight,
+            c.full_offsets_base,
+            c._offsets_maps,
+            buckets,
+            row_slots,
+            row_values,
+        ) = state
+        c.design = dataclasses.replace(
+            self.design, buckets=list(buckets), row_slots=row_slots,
+            row_values=row_values)
+        return c
+
+    def score(self, table: jax.Array) -> jax.Array:
+        return self._score(
+            table, self.design.row_slots, self.design.row_values)
+
+    def reg_term(self, table: jax.Array) -> jax.Array:
+        """The penalty the lanes' solves minimized, over the flat table
+        (its padding slots are zeros)."""
+        lam = jnp.asarray(self._reg_weight, table.dtype)
+        l2 = lam * (1.0 - self.config.l1_ratio)
+        l1 = lam * self.config.l1_ratio
+        return 0.5 * l2 * jnp.vdot(table, table) + l1 * jnp.sum(
+            jnp.abs(table))
+
+    def back_project(self, table):
+        """The flat table as per-entity (column, value) lists, a
+        ``game.scoring.CompactReTable`` (host), columns ascending."""
+        return self.design.index_map.compact(np.asarray(table))

@@ -13,6 +13,12 @@ GAME models: the reference's HDFS directory layout
 fixed-effect coefficients hold ONE record; random-effect files hold one
 record per entity with modelId = the raw entity key. id-info records the
 feature-shard id (and random-effect type for RE coordinates).
+
+A random effect trained over a sparse shard through INDEX_MAP is held as
+per-entity (column, value) lists (``game.scoring.CompactReTable``), never
+an (entities, d) table: it is written from the lists, record for record
+the same layout, and its id-info carries ``coefficientLayout=entity-sparse``
+so that the loader hands the lists back the same way.
 """
 
 from __future__ import annotations
@@ -63,6 +69,72 @@ def _coefficients_to_record(
         "variances": None if variances is None else triples(variances),
         "lossFunction": _LOSS_CLASS.get(task) if task else None,
     }
+
+
+def _compact_to_records(compact, vocab, index_to_id, task):
+    """One record an entity of a ``CompactReTable``: its stored columns'
+    (name, term, value) triples, exact zeros left out as
+    :func:`_coefficients_to_record` leaves them."""
+    cols = np.asarray(compact.columns)
+    vals = np.asarray(compact.values)
+    d = len(vocab)
+    records = []
+    for e in range(cols.shape[0]):
+        keep = (cols[e] < d) & (vals[e] != 0.0)
+        means = []
+        for i, v in zip(cols[e][keep], vals[e][keep]):
+            n, t = vocab.name_term(int(i))
+            means.append({"name": n, "term": t, "value": float(v)})
+        records.append({
+            "modelId": str(index_to_id.get(e, e)),
+            "means": means,
+            "variances": None,
+            "lossFunction": _LOSS_CLASS.get(task) if task else None,
+        })
+    return records
+
+
+def _records_to_compact(records, vocab, evocab):
+    """The inverse of :func:`_compact_to_records`: a ``CompactReTable``
+    over ``evocab``'s rows, columns ascending, from the records' triples;
+    never a dense (entities, d) table."""
+    from photon_ml_tpu.game.projectors import compact_from_lists
+
+    ents, cols, vals = [], [], []
+    for rec in records:
+        raw = rec["modelId"]
+        e = evocab.get(raw, evocab.get(_maybe_int(raw)))
+        if e is None:
+            continue
+        for t in rec["means"]:
+            idx = vocab.get(t["name"], t["term"])
+            if idx is not None:
+                ents.append(e)
+                cols.append(idx)
+                vals.append(t["value"])
+    ents = np.asarray(ents, np.int64)
+    cols = np.asarray(cols, np.int64)
+    order = np.lexsort((cols, ents))
+    return compact_from_lists(
+        ents[order], cols[order], np.asarray(vals, np.float64)[order],
+        len(evocab), len(vocab))
+
+
+def remap_compact_rows(compact, own: dict, shared: dict, d: int):
+    """:func:`remap_entity_rows` for a ``CompactReTable``: its rows
+    re-indexed into ``shared`` (a missing entity holds no column)."""
+    from photon_ml_tpu.game.scoring import CompactReTable
+
+    if shared == own:
+        return compact
+    cols = np.asarray(compact.columns)
+    vals = np.asarray(compact.values)
+    src = np.fromiter(own.values(), np.int64, count=len(own))
+    dst = np.asarray([shared[raw] for raw in own], np.int64)
+    out_c = np.full((len(shared), cols.shape[1]), d, cols.dtype)
+    out_v = np.zeros((len(shared), vals.shape[1]), vals.dtype)
+    out_c[dst], out_v[dst] = cols[src], vals[src]
+    return CompactReTable(out_c, out_v)
 
 
 def _record_to_coefficients(
@@ -136,6 +208,10 @@ class ModelIntegrityError(Exception):
 
 
 _MODEL_KINDS = ("fixed-effect", "random-effect", "factored-random-effect")
+
+# id-info's ``coefficientLayout`` of a random effect saved as per-entity
+# (column, value) lists
+ENTITY_SPARSE = "entity-sparse"
 
 
 def _manifest_files(root: str) -> List[str]:
@@ -232,10 +308,14 @@ def save_game_model(
     random_effects: Dict[str, Optional[str]],
     task: Optional[TaskType] = None,
 ):
-    """params: coordinate -> (d,) fixed or (E, d) random-effect table.
+    """params: coordinate -> (d,) fixed or (E, d) random-effect table, or
+    a random effect's per-entity lists (``game.scoring.CompactReTable``,
+    written as ``coefficientLayout=entity-sparse``).
     shards: coordinate -> feature shard id; vocabs: coordinate -> vocab;
     entity_vocabs: coordinate -> {raw_id: index} for RE coordinates;
     random_effects: coordinate -> RE type name or None (fixed)."""
+    from photon_ml_tpu.game.scoring import CompactReTable
+
     for name, table in params.items():
         if _is_factored(table):
             _save_factored_coordinate(
@@ -244,7 +324,9 @@ def save_game_model(
                 vocabs[name],
             )
             continue
-        table = np.asarray(table)
+        compact = isinstance(table, CompactReTable)
+        if not compact:
+            table = np.asarray(table)
         re_type = random_effects.get(name)
         kind = "fixed-effect" if re_type is None else "random-effect"
         cdir = os.path.join(root, kind, name)
@@ -253,8 +335,14 @@ def save_game_model(
             f.write(f"featureShardId={shards[name]}\n")
             if re_type is not None:
                 f.write(f"randomEffectType={re_type}\n")
+            if compact:
+                f.write(f"coefficientLayout={ENTITY_SPARSE}\n")
         vocab = vocabs[name]
-        if re_type is None:
+        if compact:
+            records = _compact_to_records(
+                table, vocab,
+                {v: k for k, v in entity_vocabs[name].items()}, task)
+        elif re_type is None:
             records = [
                 _coefficients_to_record(name, table, None, vocab, task)
             ]
@@ -323,6 +411,11 @@ def load_game_model(
                     evocab = {
                         rec["modelId"]: i for i, rec in enumerate(records)
                     }
+                entity_vocabs_out[name] = dict(evocab)
+                if info.get("coefficientLayout") == ENTITY_SPARSE:
+                    params[name] = _records_to_compact(
+                        records, vocab, evocab)
+                    continue
                 table = np.zeros((len(evocab), len(vocab)))
                 for rec in records:
                     raw = rec["modelId"]
@@ -330,7 +423,6 @@ def load_game_model(
                     if e is not None:
                         table[e], _ = _record_to_coefficients(rec, vocab)
                 params[name] = table
-                entity_vocabs_out[name] = dict(evocab)
     fdir = os.path.join(root, "factored-random-effect")
     if os.path.isdir(fdir):
         for name in sorted(os.listdir(fdir)):
@@ -443,6 +535,7 @@ def load_game_model_auto(root: str):
     import jax.numpy as jnp
 
     from photon_ml_tpu.game.factored import FactoredParams, is_factored_params
+    from photon_ml_tpu.game.scoring import CompactReTable
 
     model_root, vocab_root = resolve_game_dirs(root)
     vocab_files = {
@@ -492,6 +585,9 @@ def load_game_model_auto(root: str):
                 gamma=jnp.asarray(remap_entity_rows(p.gamma, own, shared)),
                 projection=p.projection,
             )
+        elif isinstance(p, CompactReTable):
+            params[name] = remap_compact_rows(
+                p, own, shared, len(coord_vocabs[name]))
         else:
             params[name] = remap_entity_rows(p, own, shared)
     return params, shards, random_effects, shard_vocabs, re_vocabs
@@ -511,6 +607,8 @@ def collapse_game_model(
     entity_vocabs) with merged coordinates named "<effect>-<shard>".
     Factored coordinates are rejected like the reference's
     UnsupportedOperationException for unknown model types."""
+    from photon_ml_tpu.game.scoring import CompactReTable
+
     groups: Dict[Tuple[str, str], List[str]] = {}
     for name in params:
         if _is_factored(params[name]):
@@ -520,6 +618,12 @@ def collapse_game_model(
             )
         effect = random_effects.get(name) or "fixed-effect"
         groups.setdefault((effect, shards[name]), []).append(name)
+    for names in groups.values():
+        if len(names) > 1 and any(
+                isinstance(params[n], CompactReTable) for n in names):
+            raise ValueError(
+                f"collapse of per-entity sparse coordinates {names} into "
+                "one is not supported")
 
     out_params: Dict[str, np.ndarray] = {}
     out_shards: Dict[str, str] = {}
@@ -534,6 +638,10 @@ def collapse_game_model(
             out_params[merged_name] = np.sum(
                 [np.asarray(params[n]) for n in names], axis=0
             )
+            continue
+        if len(names) == 1 and isinstance(params[names[0]], CompactReTable):
+            out_params[merged_name] = params[names[0]]
+            out_evocabs[merged_name] = entity_vocabs[names[0]]
             continue
         # cogroup random-effect tables on raw entity ids
         merged_vocab = union_entity_vocab(

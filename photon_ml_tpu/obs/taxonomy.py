@@ -47,7 +47,9 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "game.decode; game.update on the per-coordinate paths), the "
         "bucketed design build game.design (one a random effect: "
         "entities, buckets, bucket_caps, active_rows, active_slots, "
-        "capped_entities, passive_rows) and counters (game.passes, "
+        "capped_entities, passive_rows), inside it for a sparse shard's "
+        "random effect the INDEX_MAP compaction game.index_map (unions, "
+        "widths, stored and padded slots) and counters (game.passes, "
         "game.updates, game.checkpoint.submit_ms, game.re.capped_entities, "
         "game.re.passive_rows, game.table_write.inverse_gather, "
         "game.offsets_gather.compact, game.exchange.programs, "
@@ -57,6 +59,8 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "of a factored coordinate's tracker, "
         "game.factored.gamma_spread_runs and "
         "game.factored.table_write.inverse_gather of its traced update, "
+        "game.sparse_re.updates / .passes of an INDEX_MAP coordinate's "
+        "bucket passes, "
         "...), the "
         "game.offsets_gather.gather_indices / .padded_slots gauges of a "
         "random-effect coordinate's residual-offset gather and the "

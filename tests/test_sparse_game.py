@@ -277,10 +277,12 @@ class TestBuildIndexJob:
 
 
 class TestWideSparseRandomEffect:
-    """A SPARSE shard trains a random effect through
-    INDEX_MAP projection (per-entity active-column unions,
+    """A SPARSE shard trains a random effect through INDEX_MAP in ragged
+    compact widths (per-entity active-column unions,
     ``RandomEffectCoordinateInProjectedSpace.scala:26-120``,
-    ``IndexMapProjectorRDD.scala:113-120``)."""
+    ``IndexMapProjectorRDD.scala:113-120``): each bucket's lanes at their
+    widest union, one flat table, TRON a lane; back-projection gives
+    per-entity (column, value) lists, never an (E, d) table."""
 
     def _wide_data(self, rng, n, n_users, d_wide, pool=24, nnz=5):
         from photon_ml_tpu.game.data import GameData
@@ -303,56 +305,76 @@ class TestWideSparseRandomEffect:
         )
         return data, sf, user, y
 
-    def test_matches_dense_oracle(self, rng):
-        """Projected-from-sparse CD == plain dense RE CD on the densified
-        shard (no caps: per-entity subproblems are identical; columns
-        outside an entity's union solve to exactly 0 under L2)."""
+    @staticmethod
+    def _config(max_iters=40, tolerance=1e-12, **kw):
+        from photon_ml_tpu.core.tasks import TaskType
+        from photon_ml_tpu.game import CoordinateConfig
+        from photon_ml_tpu.models.training import OptimizerType
+
+        return CoordinateConfig(
+            shard="wide", task=TaskType.LOGISTIC_REGRESSION,
+            optimizer=OptimizerType.TRON, reg_weight=1.0,
+            max_iters=max_iters, tolerance=tolerance,
+            random_effect="userId", **kw,
+        )
+
+    @staticmethod
+    def _run_cd(coord, y, iterations=2, fuse_passes=True):
         import jax.numpy as jnp
 
         from photon_ml_tpu.core.tasks import TaskType
+        from photon_ml_tpu.game import CoordinateDescent
+
+        n = y.shape[0]
+        cd = CoordinateDescent(
+            coordinates={"re": coord},
+            labels=jnp.asarray(y),
+            base_offsets=jnp.zeros((n,)),
+            weights=jnp.ones((n,)),
+            task=TaskType.LOGISTIC_REGRESSION,
+            fuse_passes=fuse_passes,
+        )
+        return cd.run(num_iterations=iterations)
+
+    @staticmethod
+    def _dense(compact, d):
+        """The per-entity lists as an (E, d) table — the test's own oracle
+        form, at a toy width."""
+        cols, vals = np.asarray(compact.columns), np.asarray(compact.values)
+        table = np.zeros((cols.shape[0], d))
+        for e in range(cols.shape[0]):
+            keep = cols[e] < d
+            table[e, cols[e][keep]] = vals[e][keep]
+        return table
+
+    def test_matches_dense_oracle(self, rng):
+        """The ragged INDEX_MAP coordinate through the FUSED descent ==
+        plain dense RE CD on the densified shard (no caps: per-entity
+        subproblems are identical; columns outside an entity's union solve
+        to exactly 0 under L2, and are not held at all)."""
+        import jax.numpy as jnp
+
         from photon_ml_tpu.game import (
-            CoordinateConfig,
-            CoordinateDescent,
+            IndexMapRandomEffectCoordinate,
             RandomEffectCoordinate,
             build_bucketed_random_effect_design,
         )
         from photon_ml_tpu.game.data import GameData
-        from photon_ml_tpu.game.projected import (
-            ProjectedRandomEffectCoordinate,
-        )
-        from photon_ml_tpu.models.training import OptimizerType
+        from photon_ml_tpu.game.scoring import CompactReTable
 
         d_wide = 3000
         n, n_users = 400, 12
         data, sf, user, y = self._wide_data(rng, n, n_users, d_wide)
-        cfg = CoordinateConfig(
-            shard="wide",
-            task=TaskType.LOGISTIC_REGRESSION,
-            optimizer=OptimizerType.TRON,
-            reg_weight=1.0,
-            max_iters=40,
-            tolerance=1e-12,
-            random_effect="userId",
-        )
-
-        def run_cd(coord):
-            cd = CoordinateDescent(
-                coordinates={"re": coord},
-                labels=jnp.asarray(y),
-                base_offsets=jnp.zeros((n,)),
-                weights=jnp.ones((n,)),
-                task=TaskType.LOGISTIC_REGRESSION,
-            )
-            return cd.run(num_iterations=2)
-
-        proj_coord = ProjectedRandomEffectCoordinate.from_sparse_shard(
+        cfg = self._config()
+        coord = IndexMapRandomEffectCoordinate.from_sparse_shard(
             data, "userId", "wide", n_users, cfg, num_buckets=2,
             dtype=jnp.float64,
         )
-        m_proj, h_proj = run_cd(proj_coord)
-        table_wide = np.asarray(
-            proj_coord.back_project(m_proj.params["re"])
-        )
+        m_proj, h_proj = self._run_cd(coord, y)
+        compact = coord.back_project(m_proj.params["re"])
+        assert isinstance(compact, CompactReTable)
+        assert np.asarray(compact.columns).shape[1] < d_wide
+        table_wide = self._dense(compact, d_wide)
 
         dense = to_dense(sf)
         dense_data = GameData.create(
@@ -370,55 +392,36 @@ class TestWideSparseRandomEffect:
             full_offsets_base=jnp.zeros((n,)),
             config=cfg,
         )
-        m_dense, _ = run_cd(dense_coord)
+        m_dense, _ = self._run_cd(dense_coord, y)
         table_dense = np.asarray(m_dense.params["re"])
 
-        assert table_wide.shape == (n_users, d_wide)
         np.testing.assert_allclose(table_wide, table_dense, atol=1e-7)
         assert h_proj[-1].objective <= h_proj[0].objective + 1e-9
 
     def test_60k_columns_per_entity_sklearn_oracle(self, rng):
         """The acceptance shape: an RE coordinate trains on a 60k-column
-        SPARSE shard (dense design would be ~GBs); one entity's solution
-        is checked against sklearn on that entity's own rows."""
+        SPARSE shard (a dense design would be ~GBs); one entity's solution
+        is checked against sklearn on that entity's own rows, and the
+        entity's lists hold exactly its active columns."""
         import jax.numpy as jnp
 
-        from photon_ml_tpu.core.tasks import TaskType
-        from photon_ml_tpu.game import CoordinateConfig, CoordinateDescent
-        from photon_ml_tpu.game.projected import (
-            ProjectedRandomEffectCoordinate,
-        )
-        from photon_ml_tpu.models.training import OptimizerType
+        from photon_ml_tpu.game import IndexMapRandomEffectCoordinate
 
         d_wide = 60_000
         n, n_users = 600, 10
         data, sf, user, y = self._wide_data(
             rng, n, n_users, d_wide, pool=20, nnz=6
         )
-        cfg = CoordinateConfig(
-            shard="wide",
-            task=TaskType.LOGISTIC_REGRESSION,
-            optimizer=OptimizerType.TRON,
-            reg_weight=1.0,
-            max_iters=50,
-            tolerance=1e-12,
-            random_effect="userId",
+        coord = IndexMapRandomEffectCoordinate.from_sparse_shard(
+            data, "userId", "wide", n_users, self._config(max_iters=50),
+            num_buckets=2, dtype=jnp.float64,
         )
-        coord = ProjectedRandomEffectCoordinate.from_sparse_shard(
-            data, "userId", "wide", n_users, cfg, num_buckets=2,
-            dtype=jnp.float64,
-        )
-        cd = CoordinateDescent(
-            coordinates={"re": coord},
-            labels=jnp.asarray(y),
-            base_offsets=jnp.zeros((n,)),
-            weights=jnp.ones((n,)),
-            task=TaskType.LOGISTIC_REGRESSION,
-        )
-        model, _ = cd.run(num_iterations=1)
-        table = np.asarray(coord.back_project(model.params["re"]))
-        assert table.shape == (n_users, d_wide)
-        assert np.all(np.isfinite(table))
+        model, _ = self._run_cd(coord, y, iterations=1)
+        compact = coord.back_project(model.params["re"])
+        cols = np.asarray(compact.columns)
+        vals = np.asarray(compact.values)
+        assert cols.shape[0] == n_users and cols.shape[1] <= 20
+        assert np.all(np.isfinite(vals))
 
         # dense oracle for ONE entity: its rows restricted to its active
         # columns — mathematically the exact same L2-logistic problem
@@ -438,39 +441,61 @@ class TestWideSparseRandomEffect:
         skl = LogisticRegression(
             C=1.0, fit_intercept=False, tol=1e-10, max_iter=2000
         ).fit(dense_rows[:, active], y[rows_e])
-        np.testing.assert_allclose(
-            table[e, active], skl.coef_.ravel(), atol=2e-5
-        )
-        # columns outside the entity's union are exactly 0
-        inactive_mask = np.ones(d_wide, bool)
-        inactive_mask[active] = False
-        assert np.abs(table[e, inactive_mask]).max() == 0.0
+        held = cols[e] < d_wide
+        # the entity's lists are exactly its active columns, ascending
+        np.testing.assert_array_equal(cols[e][held], active)
+        np.testing.assert_allclose(vals[e][held], skl.coef_.ravel(),
+                                   atol=2e-5)
+        assert np.all(vals[e][~held] == 0.0)
 
     def test_sparse_re_scoring_matches_dense(self, rng):
+        """Compact scoring (the coordinate's gather of the flat table at
+        every row's stored entries, and ``score_game_data`` over the
+        back-projected lists) == dense scoring of the densified table on
+        the densified shard."""
+        import jax.numpy as jnp
+
+        from photon_ml_tpu.game import IndexMapRandomEffectCoordinate
         from photon_ml_tpu.game.scoring import score_game_data
 
         d_wide = 2000
         data, sf, user, y = self._wide_data(rng, 200, 8, d_wide)
-        table = rng.normal(size=(8, d_wide))
+        coord = IndexMapRandomEffectCoordinate.from_sparse_shard(
+            data, "userId", "wide", 8, self._config(), num_buckets=2,
+            dtype=jnp.float64,
+        )
+        flat = jnp.asarray(
+            rng.normal(size=coord.initial_params().shape)
+            * (coord.design.index_map.columns >= 0))
+        compact = coord.back_project(flat)
+        table = self._dense(compact, d_wide)
         dense_data = __import__("dataclasses").replace(
             data, features={"wide": to_dense(sf)}
-        )
-        s_sparse = np.asarray(
-            score_game_data(
-                {"re": table}, {"re": "wide"}, {"re": "userId"}, data
-            )
         )
         s_dense = np.asarray(
             score_game_data(
                 {"re": table}, {"re": "wide"}, {"re": "userId"}, dense_data
             )
         )
-        np.testing.assert_allclose(s_sparse, s_dense, rtol=1e-9)
+        s_compact = np.asarray(
+            score_game_data(
+                {"re": compact}, {"re": "wide"}, {"re": "userId"}, data
+            )
+        )
+        np.testing.assert_allclose(np.asarray(coord.score(flat)), s_dense,
+                                   rtol=1e-9, atol=1e-12)
+        np.testing.assert_allclose(s_compact, s_dense, rtol=1e-9,
+                                   atol=1e-12)
 
     def test_precompacted_table_and_cache(self, rng):
-        """CompactReTable params skip the host densify entirely; the
-        implicit compaction cache serves only IMMUTABLE tables (jax
-        arrays / non-writeable numpy) and evicts with its referent."""
+        """The lists an INDEX_MAP coordinate back-projects ARE a
+        CompactReTable: scored as they are against sparse and dense
+        shards, with no host densify; the implicit compaction cache of
+        (E, d) tables serves only IMMUTABLE tables (jax arrays /
+        non-writeable numpy) and evicts with its referent."""
+        import jax.numpy as jnp
+
+        from photon_ml_tpu.game import IndexMapRandomEffectCoordinate
         from photon_ml_tpu.game.scoring import (
             CompactReTable,
             _COMPACT_CACHE,
@@ -481,34 +506,38 @@ class TestWideSparseRandomEffect:
 
         d_wide = 500
         data, sf, user, y = self._wide_data(rng, 100, 6, d_wide)
-        table = rng.normal(size=(6, d_wide)) * (
-            rng.uniform(size=(6, d_wide)) < 0.05
+        coord = IndexMapRandomEffectCoordinate.from_sparse_shard(
+            data, "userId", "wide", 6, self._config(), num_buckets=1,
+            dtype=jnp.float64,
         )
+        model, _ = self._run_cd(coord, y, iterations=1)
+        lists = coord.back_project(model.params["re"])
+        table = self._dense(lists, d_wide)
         base = np.asarray(
             score_game_data(
                 {"re": table}, {"re": "wide"}, {"re": "userId"}, data
             )
         )
-        compact = CompactReTable(*_compact_table(table))
-        got = np.asarray(
-            score_game_data(
-                {"re": compact}, {"re": "wide"}, {"re": "userId"}, data
+        for compact in (lists, CompactReTable(*_compact_table(table))):
+            got = np.asarray(
+                score_game_data(
+                    {"re": compact}, {"re": "wide"}, {"re": "userId"}, data
+                )
             )
-        )
-        np.testing.assert_allclose(got, base, rtol=1e-9)
-
-        # CompactReTable against a dense shard: the compact-dense gather
-        # kernel (the serving engine's path) must reproduce the scores
-        dense_data = __import__("dataclasses").replace(
-            data, features={"wide": to_dense(sf)}
-        )
-        got_dense = np.asarray(
-            score_game_data(
-                {"re": compact}, {"re": "wide"}, {"re": "userId"},
-                dense_data,
+            np.testing.assert_allclose(got, base, rtol=1e-9, atol=1e-12)
+            # against a dense shard: the compact-dense gather kernel (the
+            # serving engine's path) reproduces the scores
+            dense_data = __import__("dataclasses").replace(
+                data, features={"wide": to_dense(sf)}
             )
-        )
-        np.testing.assert_allclose(got_dense, base, rtol=1e-9)
+            got_dense = np.asarray(
+                score_game_data(
+                    {"re": compact}, {"re": "wide"}, {"re": "userId"},
+                    dense_data,
+                )
+            )
+            np.testing.assert_allclose(got_dense, base, rtol=1e-9,
+                                       atol=1e-12)
 
         # writeable numpy: never cached (in-place mutation must be seen)
         t_np = np.array(table)
@@ -520,8 +549,6 @@ class TestWideSparseRandomEffect:
         )
 
         # jax array (immutable): cached by identity, evicted on death
-        import jax.numpy as jnp
-
         t_dev = jnp.asarray(table)
         c1 = _compact_table_cached(t_dev)
         c2 = _compact_table_cached(t_dev)
@@ -550,14 +577,65 @@ class TestSparseShardGuards:
         self, game_files
     ):
         """The driver path end-to-end: sparse userShard + INDEX_MAP
-        projector trains, saves, and matches the dense run's AUC."""
+        projector trains, saves per-entity (column, value) lists
+        (``coefficientLayout=entity-sparse``), loads them back as lists,
+        and the scoring driver reproduces the saved model's AUC."""
+        from photon_ml_tpu.cli.score import run_scoring
+        from photon_ml_tpu.game.scoring import CompactReTable
+        from photon_ml_tpu.io.models import load_game_model_auto
+
         tmp_path, gvocab, uvocab = game_files
         params = _params(
             tmp_path, gvocab, uvocab, "out_wide_re", ["userShard"]
         )
         params["coordinates"]["per-user"]["projector"] = "INDEX_MAP"
         run = run_game_training(params)
-        assert run is not None
+        best = run.sweep[run.best_index]
+        assert isinstance(best["model"].params["per-user"], CompactReTable)
+        info = (tmp_path / "out_wide_re" / "best" / "random-effect"
+                / "per-user" / "id-info").read_text()
+        assert "coefficientLayout=entity-sparse" in info
+        loaded, _, _, _, _ = load_game_model_auto(
+            str(tmp_path / "out_wide_re"))
+        assert isinstance(loaded["per-user"], CompactReTable)
+        scored = run_scoring(
+            {
+                "input": [str(tmp_path / "train")],
+                "model_dir": str(tmp_path / "out_wide_re"),
+                "output_dir": str(tmp_path / "sc_wide_re"),
+                "model_kind": "game",
+                "evaluate": True,
+                "sparse_shards": ["userShard"],
+            }
+        )
+        np.testing.assert_allclose(
+            scored.metrics["AREA_UNDER_RECEIVER_OPERATOR_CHARACTERISTICS"],
+            best["validation_metric"], rtol=1e-5)
+
+    def test_index_map_model_serves_its_lists(self, game_files):
+        """The serving engine loads an INDEX_MAP coordinate's saved
+        per-entity lists as they are and scores dense rows through them
+        exactly as the offline scorer does from the same load."""
+        from photon_ml_tpu.game.scoring import CompactReTable, score_game_data
+        from photon_ml_tpu.io.models import load_game_model_auto
+        from photon_ml_tpu.serving.engine import ScoringEngine
+
+        tmp_path, gvocab, uvocab = game_files
+        params = _params(
+            tmp_path, gvocab, uvocab, "out_served", ["userShard"]
+        )
+        params["coordinates"]["per-user"]["projector"] = "INDEX_MAP"
+        run_game_training(params)
+        root = str(tmp_path / "out_served")
+        loaded, shards, res, shard_vocabs, re_vocabs = load_game_model_auto(
+            root)
+        assert isinstance(loaded["per-user"], CompactReTable)
+        data, _, _, _ = IngestSource([str(tmp_path / "train")]).game_data(
+            shard_vocabs, ["userId"], entity_vocabs=re_vocabs)
+        offline = np.asarray(score_game_data(loaded, shards, res, data))
+        engine = ScoringEngine.from_model_dir(root)
+        np.testing.assert_allclose(engine.score_data(data), offline,
+                                   rtol=1e-9, atol=1e-12)
 
     def test_hot_columns_requires_sparse_fixed(self, game_files):
         tmp_path, gvocab, uvocab = game_files
