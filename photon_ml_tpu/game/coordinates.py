@@ -748,73 +748,136 @@ def _score_rows_by_entity(table, feats, ents):
     return jnp.where(ents >= 0, per_row, 0.0)
 
 
-def _split_columns(columns, width: int):
-    """A local id as (block, lane of the block): the block as a one-hot
-    over the width's blocks, exact in any float type."""
+def _part_count(dtype) -> int:
+    """bfloat16 parts (8 significant bits each) that hold a value of
+    ``dtype`` exactly: 3 for float32, 7 for float64."""
+    return -(-(jnp.finfo(dtype).nmant + 1) // 8)
+
+
+def _product_tiles(width: int, dtype) -> int:
+    """128-wide MXU tiles a lane's products take at ``width``: its blocks'
+    parts stacked, ceil(parts x blocks / 128)."""
+    return -(-_part_count(dtype) * (width // COMPACT_BLOCK) // COMPACT_BLOCK)
+
+
+def _bf16_parts(x):
+    """x cut into :func:`_part_count` bfloat16 parts, high to low, each the
+    rest so far truncated to bfloat16's 8 significant bits (its sign,
+    exponent and 7 highest mantissa bits kept by a mask). The parts carry
+    x's sign on disjoint bits of its significand, so any subset of them
+    sums exactly, in any order, and all of them to x (where every part is
+    a normal bfloat16: float32 above 2^-103 in magnitude, float64 above
+    2^-74)."""
+    width = 8 * x.dtype.itemsize
+    keep = jnp.asarray((1 << width) - (1 << (jnp.finfo(x.dtype).nmant - 7)),
+                       f"uint{width}")
+    bits = keep.dtype
+    parts, rest = [], x
+    for _ in range(_part_count(x.dtype) - 1):
+        part = jax.lax.bitcast_convert_type(
+            jax.lax.bitcast_convert_type(rest, bits) & keep, x.dtype)
+        parts.append(part)
+        rest = rest - part
+    return [part.astype(jnp.bfloat16) for part in parts + [rest]]
+
+
+def _split_columns(columns, width: int, parts: int):
+    """A local id as (block, lane of the block), one-hots exact in any
+    float type: the block over ``parts`` stacked copies of the width's
+    blocks (entry q is block q % blocks), the lane over a block's 128."""
     blocks = width // COMPACT_BLOCK
     pick = columns[..., None] // COMPACT_BLOCK == jnp.arange(
-        blocks, dtype=columns.dtype)
+        parts * blocks, dtype=columns.dtype) % blocks
     lane = columns[..., None] % COMPACT_BLOCK == jnp.arange(
         COMPACT_BLOCK, dtype=columns.dtype)
     return pick, lane
 
 
+def _one_pass(spec, x, y, dtype):
+    """A contraction of bfloat16 one-hots and exact bfloat16 parts in one
+    MXU pass (no HIGHEST split: the operands are bfloat16 already),
+    accumulated in ``dtype``."""
+    return jnp.einsum(spec, x.astype(jnp.bfloat16), y,
+                      preferred_element_type=dtype,
+                      precision=jax.lax.Precision.DEFAULT)
+
+
+def _lane_pick(w, columns):
+    """(s, R) w[columns] of one lane's compact ELL rows, equal to the
+    gather to the bit, read as two one-hot contractions: every block's
+    coefficient at the id's lane of a block, by one bfloat16 MXU pass over
+    the 128 lanes against the blocks' :func:`_bf16_parts` stacked
+    ((parts x blocks, 128): one 128-wide tile up to 42 blocks in float32),
+    then the parts of the id's block selected and summed. A one-hot is
+    exact in bfloat16, each product is one part accumulated with zeros,
+    and the parts sum exactly, so the one pass is as exact as HIGHEST's
+    six (half of which multiply the one-hot's zero low parts). XLA's
+    gather costs about 7 ns an index on the chip (PERF.md section 5), the
+    products a fraction of that. The other order (the id's block of 128
+    coefficients by the product, then its lane selected) compiled for TPU
+    v5e reads 0.85 of the margins' size wrong on the chip (PERF.md section
+    6); this one is checked against ``jnp.take`` on the chip by
+    ``tests/test_index_map_sparse_re.py``."""
+    width = w.shape[0]
+    parts = _bf16_parts(w.reshape(width // COMPACT_BLOCK, COMPACT_BLOCK))
+    pick, lane = _split_columns(columns, width, len(parts))
+    column = _one_pass("srl,ql->srq", lane, jnp.concatenate(parts), w.dtype)
+    return jnp.sum(jnp.where(pick, column, 0.0), axis=-1)
+
+
 def _lane_matvec(w, columns, values):
     """One lane's margins over its compact ELL rows: (s, R) local ids and
-    values against its (k,) coefficients -> (R,). The gather w[id] is read
-    as two one-hot contractions: every block's coefficient at the id's
-    lane of a block, by a matrix product over the 128 lanes (the MXU's, at
-    HIGHEST precision: the one-hot is exact, so each pick is w's value to
-    the bit), then the id's block selected. XLA's gather costs about 7 ns
-    an index on the chip (PERF.md section 5), the products a fraction of
-    that. The other order (the id's block of 128 coefficients by the
-    product, then its lane selected) compiled for TPU v5e reads 0.85 of
-    the margins' size wrong on the chip (PERF.md section 6)."""
+    values against its (k,) coefficients -> (R,), by :func:`_lane_pick`."""
     with jax.named_scope("sparse_re/matvec"):
-        width = w.shape[0]
-        pick, lane = _split_columns(columns, width)
-        column = jnp.einsum(
-            "srl,bl->srb", lane.astype(w.dtype),
-            w.reshape(width // COMPACT_BLOCK, COMPACT_BLOCK),
-            precision=jax.lax.Precision.HIGHEST)
-        return jnp.sum(
-            values * jnp.sum(jnp.where(pick, column, 0.0), axis=-1), axis=0)
+        return jnp.sum(values * _lane_pick(w, columns), axis=0)
 
 
 def _lane_rmatvec(a, columns, values, width: int):
     """The transpose: per-row (R,) weights -> the lane's (k,) vector, the
     segment sum of its stored entries by local id, read as the transposed
-    one-hot contraction (each entry spread over its block's 128 lanes,
-    then summed into its block by the MXU), no scatter."""
+    one-hot contraction, no scatter: each entry's ``values * a`` cut into
+    :func:`_bf16_parts`, each part laid on its id's block among the
+    stacked parts' ((s, R, parts x blocks)), summed over the entries
+    against the lane one-hot by one bfloat16 MXU pass accumulated in a's
+    dtype (as HIGHEST's six passes are), then the parts added. Checked
+    against ``np.add.at`` on the chip by
+    ``tests/test_index_map_sparse_re.py``."""
     with jax.named_scope("sparse_re/rmatvec"):
-        pick, lane = _split_columns(columns, width)
-        spread = jnp.where(lane, (values * a[None, :])[..., None], 0.0)
-        return jnp.einsum(
-            "srb,srl->bl", pick.astype(a.dtype), spread,
-            precision=jax.lax.Precision.HIGHEST).reshape(-1)
+        parts = _bf16_parts(values * a[None, :])
+        pick, lane = _split_columns(columns, width, len(parts))
+        part_of = jnp.arange(pick.shape[-1]) // (width // COMPACT_BLOCK)
+        spread = parts[-1][..., None]
+        for p in range(len(parts) - 2, -1, -1):
+            spread = jnp.where(part_of == p, parts[p][..., None], spread)
+        spread = jnp.where(pick, spread, jnp.zeros((), jnp.bfloat16))
+        summed = _one_pass("srl,srq->ql", lane, spread, a.dtype)
+        return jnp.sum(summed.reshape(len(parts), -1), axis=0)
 
 
 def _compact_lane_objective(loss, lam, columns, values, labels, offsets,
-                            weights):
+                            weights, width: int):
     """(value_and_grad, hvp, hvp_at, value_grad_curvature) of one lane's
     L2 GLM in its compact column space: ``GLMObjective``'s algebra with
     the margins a gather of the lane's vector at its rows' stored entries
     and the gradient and Hessian-vector product a segment sum back into
-    it, ``weights`` already masked."""
+    it, ``weights`` already masked. Built once a bucket traced, which
+    books the MXU tiles its products take
+    (``game.sparse_re.product_tiles``)."""
+    obs.registry().inc("game.sparse_re.product_tiles",
+                       _product_tiles(width, values.dtype))
 
     def vgc(w):
         z = _lane_matvec(w, columns, values) + offsets
         val = jnp.sum(weights * loss.value(z, labels)) + 0.5 * lam * jnp.vdot(
             w, w)
         grad = _lane_rmatvec(
-            weights * loss.d1(z, labels), columns, values, w.shape[0]
+            weights * loss.d1(z, labels), columns, values, width
         ) + lam * w
         return val, grad, weights * loss.d2(z, labels)
 
     def hvp_at(curvature, v):
         dz = _lane_matvec(v, columns, values)
-        return _lane_rmatvec(
-            curvature * dz, columns, values, v.shape[0]) + lam * v
+        return _lane_rmatvec(curvature * dz, columns, values, width) + lam * v
 
     def value_and_grad(w):
         val, grad, _ = vgc(w)
@@ -860,7 +923,8 @@ def _make_compact_solve(config: CoordinateConfig):
         l1 = reg_weight * config.l1_ratio
         lam = reg_weight * (1.0 - config.l1_ratio)
         vg, hvp, hvp_at, vgc = _compact_lane_objective(
-            loss, lam, columns, values, labels, offsets, weights * mask)
+            loss, lam, columns, values, labels, offsets, weights * mask,
+            w0.shape[0])
         if use_owlqn:
             return minimize_owlqn(vg, w0, l1, scfg)
         if config.optimizer == OptimizerType.TRON:

@@ -60,7 +60,8 @@ TAXONOMY: Tuple[Tuple[str, str, str], ...] = (
         "game.factored.gamma_spread_runs and "
         "game.factored.table_write.inverse_gather of its traced update, "
         "game.sparse_re.updates / .passes of an INDEX_MAP coordinate's "
-        "bucket passes, "
+        "bucket passes, game.sparse_re.product_tiles (the MXU tiles of "
+        "its lane products, booked a bucket traced), "
         "...), the "
         "game.offsets_gather.gather_indices / .padded_slots gauges of a "
         "random-effect coordinate's residual-offset gather and the "

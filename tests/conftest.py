@@ -6,11 +6,16 @@ jax's ``jax_num_cpu_devices``: every test sees 8 CPU "chips" so the full
 mesh/sharding/collective path is exercised without TPU hardware. The
 suite is a CPU suite (float64 oracles), so the platform is pinned too —
 both are config updates, valid any time before first backend use.
+``PHOTON_TEST_PLATFORMS=tpu,cpu`` leaves the chip to the chip-only tests
+(``-k on_the_chip``), which skip on any other backend.
 """
+
+import os
 
 import jax
 
-jax.config.update("jax_platforms", "cpu")
+jax.config.update("jax_platforms",
+                  os.environ.get("PHOTON_TEST_PLATFORMS", "cpu"))
 jax.config.update("jax_num_cpu_devices", 8)
 jax.config.update("jax_enable_x64", True)
 

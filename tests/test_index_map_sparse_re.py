@@ -271,29 +271,131 @@ def test_filters_keep_the_dense_builders_columns(min_support, ratio):
             np.testing.assert_array_equal(np.sort(mine), kept)
 
 
-@pytest.mark.parametrize("width", [128, 384, 2688])
-def test_lane_products_are_the_gather_and_the_segment_sum(width):
-    """The lanes' one-hot contractions at widths of one and of many
-    blocks of 128: the margins are the gather of the lane's vector at the
-    rows' local ids, the transpose the segment sum by local id."""
+def _lane_case(width, dtype, lanes=3, slots=8, rows=70, seed=None):
+    """A vmapped batch of lanes: local ids, values, the lanes' vectors over
+    a wide span of magnitudes (float32 1e-30..1e30, so the low parts carry
+    bits; float64 1e-20..1e20, where all seven parts are normal bfloat16)
+    and per-row weights."""
+    rng = np.random.default_rng(width if seed is None else seed)
+    span = 30 if dtype == np.float32 else 20
+    cols = rng.integers(0, width, (lanes, slots, rows)).astype(np.int32)
+    vals = rng.normal(size=(lanes, slots, rows)).astype(dtype)
+    w = (rng.choice([-1.0, 1.0], (lanes, width))
+         * 10.0 ** rng.uniform(-span, span, (lanes, width))).astype(dtype)
+    a = rng.normal(size=(lanes, rows)).astype(dtype)
+    return cols, vals, w, a
+
+
+def _lane_products(cols, vals, w, a):
     import jax
 
     from photon_ml_tpu.game import coordinates as C
 
-    rng = np.random.default_rng(width)
-    lanes, slots, rows = 3, 8, 70
-    cols = rng.integers(0, width, (lanes, slots, rows)).astype(np.int32)
-    vals = rng.normal(size=(lanes, slots, rows))
-    w = rng.normal(size=(lanes, width))
-    a = rng.normal(size=(lanes, rows))
-    z = jax.vmap(C._lane_matvec)(jnp.asarray(w), jnp.asarray(cols),
-                                 jnp.asarray(vals))
-    g = jax.vmap(lambda a, c, v: C._lane_rmatvec(a, c, v, width))(
-        jnp.asarray(a), jnp.asarray(cols), jnp.asarray(vals))
-    for e in range(lanes):
-        np.testing.assert_allclose(
-            np.asarray(z[e]), (vals[e] * w[e][cols[e]]).sum(0), rtol=1e-12)
-        want = np.zeros(width)
-        np.add.at(want, cols[e].ravel(), (vals[e] * a[e][None, :]).ravel())
-        np.testing.assert_allclose(np.asarray(g[e]), want, rtol=1e-12,
-                                   atol=1e-12)
+    width = w.shape[1]
+    cols, vals, w, a = map(jnp.asarray, (cols, vals, w, a))
+    pick = jax.jit(jax.vmap(C._lane_pick))(w, cols)
+    z = jax.jit(jax.vmap(C._lane_matvec))(w, cols, vals)
+    g = jax.jit(jax.vmap(lambda a, c, v: C._lane_rmatvec(a, c, v, width)))(
+        a, cols, vals)
+    return np.asarray(pick), np.asarray(z), np.asarray(g)
+
+
+def _assert_lane_products(cols, vals, w, a, pick, z, g, tol):
+    """Picks equal the gather to the bit; the margins and the transpose
+    (against float64 ``np.add.at``) within ``tol`` of the magnitudes they
+    sum, and in float64 within 1e-12 of each value as well."""
+    for e in range(cols.shape[0]):
+        np.testing.assert_array_equal(pick[e], w[e][cols[e]])
+        terms = vals[e].astype(np.float64) * w[e][cols[e]]
+        assert np.all(np.abs(z[e] - terms.sum(0))
+                      <= tol * np.abs(terms).sum(0))
+        terms = vals[e].astype(np.float64) * a[e][None, :].astype(np.float64)
+        want, scale = np.zeros(w.shape[1]), np.zeros(w.shape[1])
+        np.add.at(want, cols[e].ravel(), terms.ravel())
+        np.add.at(scale, cols[e].ravel(), np.abs(terms).ravel())
+        assert np.all(np.abs(g[e] - want) <= tol * scale)
+        if w.dtype == np.float64:
+            np.testing.assert_allclose(
+                z[e], (vals[e] * w[e][cols[e]]).sum(0), rtol=1e-12)
+            np.testing.assert_allclose(g[e], want, rtol=1e-12, atol=1e-12)
+
+
+@pytest.mark.parametrize("width, dtype, tol", [
+    pytest.param(width, dtype, tol, id=f"{width}{suffix}")
+    for dtype, tol, suffix in [(np.float64, 1e-12, ""),
+                               (np.float32, 1e-6, "-float32")]
+    for width in (128, 384, 2688, 6016)])
+def test_lane_products_are_the_gather_and_the_segment_sum(width, dtype, tol):
+    """The lanes' one-hot contractions at widths of one and of many
+    blocks of 128, and past one 128-wide tile of stacked parts (6,016 =
+    47 blocks, 141 rows in float32): the margins are the gather of the
+    lane's vector at the rows' local ids (each pick to the bit), the
+    transpose the segment sum by local id."""
+    cols, vals, w, a = _lane_case(width, dtype)
+    _assert_lane_products(cols, vals, w, a, *_lane_products(cols, vals, w, a),
+                          tol)
+
+
+@pytest.mark.parametrize("width", [384, 2688, 6016])
+def test_lane_products_run_one_bfloat16_pass(width):
+    """A bucket's value/gradient and Hessian-vector product at float32
+    lower with no HIGHEST-precision dot (HIGHEST would split each float32
+    operand into three bfloat16 passes a side), and the bucket books
+    ceil(3 x blocks / 128) MXU tiles in ``game.sparse_re.product_tiles``."""
+    import jax
+
+    from photon_ml_tpu.core.tasks import TaskType
+    from photon_ml_tpu.game import coordinates as C
+    from photon_ml_tpu.ops.losses import loss_for_task
+
+    name = "game.sparse_re.product_tiles"
+    assert obs.taxonomy.matches(name)
+    cols, vals, w, a = _lane_case(width, np.float32, lanes=2)
+    rows = a.shape[1]
+    f32 = np.float32
+    labels, offsets, weights = (np.zeros((2, rows), f32),) * 3
+    reg = obs.registry()
+    before = reg.counter(name).value
+
+    def both(w, v, cols, vals, labels, offsets, weights):
+        def one(w, v, cols, vals, labels, offsets, weights):
+            _, _, hvp_at, vgc = C._compact_lane_objective(
+                loss_for_task(TaskType.LOGISTIC_REGRESSION), f32(1.0), cols,
+                vals, labels, offsets, weights, width)
+            value, grad, curvature = vgc(w)
+            return value, grad, hvp_at(curvature, v)
+
+        return jax.vmap(one)(w, v, cols, vals, labels, offsets, weights)
+
+    text = jax.jit(both).lower(w, w, cols, vals, labels, offsets,
+                               weights).as_text()
+    dots = [line for line in text.splitlines() if "dot_general" in line]
+    assert len(dots) >= 4  # the margins twice, the transpose twice
+    assert not [line for line in dots if "HIGHEST" in line]
+    assert reg.counter(name).value - before == -(-3 * (width // 128) // 128)
+
+
+# The cell's four buckets (game_music_sparse_user.cd): (slots, depth,
+# width) at 16 lanes each, where the cell runs 8,806 / 3,768 / 1,799 /
+# 2,011.
+_CELL_BUCKETS = [(8, 70, 384), (8, 204, 768), (8, 497, 1408),
+                 (8, 1024, 2688)]
+
+
+@pytest.mark.parametrize("slots, depth, width", _CELL_BUCKETS)
+def test_lane_products_on_the_chip(slots, depth, width):
+    """The one-hot products as the chip compiles them, at the cell's
+    bucket shapes, against the gather and the segment sum: a form of a
+    one-hot contraction that is exact on the CPU read 0.85 of the margins'
+    size wrong on TPU v5e (PERF.md section 6), so a jax upgrade that
+    changes how these compile shows here. Runs where the default backend
+    is a TPU (``PHOTON_TEST_PLATFORMS=tpu,cpu``; conftest pins the CPU
+    otherwise)."""
+    import jax
+
+    if jax.default_backend() != "tpu":
+        pytest.skip("needs a TPU backend")
+    cols, vals, w, a = _lane_case(width, np.float32, lanes=16, slots=slots,
+                                  rows=depth)
+    _assert_lane_products(cols, vals, w, a, *_lane_products(cols, vals, w, a),
+                          1e-6)
